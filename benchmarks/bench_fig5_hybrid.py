@@ -26,7 +26,7 @@ _SUB = textwrap.dedent("""
     import json
     import jax
     from repro.configs import get, SHAPES
-    from repro.core import addressing, compat, hlo_cost
+    from repro.core import addressing, hlo_cost
     from repro.launch import dryrun as dr
     from repro.launch.mesh import make_test_mesh
     import dataclasses
@@ -42,7 +42,7 @@ _SUB = textwrap.dedent("""
         rules = addressing.default_rules(mesh, overrides=cfg.rules_overrides)
         fn, args, in_sh, out_sh, donate = dr.build_cell(
             cfg, SHAPES["train_4k"], mesh, rules)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             compiled = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
                                donate_argnums=donate).lower(*args).compile()
         costs = hlo_cost.analyze(compiled.as_text())
@@ -66,48 +66,32 @@ def model_sweep() -> list[str]:
     return lines
 
 
-def measured_production() -> list[str] | None:
-    """256-chip measurement from the committed dry-run variants."""
-    res = Path(__file__).resolve().parents[1] / "results" / "dryrun"
-    base = res / "mixtral-8x7b__train_4k__single.json"
-    loc = res / "mixtral-8x7b__train_4k__single__localmoe.json"
-    if not (base.exists() and loc.exists()):
-        return None
-    b = json.loads(base.read_text())
-    l = json.loads(loc.read_text())
-    cb = b["hlo"]["collective_operand_bytes_per_device"]
-    cl = l["hlo"]["collective_operand_bytes_per_device"]
-    tb = b["hlo"]["bytes_per_device"]
-    tl = l["hlo"]["bytes_per_device"]
-    return [f"fig5/measured256_interleaved,0,p_local={1 - cb / tb:.4f};"
-            f"coll_bytes={cb:.3e}",
-            f"fig5/measured256_local,0,p_local={1 - cl / tl:.4f};"
-            f"coll_bytes={cl:.3e}",
-            f"fig5/measured256_gain,0,collective_reduction={cb / cl:.2f}x"]
-
-
 def measured(timeout: int = 900) -> list[str]:
-    prod = measured_production()
-    if prod is not None:
-        return prod
+    """Compile both plans in a child process on 8 *CPU* host devices and
+    compare the collective bytes in the HLO. The child is pinned to the
+    CPU platform: it forces a host device count, and on a machine with a
+    TPU it must not try to take the chip the parent may hold. A failed
+    child fails the section."""
     src = Path(__file__).resolve().parents[1] / "src"
-    env = dict(os.environ, PYTHONPATH=str(src))
-    try:
-        out = subprocess.run([sys.executable, "-c", _SUB], env=env,
-                             capture_output=True, text=True, timeout=timeout)
-        data = json.loads(out.stdout.strip().splitlines()[-1])
-    except Exception as e:  # pragma: no cover
-        return [f"fig5/measured,0,skipped({type(e).__name__})"]
+    env = dict(os.environ, PYTHONPATH=str(src), JAX_PLATFORMS="cpu",
+               REPRO_DRYRUN_DEVICES="8")
+    out = subprocess.run([sys.executable, "-c", _SUB], env=env,
+                         capture_output=True, text=True, timeout=timeout)
+    if out.returncode != 0:
+        raise RuntimeError(f"fig5 measurement child exited "
+                           f"{out.returncode}: {out.stderr[-2000:]}")
+    data = json.loads(out.stdout.strip().splitlines()[-1])
     il = data["interleaved"]
     lc = data["local"]
     p_il = 1 - il["collective_bytes"] / max(il["total_bytes"], 1)
     p_lc = 1 - lc["collective_bytes"] / max(lc["total_bytes"], 1)
     ratio = il["collective_bytes"] / max(lc["collective_bytes"], 1)
     return [f"fig5/measured_interleaved,0,p_local={p_il:.4f};"
-            f"coll_bytes={il['collective_bytes']:.3e}",
+            f"coll_bytes={il['collective_bytes']:.3e};backend=cpu8",
             f"fig5/measured_local,0,p_local={p_lc:.4f};"
-            f"coll_bytes={lc['collective_bytes']:.3e}",
-            f"fig5/measured_gain,0,collective_reduction={ratio:.2f}x"]
+            f"coll_bytes={lc['collective_bytes']:.3e};backend=cpu8",
+            f"fig5/measured_gain,0,collective_reduction={ratio:.2f}x;"
+            f"backend=cpu8"]
 
 
 def main(smoke: bool = False) -> list[str]:

@@ -41,16 +41,18 @@ reuses it — the row `check_gate.py --require recovery` enforces.
 
 A sixth scenario measures cluster-of-clusters scaling: the same
 per-group workload runs through `ShardedServeSessionProgram` at 1, 2,
-and 4 groups, each measurement in a child process under
-`--xla_force_host_platform_device_count=8` so every group owns a host
-device. Aggregate tokens/s and per-group stall ledgers roll up into
-`serve/groups_scaling`; scaling efficiency is normalized by the
-*attainable* parallelism `min(groups, cores)` — on a multi-core host
-that demands real near-linear scaling, on a single-core host (where G
-device computes time-share one core and ideal aggregate throughput is
-flat) it degenerates to a bound on the two-level scheduler's overhead.
-The row records `cores=` so the gate and readers know which regime was
-measured.
+and 4 groups, in this process (a device belongs to one process at a
+time), each group on its own device: on a CPU host run it under
+`XLA_FLAGS=--xla_force_host_platform_device_count=8` (CI's bench lanes
+do), since `check_gate.py --require groups` refuses a row measured on
+fewer than 4 devices. Aggregate tokens/s and per-group stall ledgers
+roll up into `serve/groups_scaling`; scaling efficiency is normalized
+by the *attainable* parallelism `min(groups, cores)` — on a multi-core
+host that demands real near-linear scaling, on a single-core host
+(where G device computes time-share one core and ideal aggregate
+throughput is flat) it degenerates to a bound on the two-level
+scheduler's overhead. The row records `cores=` and `devices=` so the
+gate and readers know which regime was measured.
 
 Row format: serve/{continuous|static},us_per_token,tokens_per_s=..;...
             serve/class_{latency|throughput|best_effort},p99_lat_us,...
@@ -411,19 +413,15 @@ GROUP_CHUNK = 16                # coarse cadence: device work dominates the
 GROUP_OUT_LENS = (8, 8, 16, 24)
 
 
-def _groups_child(n_groups: int, n_req: int, seed: int) -> None:
-    """One groups-scaling measurement, meant to run in a child process
-    under `--xla_force_host_platform_device_count=8` (so each group owns
-    a host device). Prints a single JSON line."""
-    import json
+def _measure_groups(cluster, n_groups: int, n_req: int, seed: int) -> dict:
+    """One groups-scaling measurement on this process's devices (groups
+    wrap round-robin over them when there are fewer devices than groups)."""
     import os
 
     import numpy as np
 
-    from repro.cluster import Cluster, ShardedServeSessionProgram
-    from repro.runtime.engine import StallClock
+    from repro.cluster import ShardedServeSessionProgram
 
-    cluster = Cluster(ARCH)
     max_seq = MAX_PROMPT + max(GROUP_OUT_LENS) + 1
     program = cluster.compile(ShardedServeSessionProgram(
         groups=n_groups, slots=GROUP_SLOTS, max_seq=max_seq,
@@ -450,7 +448,7 @@ def _groups_child(n_groups: int, n_req: int, seed: int) -> None:
     wall = time.perf_counter() - t0
     per_stall = [st["groups"][g]["stall"]["stall_pct"]
                  for g in range(n_groups)]
-    print(json.dumps({
+    row = {
         "groups": n_groups,
         "devices": len({id(d) for d in sess.plan.devices}),
         "cores": len(os.sched_getaffinity(0)),
@@ -461,35 +459,24 @@ def _groups_child(n_groups: int, n_req: int, seed: int) -> None:
         "stall_max_pct": max(per_stall),
         "occupancy_pct": st["occupancy_pct"],
         "placed": st["placement"]["placed"],
-    }))
+    }
     sess.close()
+    return row
 
 
 def run_groups(smoke: bool) -> list[str]:
     """Cluster-of-clusters scaling: the same per-group workload at 1, 2,
-    and 4 serving groups, one child process per point so each run gets a
-    fresh 8-host-device XLA platform. Efficiency is aggregate tokens/s
-    over `min(groups, cores)` times the 1-group rate — real scaling
-    where the host has the cores, a scheduler-overhead bound where it
-    does not (the row's `cores=` field says which was measured)."""
-    import json
-    import os
-    import subprocess
-    import sys
+    and 4 serving groups, in this process on the devices it has (one
+    process per chip; the gate needs >= 4 of them). Efficiency is
+    aggregate tokens/s over `min(groups, cores)` times the 1-group rate —
+    real scaling where the host has the cores, a scheduler-overhead bound
+    where it does not (the row's `cores=` field says which)."""
+    from repro.cluster import Cluster
 
     per_group = 24 if smoke else 48
-    rows = {}
-    env = dict(os.environ)
-    flags = env.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        env["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8").strip()
-    for g in (1, 2, 4):
-        out = subprocess.run(
-            [sys.executable, __file__, "--groups-child", str(g),
-             str(per_group * g), "5"],
-            capture_output=True, text=True, env=env, check=True)
-        rows[g] = json.loads(out.stdout.strip().splitlines()[-1])
+    cluster = Cluster(ARCH)
+    rows = {g: _measure_groups(cluster, g, per_group * g, 5)
+            for g in (1, 2, 4)}
     cores = rows[1]["cores"]
     tps = {g: rows[g]["tokens_per_s"] for g in (1, 2, 4)}
     eff = {g: tps[g] / (min(g, cores) * tps[1]) for g in (2, 4)}
@@ -579,10 +566,4 @@ def main(smoke: bool = False) -> list[str]:
 
 
 if __name__ == "__main__":
-    import sys
-
-    if len(sys.argv) > 1 and sys.argv[1] == "--groups-child":
-        _groups_child(int(sys.argv[2]), int(sys.argv[3]),
-                      int(sys.argv[4]) if len(sys.argv) > 4 else 5)
-    else:
-        print("\n".join(main(smoke=True)))
+    print("\n".join(main(smoke=True)))

@@ -27,9 +27,11 @@ Checks (each prints one `gate ok:`/`gate FAIL:` line; any FAIL exits 1):
           overhead stays under --recovery-tol percent)
           `groups` (serve/groups_scaling row: positive aggregate tok/s
           at 1/2/4 serving groups and efficiency >= 0.7 normalized by
-          attainable parallelism min(groups, cores); on hosts with >= 4
-          cores also monotone tok/s in group count and per-group stall
-          within 2x of single-group)
+          attainable parallelism min(groups, cores), measured with
+          every group on its own device (>= 4 devices: on a CPU host
+          XLA_FLAGS=--xla_force_host_platform_device_count=8); on hosts
+          with >= 4 cores also monotone tok/s in group count and
+          per-group stall within 2x of single-group)
   baseline (optional, vs a committed copy of BENCH_table1.json):
           decode K16 stall_pct must not rise more than --stall-tol
           percentage points; serve continuous occupancy_pct must not drop
@@ -198,6 +200,11 @@ def check_require(gate: Gate, record: dict, require: list[str],
             gr = _derived(by["serve/groups_scaling"])
             tps = {g: float(gr.get(f"tps{g}", 0)) for g in (1, 2, 4)}
             cores = int(gr.get("cores", 1))
+            devices = int(gr.get("devices", 0))
+            gate.check(devices >= 4, "groups",
+                       f"4 groups measured on {devices} devices (>= 4, one "
+                       f"a group; on a CPU host set XLA_FLAGS="
+                       f"--xla_force_host_platform_device_count=8)")
             gate.check(all(v > 0 for v in tps.values()), "groups",
                        f"positive aggregate tok/s at 1/2/4 groups "
                        f"({tps[1]:.0f}/{tps[2]:.0f}/{tps[4]:.0f})")

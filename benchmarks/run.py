@@ -46,6 +46,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 from repro.cluster import BenchProgram, Cluster  # noqa: E402
 from repro.cluster.policy import MODES  # noqa: E402
+from repro.runtime.compile_cache import use_persistent_cache  # noqa: E402
 
 from benchmarks import (bench_decode_throughput,  # noqa: E402
                         bench_fig4_interconnect, bench_fig5_hybrid,
@@ -165,6 +166,7 @@ def _persist_table1(results: dict, repeat: int) -> Path | None:
 
 
 def main(argv: list[str] | None = None) -> None:
+    use_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny shapes; every section in seconds")

@@ -24,6 +24,7 @@ from repro.checkpoint import CheckpointManager
 from repro.cluster import Cluster, TrainProgram
 from repro.core import compat
 from repro.runtime.coordination import Coordinator, replan_mesh_shape
+from repro.runtime.compile_cache import use_persistent_cache
 
 CKPT = "/tmp/repro-elastic"
 
@@ -39,6 +40,7 @@ def make_batches(cfg, seq, start):
 
 
 def main():
+    use_persistent_cache()
     seq = 32
     cluster = Cluster("qwen3-14b-smoke")
     cfg = cluster.arch

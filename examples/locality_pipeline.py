@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from repro.cluster import Cluster
 from repro.core.interconnect import TOP_H, TopologyModel
 from repro.kernels import ops
+from repro.runtime.compile_cache import use_persistent_cache
 
 
 def histogram_equalization(img: jax.Array, bins: int = 256) -> jax.Array:
@@ -41,6 +42,7 @@ def histogram_equalization(img: jax.Array, bins: int = 256) -> jax.Array:
 
 
 def main():
+    use_persistent_cache()
     key = jax.random.PRNGKey(0)
     # synthetic low-contrast image
     img = jnp.clip(

@@ -13,6 +13,9 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 from repro.cluster import Cluster, ServeProgram, TrainProgram
+from repro.runtime.compile_cache import use_persistent_cache
+
+use_persistent_cache()
 
 # 1. one object for the substrate: arch + mesh + addressing + kernel policy
 cluster = Cluster("qwen3-14b-smoke")

@@ -26,9 +26,11 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 import jax
 
 from repro.cluster import Cluster, ServeProgram
+from repro.runtime.compile_cache import use_persistent_cache
 
 
 def main():
+    use_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mixtral-8x7b")
     ap.add_argument("--batch", type=int, default=8)

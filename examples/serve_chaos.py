@@ -19,11 +19,12 @@ Prints a `# chaos:` summary line with fault/recovery counts.
 
 `--crash` is the crash-restart drill: a child process serves the same
 workload with the durability layer on (journal + periodic snapshots)
-and SIGKILLs itself mid-decode; the parent verifies the kill, restores
+and SIGKILLs itself mid-decode; the parent, which touches no device
+until then, verifies the kill, runs the fault-free reference, restores
 a session from the durable directory, drains it, and asserts that the
 union of journal-committed (pre-crash) and post-restore deliveries
-equals the fault-free reference exactly — every token once,
-bit-identical. Prints a `# chaos-crash:` line with the measured MTTR.
+equals the reference exactly — every token once, bit-identical. Prints
+a `# chaos-crash:` line with the measured MTTR.
 
     PYTHONPATH=src python examples/serve_chaos.py --crash
 """
@@ -43,6 +44,7 @@ import numpy as np
 
 from repro.cluster import Cluster, ServeSessionProgram
 from repro.runtime import FaultPlan, SessionWedged
+from repro.runtime.compile_cache import use_persistent_cache
 
 CLASS_MIX = ("latency", "throughput", "throughput", "best_effort")
 
@@ -112,19 +114,12 @@ def run_crash_child(args):
 
 
 def run_crash_drill(args):
-    """Parent side: fault-free reference, SIGKILL'd child, restore +
-    drain, exactly-once bit-identical verification."""
-    from repro.runtime.journal import read_events, replay
+    """Parent side: SIGKILL'd child, then the fault-free reference,
+    restore + drain, exactly-once bit-identical verification.
 
-    program, params, prompts, out_lens = crash_setup(args)
-    print("reference run (fault-free, in-process):")
-    ref = program.open(params=params)
-    ref_handles = [ref.submit(p, int(n))
-                   for p, n in zip(prompts, out_lens)]
-    ref.drain()
-    expected = {h.id: [int(t) for t in h.result()] for h in ref_handles}
-    print(f"  {len(expected)} done, "
-          f"{sum(len(t) for t in expected.values())} tokens")
+    The parent stays off JAX until the child has exited: a device may
+    belong to one process at a time, so the child must not find it held."""
+    from repro.runtime.journal import read_events, replay
 
     with tempfile.TemporaryDirectory() as d:
         child_args = [sys.executable, __file__, "--crash-child",
@@ -143,6 +138,16 @@ def run_crash_drill(args):
                   f"{-signal.SIGKILL} (SIGKILL) — crash never fired")
             raise SystemExit(1)
         print(f"  child killed -9, journal + snapshots left in {d}")
+
+        program, params, prompts, out_lens = crash_setup(args)
+        print("reference run (fault-free, in-process):")
+        ref = program.open(params=params)
+        ref_handles = [ref.submit(p, int(n))
+                       for p, n in zip(prompts, out_lens)]
+        ref.drain()
+        expected = {h.id: [int(t) for t in h.result()] for h in ref_handles}
+        print(f"  {len(expected)} done, "
+              f"{sum(len(t) for t in expected.values())} tokens")
 
         committed = {rid: list(r.committed) for rid, r in
                      replay(read_events(Path(d) / "journal.jsonl"))
@@ -182,6 +187,7 @@ def run_crash_drill(args):
 
 
 def main():
+    use_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="xlstm-125m")
     ap.add_argument("--slots", type=int, default=4)

@@ -33,9 +33,11 @@ import numpy as np
 
 from repro.cluster import (Cluster, ServeSessionProgram,
                            ShardedServeSessionProgram)
+from repro.runtime.compile_cache import use_persistent_cache
 
 
 def main():
+    use_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="xlstm-125m")
     ap.add_argument("--slots", type=int, default=4,

@@ -33,9 +33,11 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 import numpy as np
 
 from repro.cluster import Cluster, ShardedServeSessionProgram
+from repro.runtime.compile_cache import use_persistent_cache
 
 
 def main():
+    use_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-14b")
     ap.add_argument("--groups", type=int, default=4)

@@ -19,9 +19,11 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 from repro.cluster import Cluster, TrainProgram
 from repro.configs import get
+from repro.runtime.compile_cache import use_persistent_cache
 
 
 def main():
+    use_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=8)

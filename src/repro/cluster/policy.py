@@ -15,8 +15,10 @@ backend probe buried in `kernels/ops.py`. Both now live here:
                   producer-consumer route (kernels/fused.py) wherever a
                   block's norm kind allows it.
 * ``reference`` — the pure-jnp oracles from kernels/ref.py.
-* ``interpret`` — Pallas bodies forced through the interpreter even on TPU
-                  (off-TPU backends always interpret, whatever the mode).
+* ``interpret`` — Pallas bodies through the interpreter. For the CPU only
+                  (off-TPU backends always interpret, whatever the mode);
+                  on a TPU backend asking for it raises instead of hiding
+                  the device behind the interpreter.
 
 ``overrides`` refines single ops: a mode string re-routes that op only
 (``{"matmul": "reference"}``), a block dict pins its blocking for
@@ -83,6 +85,9 @@ class KernelPolicy:
             elif not isinstance(v, Mapping):
                 raise TypeError(f"override for {op!r} must be a mode string "
                                 f"or a block dict, got {type(v).__name__}")
+        if "interpret" in (self.mode, *self.overrides.values()):
+            _refuse_interpret_on_tpu(f"KernelPolicy({self.mode!r}, "
+                                     f"overrides={dict(self.overrides)!r})")
 
     # -- per-op resolution ----------------------------------------------------
     def mode_for(self, op: str) -> str:
@@ -96,13 +101,15 @@ class KernelPolicy:
         return dict(o) if isinstance(o, Mapping) else None
 
     def interpret_for(self, op: str) -> bool:
-        """Should `op`'s Pallas body run interpreted? Forced by the
-        ``interpret`` mode; always true off-TPU (numerics-identical, which is
-        what the allclose tests against ref.py verify)."""
-        if self.mode_for(op) == "interpret":
-            return True
+        """Should `op`'s Pallas body run interpreted? Always off-TPU
+        (numerics-identical, which is what the allclose tests against ref.py
+        verify); never on a TPU, where the ``interpret`` mode raises."""
         import jax
-        return jax.default_backend() != "tpu"
+        if jax.default_backend() != "tpu":
+            return True
+        if self.mode_for(op) == "interpret":
+            _refuse_interpret_on_tpu(f"the policy's mode for {op!r}")
+        return False
 
     @property
     def fused(self) -> bool:
@@ -175,6 +182,15 @@ class KernelPolicy:
         return repr(sorted((k, repr(v)) for k, v in d.items()))
 
 
+def _refuse_interpret_on_tpu(what: str) -> None:
+    import jax
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{what} asks for interpreted Pallas kernels on a TPU backend; "
+            f"interpret mode is for the CPU only (unset REPRO_INTERPRET / "
+            f"REPRO_KERNEL_POLICY=interpret on the chip)")
+
+
 # ----------------------------------------------------------------------------
 # The active-policy stack
 # ----------------------------------------------------------------------------
@@ -189,7 +205,7 @@ def _env_truthy(name: str) -> bool:
 def default_policy() -> KernelPolicy:
     """The ambient policy when no scope is active. ``REPRO_KERNEL_POLICY``
     selects the mode; ``REPRO_INTERPRET=1`` (the legacy env path) maps to
-    ``interpret``."""
+    ``interpret``, which raises on a TPU backend."""
     mode = os.environ.get("REPRO_KERNEL_POLICY", "").strip()
     if not mode:
         mode = "interpret" if _env_truthy("REPRO_INTERPRET") else "tuned"

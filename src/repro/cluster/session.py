@@ -469,7 +469,7 @@ class CompiledTrain(Program):
             self.step, state, feed, state_shardings=state_sh,
             train_chunk=self.chunk)
         try:
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 report = loop.run(
                     start_step=None if spec.resume else 0)
         finally:
@@ -636,19 +636,26 @@ class CompiledServeSession(Program):
         knobs, so no recompile (`None` keeps the spec's choice).
 
         `device` pins the session's params and pool state to one device
-        (the sharded session places each group on its own mesh slice);
-        `journal_group` tags every journal event with the owning group id
-        (see `runtime.Journal`). Both default to the single-session
-        behaviour: default device, untagged journal."""
+        (the sharded session places each group on its own mesh slice):
+        params drawn here and the pool are built on that device, never on
+        the default one and copied; `journal_group` tags every journal
+        event with the owning group id (see `runtime.Journal`). Both
+        default to the single-session behaviour: default device, untagged
+        journal."""
         from repro.runtime import ServeSession
 
         spec = self.spec
-        if params is None:
-            params = self.init_params()
         make_state = self._make_state
         if device is not None:
-            params = jax.device_put(params, device)
-            make_state = lambda: jax.device_put(self._make_state(), device)
+            def on_device(build):
+                with jax.default_device(device):
+                    return jax.device_put(build(), device)
+
+            params = (on_device(self.init_params) if params is None
+                      else jax.device_put(params, device))
+            make_state = lambda: on_device(self._make_state)
+        elif params is None:
+            params = self.init_params()
         kv = None
         if spec.paged:
             from repro.runtime.kvpool import PagedKV
@@ -842,12 +849,14 @@ class CompiledShardedServeSession(CompiledServeSession):
 
         spec = self.spec
         G = spec.groups
-        if params is None:
-            params = self.init_params()
         devices = cells.group_devices(self.cluster.mesh, G)
         # single distinct device (CPU smoke, groups=1): skip device_put so
-        # the cell is bit-identical to the unsharded session's
+        # the cell is bit-identical to the unsharded session's. Otherwise,
+        # without `params`, each group draws its own (same seed) on its
+        # own device, so no device holds a second copy
         distinct = len({id(d) for d in devices}) > 1
+        if params is None and not distinct:
+            params = self.init_params()
         plans = (list(faults) if isinstance(faults, (list, tuple))
                  else [faults] + [None] * (G - 1))
         if len(plans) != G:
@@ -905,7 +914,7 @@ class CompiledDryRun(Program):
                 session=spec.session, paged=spec.paged,
                 page_size=spec.page_size)
             t0 = time.time()
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 lowered = jax.jit(fn, in_shardings=in_sh,
                                   out_shardings=out_sh,
                                   donate_argnums=donate).lower(*args)

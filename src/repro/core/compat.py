@@ -1,144 +1,36 @@
-"""Version-bridging helpers over the JAX API surface this repo targets.
+"""Mesh constructors with the axis types this repo's sharding code expects.
 
-The codebase is written against the current jax mesh/sharding API
-(`jax.set_mesh`, `jax.sharding.AxisType`, `jax.shard_map`, the
-positional `AbstractMesh(shape, axis_names)` constructor).  Older
-installs (0.4.x) expose the same functionality under different names and
-signatures; everything that touches those entry points goes through this
-module so the rest of the code reads as if only the modern API existed.
+`jax.make_mesh` and `jax.sharding.AbstractMesh` default to `Explicit`
+axes, under which every op must agree on its operands' shardings
+(`dynamic_update_slice` of a replicated update into a sharded cache is a
+`ShardingTypeError`). The planner and the program cells here place arrays
+with `NamedSharding`s and `with_sharding_constraint` and leave the rest to
+GSPMD, which is what `Auto` axes mean. Every mesh goes through these two
+helpers so none is built with the other default by accident.
 """
 
 from __future__ import annotations
 
-import contextlib
-import inspect
 from typing import Any, Sequence
 
 import jax
 
-__all__ = ["make_mesh", "abstract_mesh", "shard_map", "set_mesh",
-           "pallas_hints", "pallas_compiler_params"]
+__all__ = ["make_mesh", "abstract_mesh"]
+
+
+def _auto(axis_names: Sequence[str]) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * len(tuple(axis_names))
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               *, devices: Sequence[Any] | None = None) -> jax.sharding.Mesh:
-    """`jax.make_mesh` with Auto axis types where the install supports them."""
-    kwargs: dict[str, Any] = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    params = inspect.signature(jax.make_mesh).parameters
-    if "axis_types" in params and hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (
-            (jax.sharding.AxisType.Auto,) * len(tuple(axis_names)))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    """`jax.make_mesh` with `Auto` axes."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=_auto(axis_names), devices=devices)
 
 
 def abstract_mesh(axis_shapes: Sequence[int],
                   axis_names: Sequence[str]) -> jax.sharding.AbstractMesh:
-    """AbstractMesh across both constructor generations."""
-    shapes, names = tuple(axis_shapes), tuple(axis_names)
-    try:
-        return jax.sharding.AbstractMesh(shapes, names)
-    except TypeError:
-        # 0.4.x signature: a tuple of (axis_name, axis_size) pairs.
-        return jax.sharding.AbstractMesh(tuple(zip(names, shapes)))
-
-
-def _resolve_shard_map():
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map
-    from jax.experimental.shard_map import shard_map as sm
-    return sm
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None,
-              **kwargs):
-    """`jax.shard_map`, translating `check_vma` to the legacy `check_rep`."""
-    sm = _resolve_shard_map()
-    params = inspect.signature(sm).parameters
-    if check_vma is not None:
-        if "check_vma" in params:
-            kwargs["check_vma"] = check_vma
-        elif "check_rep" in params:
-            kwargs["check_rep"] = check_vma
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
-
-
-@contextlib.contextmanager
-def set_mesh(mesh):
-    """`jax.set_mesh` where available; the legacy Mesh context otherwise.
-
-    Call sites pair this with `jax.jit(..., in_shardings=..., out_shardings=...)`
-    whose NamedShardings already carry the mesh, so the legacy fallback only
-    needs to provide an ambient mesh for with_sharding_constraint-style uses.
-    """
-    setter = getattr(jax, "set_mesh", None)
-    if setter is not None:
-        with setter(mesh):
-            yield
-    elif isinstance(mesh, jax.sharding.Mesh):
-        with mesh:
-            yield
-    else:                                   # AbstractMesh on a legacy install
-        yield
-
-
-# ----------------------------------------------------------------------------
-# Pallas pipelining hints
-# ----------------------------------------------------------------------------
-#
-# The hint surface of pallas_call drifts across releases: `cost_estimate`
-# moved from absent to a first-class kwarg, the TPU compiler-params class was
-# renamed (TPUCompilerParams -> CompilerParams), and explicit multiple-
-# buffering knobs (`num_stages` / `pipeline_depth`) exist only on some
-# versions. `pallas_hints` keeps only what the installed version accepts, so
-# kernel code states its full intent and older installs silently drop the
-# parts they cannot express (they are scheduling hints, never semantics).
-
-
-def _pallas_tpu_params_cls():
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None)
-    return cls if cls is not None else getattr(pltpu, "TPUCompilerParams")
-
-
-def _pallas_tpu_fields() -> frozenset:
-    return frozenset(
-        getattr(_pallas_tpu_params_cls(), "__dataclass_fields__", ()))
-
-
-def _pallas_call_params() -> frozenset:
-    from jax.experimental import pallas as pl
-    return frozenset(inspect.signature(pl.pallas_call).parameters)
-
-
-def pallas_hints(*, cost: dict | None = None, num_stages: int | None = None,
-                 dimension_semantics: Sequence[str] | None = None,
-                 ) -> tuple[dict, dict]:
-    """Split pipelining hints into what this install can express.
-
-    Returns ``(pallas_call kwargs, compiler-params kwargs)``. `cost` is a
-    dict of `pl.CostEstimate` fields (flops/bytes_accessed/transcendentals);
-    `num_stages` the desired multiple-buffering depth (2 = classic double
-    buffering). Unsupported hints are dropped — they only steer scheduling.
-    """
-    from jax.experimental import pallas as pl
-    call_kw: dict[str, Any] = {}
-    cp_kw: dict[str, Any] = {}
-    fields = _pallas_tpu_fields()
-    if dimension_semantics is not None and "dimension_semantics" in fields:
-        cp_kw["dimension_semantics"] = tuple(dimension_semantics)
-    if (cost is not None and hasattr(pl, "CostEstimate")
-            and "cost_estimate" in _pallas_call_params()):
-        call_kw["cost_estimate"] = pl.CostEstimate(**cost)
-    if num_stages is not None:
-        for field in ("num_stages", "pipeline_depth", "num_pipeline_stages"):
-            if field in fields:
-                cp_kw[field] = int(num_stages)
-                break
-    return call_kw, cp_kw
-
-
-def pallas_compiler_params(cp_kwargs: dict):
-    """TPU compiler-params object across both class generations."""
-    return _pallas_tpu_params_cls()(**cp_kwargs)
+    """`jax.sharding.AbstractMesh` with `Auto` axes."""
+    return jax.sharding.AbstractMesh(tuple(axis_shapes), tuple(axis_names),
+                                     axis_types=_auto(axis_names))

@@ -23,6 +23,8 @@ from typing import Sequence
 
 import jax
 
+from repro.core import compat
+
 # ----------------------------------------------------------------------------
 # Hardware constants (TPU v5e target, per task spec)
 # ----------------------------------------------------------------------------
@@ -130,9 +132,9 @@ def make_mesh(shape: Sequence[int], axis_names: Sequence[str]) -> jax.sharding.M
             f"{len(devices)} are visible. Set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={n} before importing jax "
             f"(see launch/dryrun.py).")
-    return jax.make_mesh(tuple(shape), tuple(axis_names))
+    return compat.make_mesh(shape, axis_names)
 
 
 def smoke_mesh(axis_names: Sequence[str] = ("data", "model")) -> jax.sharding.Mesh:
     """1-chip (or few-chip) mesh for CPU smoke tests — every axis size 1."""
-    return jax.make_mesh((1,) * len(axis_names), tuple(axis_names))
+    return compat.make_mesh((1,) * len(axis_names), axis_names)

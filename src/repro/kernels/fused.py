@@ -106,7 +106,9 @@ def traffic_rmsnorm_matmul(shapes: dict, blocks: dict,
     producer = _rn.traffic({"m": m, "d": k}, {"block_rows": bm}, dtype_bytes)
     return pp.fused_traffic(consumer, producer,
                             intermediate_bytes=float(m * k * dtype_bytes),
-                            extra_vmem=2 * k * dtype_bytes,
+                            # the scale, double-buffered, and the
+                            # prologue's normalized (bm, k) A tile
+                            extra_vmem=(2 * k + bm * k) * dtype_bytes,
                             refetch=n // bn)
 
 
@@ -241,7 +243,9 @@ def traffic_matmul_residual_add(shapes: dict, blocks: dict,
     bn = min(blocks["bn"], n)
     return pp.fused_traffic(consumer, producer,
                             intermediate_bytes=float(m * n * dtype_bytes),
-                            extra_vmem=2 * bm * bn * dtype_bytes)
+                            # the residual tile, double-buffered, and the
+                            # epilogue's f32 (bm, bn) sum
+                            extra_vmem=2 * bm * bn * dtype_bytes + 4 * bm * bn)
 
 
 pp.register(pp.KernelDef(

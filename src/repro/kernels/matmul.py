@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import mesh as hw
+
 from . import pipeline as pp
 
 
@@ -76,6 +78,15 @@ def matmul(a: jax.Array, b: jax.Array, *, bm: int | None = None,
 
 # -- pipeline-layer contract --------------------------------------------------
 
+def body_vmem(bm: int, bk: int, dtype_bytes: int) -> int:
+    """VMEM the compiler adds for the kernel body beyond the pipeline's
+    buffers: one more (bm, bk) A tile and an f32 (bm, 128) strip. Measured
+    by compiling for v5e and bisecting `vmem_limit_bytes` (4096x5120x5120
+    bf16 at four blockings); an upper bound at each, so a blocking the
+    autotuner admits is one the compiler accepts."""
+    return dtype_bytes * bm * bk + 4 * bm * hw.MXU_TILE
+
+
 def traffic(shapes: dict, blocks: dict, dtype_bytes: int = 4) -> pp.Traffic:
     m, n, k = shapes["m"], shapes["n"], shapes["k"]
     bm = min(blocks["bm"], m)
@@ -86,7 +97,8 @@ def traffic(shapes: dict, blocks: dict, dtype_bytes: int = 4) -> pp.Traffic:
     ideal = dtype_bytes * (m * k + k * n + m * n)
     vmem = (2 * dtype_bytes * (bm * bk + bk * bn)   # double-buffered operands
             + 2 * dtype_bytes * bm * bn             # output tile
-            + 4 * bm * bn)                          # f32 accumulator scratch
+            + 4 * bm * bn                           # f32 accumulator scratch
+            + body_vmem(bm, bk, dtype_bytes))
     return pp.Traffic(
         flops=2.0 * m * n * k,
         hbm_bytes=float(streamed),

@@ -48,7 +48,6 @@ from typing import Any, Callable, Iterator, Sequence
 import jax
 from jax.experimental import pallas as pl
 
-from repro.core import compat
 from repro.core import mesh as hw
 from repro.core.interconnect import TOP_H, TopologyModel
 from repro.launch.roofline import kernel_roofline
@@ -344,36 +343,18 @@ class KernelPipeline:
 
         return wrapped
 
-    def pipeline_stages(self, dtype_bytes: int = 4) -> int | None:
-        """CostEstimate-backed multiple-buffering hint for the grid pipeline.
-
-        Compute-bound kernels keep the classic 2 stages (block k+1's DMA
-        under block k's compute fully hides the memory term). Memory-bound
-        kernels want a deeper in-flight window — the TCDM-burst amortization
-        — so they get 3 stages when a third slot set still fits the VMEM
-        budget. None when the pipeline carries no cost model.
-        """
-        if self.cost is None:
-            return None
-        r = kernel_roofline(self.cost.flops, self.cost.hbm_bytes)
-        if r["memory_s"] <= r["compute_s"]:
-            return 2
-        slot = sum(t.bytes_per_step(dtype_bytes)
-                   for t in (*self.in_tiles, *self.extra_tiles,
-                             *self.out_tiles)
-                   if t.memory_space is None)
-        scratch = self.vmem_bytes(dtype_bytes) - 2 * slot
-        return 3 if 3 * slot + scratch <= VMEM_BUDGET_BYTES else 2
-
     def pallas_call(self, *, interpret: bool = False) -> Callable:
+        from jax.experimental.pallas import tpu as pltpu
         out_specs = tuple(t.block_spec() for t in self.out_tiles)
-        call_kw, cp_kw = compat.pallas_hints(
-            cost=(dict(flops=int(self.cost.flops),
-                       bytes_accessed=int(self.cost.hbm_bytes),
-                       transcendentals=int(self.cost.transcendentals))
-                  if self.cost is not None else None),
-            num_stages=self.pipeline_stages(),
-            dimension_semantics=self.dimension_semantics())
+        cost = (pl.CostEstimate(flops=int(self.cost.flops),
+                                bytes_accessed=int(self.cost.hbm_bytes),
+                                transcendentals=int(self.cost.transcendentals))
+                if self.cost is not None else None)
+        # compiled under the same VMEM limit the autotuner budgets against,
+        # so a blocking it admits is one the compiler accepts
+        params = pltpu.CompilerParams(
+            dimension_semantics=self.dimension_semantics(),
+            vmem_limit_bytes=VMEM_BUDGET_BYTES)
         return pl.pallas_call(
             self._hooked_body(),
             grid=tuple(a.size for a in self.grid),
@@ -382,9 +363,9 @@ class KernelPipeline:
             out_specs=out_specs if self.multi_out else out_specs[0],
             out_shape=self.out_shape,
             scratch_shapes=list(self.scratch),
-            compiler_params=compat.pallas_compiler_params(cp_kw),
-            interpret=interpret,
-            **call_kw)
+            compiler_params=params,
+            cost_estimate=cost,
+            interpret=interpret)
 
     def __call__(self, *operands, interpret: bool = False):
         return self.pallas_call(interpret=interpret)(*operands)
@@ -578,8 +559,12 @@ def mxu_align(dim: int) -> int:
 # Autotuner
 # ----------------------------------------------------------------------------
 
-# leave headroom under the physical VMEM for the compiler's own buffers
-VMEM_BUDGET_BYTES = int(hw.VMEM_BYTES * 0.75)
+# The scoped VMEM limit every kernel is compiled under (`vmem_limit_bytes`)
+# and the budget the autotuner admits blockings against: one number, so a
+# modeled pick is never one the compiler refuses. Half the physical VMEM
+# leaves the rest to XLA's own fusions around the kernel. (Left unset, the
+# limit would be the compiler's default scoped limit, 16 MiB on v5e.)
+VMEM_BUDGET_BYTES = hw.VMEM_BYTES // 2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -591,7 +576,7 @@ class TuneResult:
     default_blocks: dict[str, int]
     default_cost: CostBreakdown
     # timed-race results; 0.0 / "modeled" when the pick was score-only
-    # (frozen mode, no operand factory, or every race lane failed)
+    # (frozen mode, or no operand factory)
     measured_us: float = 0.0
     default_us: float = 0.0
     source: str = "modeled"
@@ -600,6 +585,9 @@ class TuneResult:
     # composition of primitive kernels beat every blocking, and tuned_call
     # dispatches the composition for this (kernel, shapes) cell instead
     route: str = "fused"
+    # race lanes that failed to compile or run: "<kernel> <blocks>:
+    # <error type>: <first line of the error>", one per lane
+    refused: tuple[str, ...] = ()
 
     @property
     def timed(self) -> bool:
@@ -645,6 +633,20 @@ class _RaceOutcome:
     default_s: float
     lanes: int
     route: str = "fused"
+    refused: tuple[str, ...] = ()
+
+
+class RaceFailed(RuntimeError):
+    """Every lane of a timed race failed to compile or run."""
+
+
+def _refusal(kernel: str, blocks: dict, err: Exception) -> str:
+    """One refused lane, reported: the policy's ``tune_lane_failures``
+    counter and the first line of the error, tagged with the lane."""
+    from repro.cluster.policy import current_policy
+    current_policy().bump("tune_lane_failures")
+    first = (str(err).strip().splitlines() or [type(err).__name__])[0]
+    return f"{kernel} {blocks}: {type(err).__name__}: {first}"
 
 
 # sentinel "blocks" dict the composition lane hands the injectable timer —
@@ -664,8 +666,11 @@ def _race(kernel: str, shapes: dict, candidates: Sequence[dict],
           warmup: int | None = None) -> _RaceOutcome | None:
     """Time each candidate blocking (plus the default) on device and return
     the measured winner; None when racing is impossible (no operand
-    factory for this kernel, operand synthesis failed, or every lane
-    errored) — the caller falls back to the modeled pick.
+    factory for this kernel, or operand synthesis failed) — the caller
+    keeps the modeled pick. A lane that fails to compile or run cannot
+    win; each such lane is reported (`_refusal`), and when every kernel
+    lane fails the race raises `RaceFailed` instead of handing back a
+    blocking nobody has seen run.
 
     When the descriptor carries an unfused `composition`, it races as one
     extra lane (timed with the `COMPOSITION_LANE` sentinel as its blocks
@@ -702,15 +707,18 @@ def _race(kernel: str, shapes: dict, candidates: Sequence[dict],
             seen.add(k)
             lanes.append(dict(b))
     times: list[float] = []
+    refused: list[str] = []
     for b in lanes:
         try:
             times.append(float(timer(lambda b=b: desc.wrapper(*operands, **b),
                                      b)))
-        except Exception:
-            times.append(float("inf"))      # a lane that won't run can't win
+        except Exception as e:              # a lane that won't run can't win
+            times.append(float("inf"))
+            refused.append(_refusal(kernel, b, e))
     best = min(range(len(lanes)), key=times.__getitem__)
     if not math.isfinite(times[best]):
-        return None
+        raise RaceFailed(f"every lane of the {kernel} race at {shapes} "
+                         f"failed:\n  " + "\n  ".join(refused))
     default_key = tuple(sorted(default_blocks.items()))
     default_s = next(t for b, t in zip(lanes, times)
                      if tuple(sorted(b.items())) == default_key)
@@ -720,14 +728,17 @@ def _race(kernel: str, shapes: dict, candidates: Sequence[dict],
         try:
             comp_s = float(timer(lambda: desc.composition(*operands),
                                  dict(COMPOSITION_LANE)))
-        except Exception:
+        except Exception as e:
             comp_s = float("inf")
+            refused.append(_refusal(kernel, dict(COMPOSITION_LANE), e))
     if comp_s < times[best]:
         return _RaceOutcome(blocks=lanes[best], measured_s=comp_s,
                             default_s=default_s,
-                            lanes=len(lanes) + comp_lanes, route="unfused")
+                            lanes=len(lanes) + comp_lanes, route="unfused",
+                            refused=tuple(refused))
     return _RaceOutcome(blocks=lanes[best], measured_s=times[best],
-                        default_s=default_s, lanes=len(lanes) + comp_lanes)
+                        default_s=default_s, lanes=len(lanes) + comp_lanes,
+                        refused=tuple(refused))
 
 
 def autotune(kernel: str, shapes: dict, *, dtype_bytes: int = 4,
@@ -762,10 +773,10 @@ def autotune(kernel: str, shapes: dict, *, dtype_bytes: int = 4,
         if t.vmem_bytes > vmem_budget:
             continue
         scored.append((score(t).total_s, dict(blocks)))
-    if not scored:                 # budget excluded everything: take smallest
-        blocks = next(iter(defn.tune_space(shapes)))
-        scored = [(score(defn.traffic(shapes, blocks, dtype_bytes)).total_s,
-                   dict(blocks))]
+    if not scored:
+        raise ValueError(
+            f"no {kernel} blocking at {shapes} fits the "
+            f"{vmem_budget / 2**20:.1f} MiB VMEM budget")
     scored.sort(key=lambda sc: sc[0])
     best_blocks = dict(scored[0][1])
     default = defn.default_blocks(shapes)
@@ -773,7 +784,7 @@ def autotune(kernel: str, shapes: dict, *, dtype_bytes: int = 4,
 
     resolved = tunedb.tune_mode(mode)
     measured_us = default_us = 0.0
-    source, raced, route = "modeled", 0, "fused"
+    source, raced, route, refused = "modeled", 0, "fused", ()
     if resolved == "timed":
         top_n = _env_int("REPRO_TUNE_TOPN", 3) if top_n is None else top_n
         outcome = _race(kernel, shapes,
@@ -784,6 +795,7 @@ def autotune(kernel: str, shapes: dict, *, dtype_bytes: int = 4,
             measured_us = outcome.measured_s * 1e6
             default_us = outcome.default_s * 1e6
             source, raced, route = "timed", outcome.lanes, outcome.route
+            refused = outcome.refused
             from repro.cluster.policy import current_policy
             current_policy().bump("tune_races")
 
@@ -794,7 +806,8 @@ def autotune(kernel: str, shapes: dict, *, dtype_bytes: int = 4,
                         default_blocks=dict(default),
                         default_cost=default_cost,
                         measured_us=measured_us, default_us=default_us,
-                        source=source, raced=raced, route=route)
+                        source=source, raced=raced, route=route,
+                        refused=refused)
     if register_record:
         from repro.configs import registry
         best_traffic = defn.traffic(shapes, best_blocks, dtype_bytes)

@@ -1,8 +1,11 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks device
-# count on first init). REPRO_DRYRUN_DEVICES overrides for scaled-down CI
-# runs (still before the jax import below).
+# The dry-run compiles for 512 *CPU* host devices; pinning the platform
+# keeps it off an attached TPU, which another process may hold.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST run before any other import (jax locks platform and
+# device count on first init). REPRO_DRYRUN_DEVICES overrides for
+# scaled-down CI runs (still before the jax import below).
 if os.environ.get("REPRO_DRYRUN_DEVICES"):
     os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
                                + os.environ["REPRO_DRYRUN_DEVICES"])
@@ -94,7 +97,8 @@ def _write(record: dict, out_dir: Path):
         name += f"__{record['variant']}"
     (out_dir / (name + ".json")).write_text(
         json.dumps(record, indent=2, default=float))
-    print(f"[dryrun] wrote {name}.json: status={record.get('status')}")
+    print(f"[dryrun] wrote {name}.json: status={record.get('status')} "
+          f"(compiled on the cpu platform)")
 
 
 def run_all(mesh_kinds: list[str], timeout: int = 3600,
@@ -103,7 +107,8 @@ def run_all(mesh_kinds: list[str], timeout: int = 3600,
     if only_missing:
         cells = [c for c in cells
                  if not (RESULTS / f"{c[0]}__{c[1]}__{c[2]}.json").exists()]
-    print(f"[dryrun] {len(cells)} cells to run")
+    print(f"[dryrun] {len(cells)} cells to run, one cpu-platform process "
+          f"each")
     procs: list[tuple] = []
     results = []
 
@@ -122,7 +127,8 @@ def run_all(mesh_kinds: list[str], timeout: int = 3600,
         drain()
         cmd = [sys.executable, "-m", "repro.launch.dryrun", "--arch", cell[0],
                "--shape", cell[1], "--mesh", cell[2]]
-        procs.append((subprocess.Popen(cmd), cell, time.time()))
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        procs.append((subprocess.Popen(cmd, env=env), cell, time.time()))
     drain(block_all=True)
     failed = [c for c, rc, _ in results if rc != 0]
     print(f"[dryrun] done; {len(failed)} failed: {failed}")

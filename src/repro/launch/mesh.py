@@ -16,15 +16,17 @@ from __future__ import annotations
 
 import jax
 
+from repro.core import compat
+
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return compat.make_mesh(shape, axes)
 
 
 def make_test_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """Scaled-down mesh for CI: 8 devices, same axis structure."""
     shape = (2, 2, 2) if multi_pod else (2, 4)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return compat.make_mesh(shape, axes)

@@ -21,9 +21,11 @@ from repro.cluster import Cluster, TrainProgram
 from repro.cluster.policy import MODES
 from repro.configs import get
 from repro.core import compat
+from repro.runtime.compile_cache import use_persistent_cache
 
 
 def main():
+    use_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",

@@ -682,10 +682,12 @@ def make_prefill_step(cfg, *, policy=None):
     return prefill_step
 
 
-def make_decode_step(cfg, max_seq: int = 1 << 30, *, policy=None):
+def make_decode_step(cfg, max_seq: int = 1 << 30, *, policy=None,
+                     logits: bool = False):
     """`max_seq` is the workload's logical context length; caches shorter
     than it (windowed archs) operate as rolling buffers. `policy` pins the
-    kernel policy the step traces under (None -> ambient).
+    kernel policy the step traces under (None -> ambient). `logits=True`
+    returns the (B, V) float32 logits in place of the greedy (B, 1) token.
 
     `batch["pos"]` is a scalar (all slots at the same position — the batch
     program) or a (B,) vector (per-slot positions — the continuous-batching
@@ -732,10 +734,11 @@ def make_decode_step(cfg, max_seq: int = 1 << 30, *, policy=None):
                     cache["rem"][f"rem{i}"], pos, ctx)
                 new_cache["rem"][f"rem{i}"] = c
         x = _final_norm(cfg, params, x)
-        logits = jnp.einsum("bsd,dv->bsv", x, params["unembed"],
-                            preferred_element_type=F32)[:, 0]
-        token = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
-        return new_cache, token
+        out = jnp.einsum("bsd,dv->bsv", x, params["unembed"],
+                         preferred_element_type=F32)[:, 0]
+        if logits:
+            return new_cache, out
+        return new_cache, jnp.argmax(out, axis=-1).astype(jnp.int32)[:, None]
 
     def decode_step(params, cache, batch):
         with kpolicy.scoped(pol):

@@ -6,17 +6,39 @@ fetch path is lower+compile. The cache memoizes AOT-compiled executables
 keyed on (step identity, arch, shapes, mesh, rules fingerprint), so elastic
 restarts and repeated launches never pay recompilation ("cold boot" is the
 paper's cache-refill phase; see bench Fig. 15).
+
+`use_persistent_cache` places JAX's on-disk compilation cache, which
+carries compiled programs across processes: the directory named by
+``JAX_COMPILATION_CACHE_DIR`` when the environment sets it, else a fixed
+directory inside the checkout. The path is part of each entry's key, so it
+never depends on a temp name, a pid or the time.
 """
 
 from __future__ import annotations
 
 import hashlib
 import json
+import os
+from pathlib import Path
 from typing import Any, Callable
 
 import jax
 
-from repro.core import compat
+CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def use_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is changed here. Otherwise the cache goes to `CHECKOUT_CACHE_DIR`
+    (``<checkout>/.jax_cache``, listed in .gitignore).
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(CHECKOUT_CACHE_DIR))
+    return str(CHECKOUT_CACHE_DIR)
 
 
 def _fingerprint(*parts: Any) -> str:
@@ -47,7 +69,7 @@ class CompileCache:
                else mesh.shape)
 
         def build():
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 return jax.jit(fn, in_shardings=in_shardings,
                                out_shardings=out_shardings,
                                donate_argnums=donate).lower(*args_sds).compile()

@@ -129,6 +129,36 @@ def test_compile_cache_hits():
     assert cache.hits == 2 and cache.misses == 1 and len(calls) == 1
 
 
+@pytest.fixture
+def _restore_cache_dir():
+    saved = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", saved)
+
+
+def test_persistent_cache_honours_env(monkeypatch, tmp_path,
+                                      _restore_cache_dir):
+    from repro.runtime import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.use_persistent_cache() == str(tmp_path)
+    # jax reads the variable itself; the helper changes nothing
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_persistent_cache_defaults_to_fixed_checkout_dir(monkeypatch,
+                                                         _restore_cache_dir):
+    from pathlib import Path
+
+    from repro.runtime import compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    first = compile_cache.use_persistent_cache()
+    assert compile_cache.use_persistent_cache() == first
+    repo = Path(__file__).resolve().parents[1]
+    assert first == str(repo / ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == first
+
+
 def test_coordinator_and_replan():
     coord = Coordinator(n_hosts=64)
     seen = []

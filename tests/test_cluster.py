@@ -89,6 +89,34 @@ def test_interpret_mode_matches_env_path():
     np.testing.assert_array_equal(np.asarray(got_policy), np.asarray(got_env))
 
 
+_ASK_FOR_INTERPRET = {
+    "mode": lambda mp: KernelPolicy(mode="interpret"),
+    "override": lambda mp: KernelPolicy(overrides={"matmul": "interpret"}),
+    "REPRO_INTERPRET": lambda mp: (mp.setenv("REPRO_INTERPRET", "1"),
+                                   default_policy()),
+    "REPRO_KERNEL_POLICY": lambda mp: (
+        mp.setenv("REPRO_KERNEL_POLICY", "interpret"), current_policy()),
+}
+
+
+@pytest.mark.parametrize("how", sorted(_ASK_FOR_INTERPRET))
+def test_interpret_refused_on_tpu_backend(monkeypatch, how):
+    """Interpret mode is for the CPU: on a TPU backend every way of asking
+    for it raises instead of running the interpreter behind the device."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="CPU only"):
+        _ASK_FOR_INTERPRET[how](monkeypatch)
+
+
+def test_tpu_backend_never_interprets(monkeypatch):
+    built_on_cpu = KernelPolicy(mode="interpret")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for mode in ("tuned", "fused"):
+        assert KernelPolicy(mode=mode).interpret_for("matmul") is False
+    with pytest.raises(RuntimeError, match="CPU only"):
+        built_on_cpu.interpret_for("matmul")
+
+
 def test_tuned_call_respects_policy():
     registry.KERNEL_TUNES.clear()
     a, b = rand(4, (48, 32)), rand(5, (32, 40))

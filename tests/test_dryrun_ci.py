@@ -32,7 +32,7 @@ SCRIPT = textwrap.dedent("""
     mesh = make_test_mesh(multi_pod=multi)
     rules = addressing.default_rules(mesh, overrides=cfg.rules_overrides)
     fn, args, in_sh, out_sh, donate = dr.build_cell(cfg, shape, mesh, rules)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
                            donate_argnums=donate).lower(*args).compile()
     costs = hlo_cost.analyze(compiled.as_text())

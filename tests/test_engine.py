@@ -5,7 +5,8 @@ the per-token host loop (tokens, EOS masking/early-stop, emitted_per_slot)
 while cutting host syncs from O(T) to O(T/K); steady-state decode chunks
 allocate no new device buffers (donation); the chunked train path matches
 the per-step loop and samples straggler/logging at chunk granularity; the
-StallClock ledger and the Pallas pipelining-hint compat layer behave.
+StallClock ledger behaves; every Pallas kernel is compiled under the VMEM
+limit the autotuner budgets against.
 """
 
 import time
@@ -16,7 +17,6 @@ import numpy as np
 import pytest
 
 from repro.cluster import Cluster, ServeProgram, TrainProgram
-from repro.core import compat
 from repro.models import steps
 from repro.runtime.engine import (DecodeEngine, StallClock, make_decode_chunk,
                                   make_train_chunk, stack_batches)
@@ -256,48 +256,48 @@ def test_serve_stats_report_stall_and_chunk():
 
 
 # ----------------------------------------------------------------------------
-# Pallas pipelining hints (compat-guarded)
+# Pallas compile hints: the VMEM limit the autotuner budgets against
 # ----------------------------------------------------------------------------
 
 
-def test_pallas_hints_filter_to_installed_surface():
-    call_kw, cp_kw = compat.pallas_hints(
-        cost={"flops": 100, "bytes_accessed": 10, "transcendentals": 0},
-        num_stages=3, dimension_semantics=("parallel", "arbitrary"))
-    # only knobs this install's pallas accepts survive
-    assert set(call_kw) <= compat._pallas_call_params()
-    assert set(cp_kw) <= compat._pallas_tpu_fields()
-    compat.pallas_compiler_params(cp_kw)    # must construct cleanly
-    if "cost_estimate" in compat._pallas_call_params():
-        assert "cost_estimate" in call_kw
-    none_call, none_cp = compat.pallas_hints()
-    assert none_call == {} and none_cp == {}
+def _captured_pallas_kwargs(monkeypatch, pipe) -> dict:
+    from repro.kernels import pipeline as pp
+    captured = {}
+
+    def fake_pallas_call(body, **kwargs):
+        captured.update(kwargs)
+        return lambda *operands: None
+
+    monkeypatch.setattr(pp.pl, "pallas_call", fake_pallas_call)
+    pipe.pallas_call()
+    return captured
 
 
-def test_pipeline_stages_heuristic():
-    from repro.kernels import axpy
+def test_pallas_call_compiles_under_the_autotuner_budget(monkeypatch):
+    """Every kernel is compiled under the scoped VMEM limit the autotuner
+    admits blockings against, so a modeled pick is one the compiler takes."""
+    from repro.kernels import matmul
     from repro.kernels import pipeline as pp
 
-    # axpy streams ~3 bytes/flop — memory-bound, wants a deeper window
-    p = axpy.build_pipeline(1024, 256, jnp.float32, block_rows=128)
-    assert p.pipeline_stages() == 3
-    # ...but not when a third slot set would bust the VMEM budget
-    p = axpy.build_pipeline(8192, 1024, jnp.float32, block_rows=4096)
-    assert p.pipeline_stages() == 2
+    pipe = matmul.build_pipeline(256, 512, 1024, jnp.bfloat16, bm=128,
+                                 bn=256, bk=512, dtype_bytes=2)
+    kw = _captured_pallas_kwargs(monkeypatch, pipe)
+    params = kw["compiler_params"]
+    assert params.vmem_limit_bytes == pp.VMEM_BUDGET_BYTES
+    assert params.dimension_semantics == ("parallel", "parallel", "arbitrary")
+    assert kw["grid"] == (2, 2, 2)
+    assert kw["cost_estimate"].flops == 2 * 256 * 512 * 1024
+    assert pipe.cost.vmem_bytes <= pp.VMEM_BUDGET_BYTES
 
-    def synthetic(cost):
-        tile = pp.TileSpec((128, 128), lambda i: (0, 0))
-        return pp.KernelPipeline(
-            "synthetic", lambda *refs: None, grid=(pp.GridAxis("i", 1),),
-            in_tiles=[tile], out_tiles=tile,
-            out_shape=jax.ShapeDtypeStruct((128, 128), jnp.float32),
-            cost=cost)
 
-    # compute-bound: classic double buffering already hides the transfers
-    compute = pp.Traffic(flops=1e12, hbm_bytes=1e6, ideal_bytes=1e6,
-                         grid_steps=1, vmem_bytes=0)
-    assert synthetic(compute).pipeline_stages() == 2
-    memory = pp.Traffic(flops=1e6, hbm_bytes=1e12, ideal_bytes=1e12,
-                        grid_steps=1, vmem_bytes=0)
-    assert synthetic(memory).pipeline_stages() == 3
-    assert synthetic(None).pipeline_stages() is None
+def test_pallas_call_without_cost_model_has_no_estimate(monkeypatch):
+    from repro.kernels import pipeline as pp
+
+    tile = pp.TileSpec((128, 128), lambda i: (0, 0))
+    pipe = pp.KernelPipeline(
+        "synthetic", lambda *refs: None, grid=(pp.GridAxis("i", 1),),
+        in_tiles=[tile], out_tiles=tile,
+        out_shape=jax.ShapeDtypeStruct((128, 128), jnp.float32))
+    kw = _captured_pallas_kwargs(monkeypatch, pipe)
+    assert kw["cost_estimate"] is None
+    assert kw["compiler_params"].vmem_limit_bytes == pp.VMEM_BUDGET_BYTES

@@ -81,7 +81,7 @@ def test_scan_bytes_linear_not_quadratic():
 def _sharded_text(n_dev, fn, arg_specs, in_specs, out_spec):
     import os
     mesh = compat.make_mesh((2, n_dev // 2), ("data", "model"))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return jax.jit(fn, in_shardings=in_specs,
                        out_shardings=out_spec).lower(*arg_specs).compile().as_text()
 

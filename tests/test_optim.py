@@ -90,11 +90,11 @@ def test_error_feedback_unbiased_over_time():
 def test_compressed_psum_under_shard_map():
     from functools import partial
     from jax.sharding import PartitionSpec as P
-    from repro.core.compat import make_mesh, shard_map
+    from repro.core.compat import make_mesh
     mesh = make_mesh((1,), ("data",))
     grads = {"w": jnp.ones((4,), jnp.float32)}
 
-    @partial(shard_map, mesh=mesh, in_specs=(P(),), out_specs=(P(), P()),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(),), out_specs=(P(), P()),
              check_vma=False)
     def run(g):
         return compressed_psum(g, "data", "bf16")

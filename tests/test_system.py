@@ -95,3 +95,106 @@ def test_region_plan_places_weights_and_state():
     # a batch tensor: sequential region (owner-local)
     assert rules.spec_for(("batch", "seq"), (8, 64), mesh) == \
         jax.sharding.PartitionSpec("data")
+
+
+def _chip_smoke_path():
+    from pathlib import Path
+    return Path(__file__).resolve().parents[1] / "chip_smoke.py"
+
+
+def _chip_smoke():
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location("chip_smoke",
+                                                  _chip_smoke_path())
+    cs = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(cs)
+    return cs
+
+
+def test_chip_smoke_refuses_the_cpu(tmp_path):
+    """chip_smoke.py has no CPU path: on the CPU platform it exits
+    non-zero and prints no result line."""
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, str(_chip_smoke_path())],
+                         capture_output=True, text=True, timeout=300,
+                         env=env, cwd=tmp_path)
+    assert out.returncode != 0
+    assert '"ok"' not in out.stdout
+    assert "needs a TPU backend" in out.stderr
+
+
+def test_chip_smoke_workload_guarantees_prefix_hits():
+    """The smoke workload's shape: 16 requests, prompts of 32-256 tokens,
+    answers of 32-64, half sharing a 128-token preamble; and the first
+    wave's sharing requests finish a whole chunk before any other
+    first-wave request, so their preamble pages are published before the
+    second wave is admitted."""
+    cs = _chip_smoke()
+    reqs = cs.workload(151936)
+    assert len(reqs) == cs.N_REQUESTS == 16
+    assert all(32 <= p.size <= cs.MAX_PROMPT for p, _ in reqs)
+    assert all(32 <= n <= 64 for _, n in reqs)
+    pre = reqs[0][0][:cs.PREAMBLE]
+    shares = [np.array_equal(p[:cs.PREAMBLE], pre) for p, _ in reqs]
+    assert sum(shares) == len(reqs) // 2
+    steps = lambda i: reqs[i][0].size + reqs[i][1] - 1
+    first = range(cs.SLOTS)
+    last_shared = max(steps(i) for i in first if shares[i])
+    first_other = min(steps(i) for i in first if not shares[i])
+    assert last_shared // cs.CHUNK < first_other // cs.CHUNK
+    assert any(shares[cs.SLOTS:])
+    assert cs.workload(151936)[5][0].tolist() == reqs[5][0].tolist()
+
+
+@pytest.mark.parametrize("fault", [None, "lost_ffn_residual"])
+def test_chip_smoke_fused_logits_check(monkeypatch, fault):
+    """The tuned-vs-fused logits check passes on the intact fused decode
+    path (rounding only) and fails on a wiring fault in it: a fused FFN
+    down-projection that drops its residual."""
+    from repro.models import blocks
+
+    cs = _chip_smoke()
+    cfg = get("qwen3-14b-smoke")
+    monkeypatch.setattr(cs, "MAX_SEQ", 64)
+    if fault:
+        real = blocks.fused_matmul_residual
+        monkeypatch.setattr(blocks, "fused_matmul_residual",
+                            lambda a, w, res: real(a, w, res * 0))
+    params = steps.init_params(cfg, jax.random.PRNGKey(0), max_seq=64)
+    rng = np.random.default_rng(0)
+    prompt, generated = (rng.integers(0, cfg.vocab, n) for n in (9, 3))
+    if fault:
+        with pytest.raises(cs.SmokeFailure, match="fused decode logits"):
+            cs.fused_logits_check(cfg, params, prompt, generated)
+    else:
+        cs.fused_logits_check(cfg, params, prompt, generated)
+
+
+def test_chip_smoke_first_difference():
+    cs = _chip_smoke()
+    a = [np.array([1, 2, 3]), np.array([4, 5, 6]), np.array([7, 8, 9])]
+    b = [np.array([1, 2, 3]), np.array([4, 0, 6]), np.array([0, 8, 9])]
+    assert cs.first_difference(a, b) == (1, 1)
+    assert cs.first_difference(a, a) == (0, 0)
+
+
+def test_decode_step_logits_match_greedy_token():
+    """`make_decode_step(logits=True)` returns the logits the greedy step
+    takes its argmax of, with the same cache update."""
+    cfg = get("qwen3-14b-smoke")
+    params = steps.init_params(cfg, jax.random.PRNGKey(1), max_seq=32)
+    cache = steps.init_cache(cfg, 2, steps.decode_cache_len(cfg, 32))
+    batch = {"tokens": jnp.array([[3], [5]], jnp.int32),
+             "pos": jnp.asarray(0, jnp.int32)}
+    c_tok, tok = steps.make_decode_step(cfg, max_seq=32)(params, cache, batch)
+    c_log, logits = steps.make_decode_step(cfg, max_seq=32, logits=True)(
+        params, cache, batch)
+    assert logits.shape == (2, cfg.vocab) and logits.dtype == jnp.float32
+    np.testing.assert_array_equal(np.argmax(logits, -1)[:, None], tok)
+    for x, y in zip(jax.tree.leaves(c_tok), jax.tree.leaves(c_log)):
+        np.testing.assert_array_equal(x, y)

@@ -95,25 +95,44 @@ def test_race_default_lane_can_win():
 
 
 def test_race_erroring_lane_cannot_win():
-    """A lane whose timer throws is scored inf; the survivors race on."""
+    """A lane whose timer throws is scored inf; the survivors race on. Each
+    refused lane is counted and reported with its error's first line."""
     default = pp.KERNELS["matmul"].default_blocks(SHAPES)
     default_key = tuple(sorted(default.items()))
 
     def timer(fn, blocks):
         if tuple(sorted(blocks.items())) != default_key:
-            raise RuntimeError("candidate refused to compile")
+            raise RuntimeError("candidate refused to compile\nsecond line")
         return 0.3
-    r = pp.autotune("matmul", SHAPES, mode="timed", timer=timer)
+    pol = KernelPolicy()
+    with use_policy(pol):
+        r = pp.autotune("matmul", SHAPES, mode="timed", timer=timer)
     assert r.source == "timed"
     assert r.blocks == dict(default)
+    assert len(r.refused) == r.raced - 1 >= 1
+    assert pol.stats["tune_lane_failures"] == len(r.refused)
+    assert all(line.startswith("matmul {") and line.endswith(
+        "RuntimeError: candidate refused to compile") for line in r.refused)
 
 
 def test_race_all_lanes_failing_falls_back_to_modeled():
+    """There is no fallback: when every lane fails, the race raises with
+    each lane's error instead of keeping a pick nobody has seen run."""
     def timer(fn, blocks):
         raise RuntimeError("no lane runs")
-    r = pp.autotune("matmul", SHAPES, mode="timed", timer=timer)
-    assert r.source == "modeled" and not r.timed and r.raced == 0
-    assert r.blocks == modeled_pick()
+    pol = KernelPolicy()
+    with use_policy(pol), pytest.raises(pp.RaceFailed,
+                                        match="no lane runs") as exc:
+        pp.autotune("matmul", SHAPES, mode="timed", timer=timer)
+    n = pol.stats["tune_lane_failures"]
+    assert n >= 2 and str(exc.value).count("no lane runs") == n
+    assert registry.get_kernel_tune("matmul", KEY) is None
+
+
+def test_autotune_raises_when_budget_admits_nothing():
+    with pytest.raises(ValueError, match="VMEM budget"):
+        pp.autotune("matmul", SHAPES, mode="modeled", vmem_budget=1024,
+                    register_record=False)
 
 
 def test_modeled_mode_never_races():
