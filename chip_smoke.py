@@ -274,8 +274,7 @@ def serve(program, params, reqs, label: str,
     kv = st.get("kv", {})
     print(f"serve {label}: {len(handles)} requests, "
           f"{st['emitted_total']} tokens in {wall:.2f} s "
-          f"({st['emitted_total'] / wall:.1f} tok/s wall clock, "
-          f"{st['tokens_per_s']:.1f} tok/s over post-warmup chunks) "
+          f"({st['emitted_total'] / wall:.1f} tok/s wall clock) "
           f"{BRING_UP}")
     print(f"serve {label}: TTFT p50 {st['ttft_ms']['p50']:.1f} ms p99 "
           f"{st['ttft_ms']['p99']:.1f} ms; stall "

@@ -90,10 +90,12 @@ def main():
         if not events and next_up < args.requests:
             time.sleep(min(0.005, max(arrivals[next_up] - now, 0.0)))
 
+    wall = time.perf_counter() - t0
     st = session.stats()
     stall = st["stall"]
     print(f"done: {st['requests_done']} requests, "
-          f"{st['emitted_total']} tokens at {st['tokens_per_s']:.1f} tok/s")
+          f"{st['emitted_total']} tokens in {wall:.2f} s "
+          f"({st['emitted_total'] / wall:.1f} tok/s wall clock)")
     print(f"slot occupancy {st['occupancy_pct']:.0f}%  "
           f"ttft p50={st['ttft_ms']['p50']:.0f}ms "
           f"p99={st['ttft_ms']['p99']:.0f}ms  "

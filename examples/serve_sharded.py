@@ -103,10 +103,12 @@ def main():
         if not events and next_up < args.requests:
             time.sleep(min(0.005, max(arrivals[next_up] - now, 0.0)))
 
+    wall = time.perf_counter() - t0
     st = session.stats()
     pl = st["placement"]
     print(f"\ndone: {st['requests_done']} requests, "
-          f"{st['emitted_total']} tokens at {st['tokens_per_s']:.1f} tok/s "
+          f"{st['emitted_total']} tokens in {wall:.2f} s "
+          f"({st['emitted_total'] / wall:.1f} tok/s wall clock) "
           f"across {st['n_groups']} groups")
     print(f"placement: {pl['placed']} per group — "
           f"{pl['locality_hits']}/{pl['placements']} placements had warm "

@@ -448,15 +448,16 @@ def update_cache(k_cache, v_cache, k_new, v_new, pos, *, rolling: bool = False):
     """
     sc = k_cache.shape[1]
     slot = jnp.asarray(pos) % sc if rolling else jnp.asarray(pos)
-    if slot.ndim == 0:
-        k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k_new, slot,
-                                                      axis=1)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v_new, slot,
-                                                      axis=1)
-        return k_cache, v_cache
-    upd = jax.vmap(
-        lambda c, n, s: jax.lax.dynamic_update_slice_in_dim(c, n, s, axis=0))
-    return upd(k_cache, k_new, slot), upd(v_cache, v_new, slot)
+    with jax.named_scope("kv_write"):
+        if slot.ndim == 0:
+            k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k_new,
+                                                          slot, axis=1)
+            v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v_new,
+                                                          slot, axis=1)
+            return k_cache, v_cache
+        upd = jax.vmap(lambda c, n, s:
+                       jax.lax.dynamic_update_slice_in_dim(c, n, s, axis=0))
+        return upd(k_cache, k_new, slot), upd(v_cache, v_new, slot)
 
 
 # ----------------------------------------------------------------------------
@@ -484,8 +485,9 @@ def paged_update_cache(k_pool, v_pool, k_new, v_new, pos, pages):
     page_idx = jnp.take_along_axis(pages, (pos_b // ps)[:, None],
                                    axis=1)[:, 0]            # (B,)
     off = pos_b % ps
-    k_pool = k_pool.at[page_idx, off].set(k_new[:, 0])
-    v_pool = v_pool.at[page_idx, off].set(v_new[:, 0])
+    with jax.named_scope("kv_write"):
+        k_pool = k_pool.at[page_idx, off].set(k_new[:, 0])
+        v_pool = v_pool.at[page_idx, off].set(v_new[:, 0])
     return k_pool, v_pool
 
 
@@ -496,7 +498,8 @@ def paged_gather(pool, pages):
     `idx < pos` mask gives them exactly-zero softmax weight."""
     b, npp = pages.shape
     _, ps, kv, hd = pool.shape
-    return pool[pages].reshape(b, npp * ps, kv, hd)
+    with jax.named_scope("kv_gather"):
+        return pool[pages].reshape(b, npp * ps, kv, hd)
 
 
 def paged_decode_attention(q, k_pool, v_pool, pos, pages, *, n_kv: int):

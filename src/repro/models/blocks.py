@@ -167,32 +167,35 @@ def attn_block_decode(cfg, p, x, cache, pos, ctx, *, window=None):
     paged = _paged(ctx, window)
     rolling = (not paged and bool(window)
                and cache["k"].shape[1] < ctx["max_seq"])
-    if _fused_rms(cfg):
-        q, k, v = _fused_qkv(cfg, p, x, ctx)
-    else:
-        q, k, v = qkv_project(p["attn"], _norm(cfg, p, "ln_attn", x),
-                              ctx["positions"], n_heads=cfg.n_heads,
-                              n_kv_heads=cfg.n_kv_heads, head_dim=cfg.hd,
-                              qkv_bias=cfg.qkv_bias, qk_norm=cfg.qk_norm,
-                              rope=ctx.get("rope", True),
-                              theta=cfg.rope_theta)
-    if paged:
-        kc, vc = attn_lib.paged_update_cache(cache["k"], cache["v"], k, v,
-                                             pos, ctx["pages"])
-        o = attn_lib.paged_decode_attention(q, kc, vc, pos + 1, ctx["pages"],
-                                            n_kv=cfg.n_kv_heads)
-    else:
-        kc, vc = attn_lib.update_cache(cache["k"], cache["v"], k, v, pos,
-                                       rolling=rolling)
-        o = attn_lib.decode_attention(q, kc, vc, pos + 1,
-                                      n_kv=cfg.n_kv_heads,
-                                      window=window, rolling=rolling)
-    if _fused_rms(cfg):
-        x = _fused_out_residual(p, o, x)
-    else:
-        x = x + out_project(p["attn"], o)
+    with jax.named_scope("attention"):
+        if _fused_rms(cfg):
+            q, k, v = _fused_qkv(cfg, p, x, ctx)
+        else:
+            q, k, v = qkv_project(p["attn"], _norm(cfg, p, "ln_attn", x),
+                                  ctx["positions"], n_heads=cfg.n_heads,
+                                  n_kv_heads=cfg.n_kv_heads, head_dim=cfg.hd,
+                                  qkv_bias=cfg.qkv_bias, qk_norm=cfg.qk_norm,
+                                  rope=ctx.get("rope", True),
+                                  theta=cfg.rope_theta)
+        if paged:
+            kc, vc = attn_lib.paged_update_cache(cache["k"], cache["v"], k,
+                                                 v, pos, ctx["pages"])
+            o = attn_lib.paged_decode_attention(q, kc, vc, pos + 1,
+                                                ctx["pages"],
+                                                n_kv=cfg.n_kv_heads)
+        else:
+            kc, vc = attn_lib.update_cache(cache["k"], cache["v"], k, v, pos,
+                                           rolling=rolling)
+            o = attn_lib.decode_attention(q, kc, vc, pos + 1,
+                                          n_kv=cfg.n_kv_heads,
+                                          window=window, rolling=rolling)
+        if _fused_rms(cfg):
+            x = _fused_out_residual(p, o, x)
+        else:
+            x = x + out_project(p["attn"], o)
     if cfg.d_ff:
-        x = _ffn_residual(cfg, p, x)
+        with jax.named_scope("ffn"):
+            x = _ffn_residual(cfg, p, x)
     return x, {"k": kc, "v": vc}
 
 
@@ -335,23 +338,27 @@ def moe_block_decode(cfg, p, x, cache, pos, ctx):
     paged = _paged(ctx, cfg.window)
     rolling = (not paged and bool(cfg.window)
                and cache["k"].shape[1] < ctx["max_seq"])
-    q, k, v = qkv_project(p["attn"], _norm(cfg, p, "ln_attn", x),
-                          ctx["positions"], n_heads=cfg.n_heads,
-                          n_kv_heads=cfg.n_kv_heads, head_dim=cfg.hd,
-                          qkv_bias=cfg.qkv_bias, qk_norm=cfg.qk_norm,
-                          theta=cfg.rope_theta)
-    if paged:
-        kc, vc = attn_lib.paged_update_cache(cache["k"], cache["v"], k, v,
-                                             pos, ctx["pages"])
-        o = attn_lib.paged_decode_attention(q, kc, vc, pos + 1, ctx["pages"],
-                                            n_kv=cfg.n_kv_heads)
-    else:
-        kc, vc = attn_lib.update_cache(cache["k"], cache["v"], k, v, pos,
-                                       rolling=rolling)
-        o = attn_lib.decode_attention(q, kc, vc, pos + 1, n_kv=cfg.n_kv_heads,
-                                      window=cfg.window, rolling=rolling)
-    x = x + out_project(p["attn"], o)
-    y, _ = moe_apply(cfg, p["moe"], _norm(cfg, p, "ln_ffn", x))
+    with jax.named_scope("attention"):
+        q, k, v = qkv_project(p["attn"], _norm(cfg, p, "ln_attn", x),
+                              ctx["positions"], n_heads=cfg.n_heads,
+                              n_kv_heads=cfg.n_kv_heads, head_dim=cfg.hd,
+                              qkv_bias=cfg.qkv_bias, qk_norm=cfg.qk_norm,
+                              theta=cfg.rope_theta)
+        if paged:
+            kc, vc = attn_lib.paged_update_cache(cache["k"], cache["v"], k,
+                                                 v, pos, ctx["pages"])
+            o = attn_lib.paged_decode_attention(q, kc, vc, pos + 1,
+                                                ctx["pages"],
+                                                n_kv=cfg.n_kv_heads)
+        else:
+            kc, vc = attn_lib.update_cache(cache["k"], cache["v"], k, v, pos,
+                                           rolling=rolling)
+            o = attn_lib.decode_attention(q, kc, vc, pos + 1,
+                                          n_kv=cfg.n_kv_heads,
+                                          window=cfg.window, rolling=rolling)
+        x = x + out_project(p["attn"], o)
+    with jax.named_scope("ffn"):
+        y, _ = moe_apply(cfg, p["moe"], _norm(cfg, p, "ln_ffn", x))
     return x + y, {"k": kc, "v": vc}
 
 

@@ -733,9 +733,10 @@ def make_decode_step(cfg, max_seq: int = 1 << 30, *, policy=None,
                     cfg, params["rem"][f"rem{i}"], x,
                     cache["rem"][f"rem{i}"], pos, ctx)
                 new_cache["rem"][f"rem{i}"] = c
-        x = _final_norm(cfg, params, x)
-        out = jnp.einsum("bsd,dv->bsv", x, params["unembed"],
-                         preferred_element_type=F32)[:, 0]
+        with jax.named_scope("unembed"):
+            x = _final_norm(cfg, params, x)
+            out = jnp.einsum("bsd,dv->bsv", x, params["unembed"],
+                             preferred_element_type=F32)[:, 0]
         if logits:
             return new_cache, out
         return new_cache, jnp.argmax(out, axis=-1).astype(jnp.int32)[:, None]

@@ -404,9 +404,7 @@ class ShardedServeSession:
     def stats(self) -> dict:
         """Aggregate serving stats plus the per-group breakdown.
 
-        Counters sum across groups; `tokens_per_s` is the sum of the
-        groups' windowed rates (they run concurrently); `occupancy_pct`
-        is slot-weighted; `stall` is the `StallClock.merge` roll-up of
+        Counters sum across groups; `occupancy_pct` is slot-weighted; `stall` is the `StallClock.merge` roll-up of
         the per-group ledgers (one shared wall, counters summed);
         `placement` is the mesh scheduler's ledger; `groups` maps gid to
         that group's full `ServeSession.stats()`.
@@ -423,7 +421,6 @@ class ShardedServeSession:
                                       for st in per.values()),
             "requests_shed": sum(st["requests_shed"] for st in per.values()),
             "emitted_total": sum(st["emitted_total"] for st in per.values()),
-            "tokens_per_s": sum(st["tokens_per_s"] for st in per.values()),
             "occupancy_pct": occ / max(slots, 1),
             "slots": slots,
             "usable_slots": sum(st["usable_slots"] for st in per.values()),

@@ -586,6 +586,18 @@ class PagedKV:
         self.checksums = {}
         self._scrub_cursor = 0
 
+    def page_gauges(self) -> tuple[int, int]:
+        """(pages some slot's table addresses, shared pages once; pages
+        only the prefix cache holds). A page in use is referenced by slots,
+        by the cache, or by both, or is fenced off by quarantine with no
+        reference left, so the cache's own pages are what remains of the
+        used ones once the slots' and the fenced ones are taken out."""
+        held = len(set().union(*self._slot_table))
+        owned = len(set().union(*self._slot_owned))
+        fenced = sum(1 for p in self.pool.quarantined
+                     if self.pool.refcount[p] == 0)
+        return held, self.pool.used_pages - owned - fenced
+
     def slot_pages(self, slot: int) -> list[int]:
         """The page ids the slot's device table addresses (table order)."""
         return list(self._slot_table[slot])

@@ -103,7 +103,8 @@ class Request:
     state: str = QUEUED
     slot: int | None = None
     tokens: list = dataclasses.field(default_factory=list)
-    started_at: float | None = None
+    started_at: float | None = None         # latest grant of a slot
+    admitted_at: float | None = None        # first grant of a slot
     first_token_at: float | None = None
     finished_at: float | None = None
     hit_eos: bool = False

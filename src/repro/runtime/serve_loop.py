@@ -60,6 +60,7 @@ from repro.runtime.scheduler import (CANCELLED, CLASSES, DONE, FAILED, QUEUED,
                                      REASON_RETRIES, REASON_SHED, RUNNING,
                                      Request, RequestHandle, SlotScheduler,
                                      deserialize_request, serialize_request)
+from repro.runtime.telemetry import recorder
 
 
 class ServeLoop:
@@ -351,7 +352,6 @@ class ServeSession:
         self._busy_steps = 0
         self._total_steps = 0
         self._emitted_total = 0
-        self._per_chunk_emitted: deque[int] = deque(maxlen=HISTORY)
         self._ttfts: deque[float] = deque(maxlen=HISTORY)
         self._latencies: deque[float] = deque(maxlen=HISTORY)
         self._n_done = 0
@@ -390,6 +390,11 @@ class ServeSession:
         # serving-group id stamped on every journal event (sharded
         # sessions; None leaves the single-group format untouched)
         self._journal_group = journal_group
+        # spans of every poll and a record per request, in the process's
+        # recorder (runtime/telemetry.py)
+        self._tel = recorder().session(
+            group=journal_group,
+            kv_pages=None if kv is None else kv.pool.n_pages - 1)
         if self._durable_dir is not None:
             self._durable_dir.mkdir(parents=True, exist_ok=True)
             if resume:
@@ -464,6 +469,7 @@ class ServeSession:
                 "prompt": prompt.tolist(),
                 "max_new": int(max_new), "klass": klass,
                 "deadline_s": deadline_s})
+        self._tel.request(req)
         handle = RequestHandle(req)
         if not handle.done:             # the submission itself may have
             self.handles[req.rid] = handle      # been shed under overload
@@ -619,6 +625,27 @@ class ServeSession:
         return kept
 
     def _admit_and_refill(self, events: list) -> None:
+        with self._tel.span("serve.retire"):
+            self._retire(events)
+        with self._tel.span("serve.admit"):
+            if self.preempt:
+                self._preempt_for_latency()
+            admits = self.scheduler.admit()
+            if not admits and not self._pending_deactivate:
+                return
+            fresh = [(s, r) for s, r in admits if r.snapshot is None]
+            resumed = [(s, r) for s, r in admits if r.snapshot is not None]
+            kv_fresh = []
+            if self.kv is not None and fresh:
+                kv_fresh = self._alloc_pages(fresh, events)
+                fresh = [(s, r) for s, r, _ in kv_fresh]
+        with self._tel.span("serve.refill"):
+            self._refill(fresh, resumed, kv_fresh)
+
+    def _retire(self, events: list) -> None:
+        """Free the slots of finished and cancelled requests (a clean
+        finish publishes its prompt pages first), surface shed requests,
+        and dispatch the scrub of freed dirty pages."""
         for slot, req in list(self.scheduler.running_requests()):
             if req.state != RUNNING:            # cancelled mid-flight
                 self._pending_release.add(slot)
@@ -635,10 +662,7 @@ class ServeSession:
                     if self._page_read_fn is not None:
                         pp = self.kv.publishable_pages(slot)
                         if pp:
-                            arrs = self._page_read_fn(
-                                self.state, np.asarray(pp, np.int32))
-                            digests = dict(
-                                zip(pp, page_digests(arrs, len(pp))))
+                            digests = dict(zip(pp, self._page_digests(pp)))
                     self.kv.publish(slot, digests=digests)
                 self.kv.release(slot)
         self._pending_release.clear()
@@ -651,20 +675,14 @@ class ServeSession:
             if dirty:
                 self.state = self._page_scrub_fn(
                     self.state, np.asarray(dirty, np.int32))
-        if self.preempt:
-            self._preempt_for_latency()
-        admits = self.scheduler.admit()
-        if not admits and not self._pending_deactivate:
-            return
+
+    def _refill(self, fresh: list, resumed: list, kv_fresh: list) -> None:
+        """Install the admitted requests (and the pending deactivations)
+        in the device state: refill, copy-on-write page copies, restores
+        of preempted slots. A failure un-admits the round."""
         release = np.zeros(self.n_slots, bool)
         if self._pending_deactivate:
             release[sorted(self._pending_deactivate)] = True
-        fresh = [(s, r) for s, r in admits if r.snapshot is None]
-        resumed = [(s, r) for s, r in admits if r.snapshot is not None]
-        kv_fresh = []
-        if self.kv is not None and fresh:
-            kv_fresh = self._alloc_pages(fresh, events)
-            fresh = [(s, r) for s, r, _ in kv_fresh]
         granted = fresh + resumed       # still slot-assigned after alloc
         try:
             if self._faults is not None:
@@ -707,6 +725,10 @@ class ServeSession:
                 req.snapshot = None
             self._pending_deactivate.clear()
             self._refill_failures = 0
+            for _, req in granted:
+                if req.admitted_at is None:
+                    req.admitted_at = req.started_at
+                    self._tel.request(req)
             if self._journal is not None:
                 for slot, req in granted:
                     self._journal.append({"ev": "admit", "rid": req.rid,
@@ -726,7 +748,7 @@ class ServeSession:
                 raise
 
     def _watchdog_wait(self, arrays, timeout: float, chunk_idx: int,
-                       wedge: bool) -> None:
+                       wedge: bool) -> float:
         """Bound the device wait: block_until_ready runs on a watchdog
         thread while the driver waits at most `timeout` seconds. An
         injected wedge simply never finishes the wait — exactly what a
@@ -747,7 +769,7 @@ class ServeSession:
             raise SessionWedged(chunk_idx, timeout, self.clock.report())
         if errs:
             raise errs[0]
-        self.clock.sync_done(t0)
+        return self.clock.sync_done(t0)
 
     def _handle_idle_queue(self, events: list) -> None:
         """Nothing running but work queued: either the pool is fully
@@ -815,8 +837,16 @@ class ServeSession:
         pages = [int(p) for p in pages]
         if not pages or self._page_read_fn is None:
             return []
-        arrs = self._page_read_fn(self.state, np.asarray(pages, np.int32))
-        return self.kv.verify(pages, page_digests(arrs, len(pages)))
+        return self.kv.verify(pages, self._page_digests(pages))
+
+    def _page_digests(self, pages: list[int]) -> list[bytes]:
+        """Read `pages` back to the host and digest their content: one
+        ``serve.page_read`` span."""
+        with self._tel.span("serve.page_read", pages=len(pages)) as sp:
+            arrs = self._page_read_fn(self.state,
+                                      np.asarray(pages, np.int32))
+            sp.attrs["bytes"] = sum(int(a.nbytes) for a in arrs)
+            return page_digests(arrs, len(pages))
 
     def _inject_bit_flip(self, page: int | None) -> None:
         """Scripted silent-corruption fault: perturb one KV page on
@@ -985,6 +1015,11 @@ class ServeSession:
         if self._wedged:
             raise RuntimeError("session is wedged; call recover_wedged() "
                                "before polling again")
+        self._tel.chunk = self._chunk_index
+        with self._tel.span("serve.poll"):
+            return self._poll(timeout_s)
+
+    def _poll(self, timeout_s: float | None) -> list:
         # scripted silent corruption lands *before* admission, so the
         # admit-time checksum verify — not luck — must catch it before
         # the page is shared with a new request
@@ -1010,17 +1045,49 @@ class ServeSession:
                 mask = np.zeros(self.n_slots, bool)
                 mask[corrupted] = True
                 self.state = self._get_corrupt_fn()(self.state, mask)
-        t0 = self.clock.dispatch()
-        self.state, toks, emit, busy, _all_done = self._chunk_fn(
-            self.params, self.state)
-        self._chunk_index += 1
-        wedge = self._faults is not None and self._faults.wedged(chunk_idx)
-        if timeout is None:
-            self.clock.sync(toks, emit, busy)
-        else:
-            self._watchdog_wait((toks, emit, busy), timeout, chunk_idx,
-                                wedge)
-        dt = time.perf_counter() - t0
+        gauges = {}             # the page pool as the chunk dispatches
+        if self.kv is not None:
+            held, cached_only = self.kv.page_gauges()
+            gauges = {"pages_held": held, "pages_cached_only": cached_only}
+        with self._tel.chunk_annotation():
+            t0 = self.clock.dispatch()
+            self.state, toks, emit, busy, _all_done = self._chunk_fn(
+                self.params, self.state)
+            self._chunk_index += 1
+            wedge = (self._faults is not None
+                     and self._faults.wedged(chunk_idx))
+            if timeout is None:
+                t1 = self.clock.sync(toks, emit, busy)
+            else:
+                t1 = self._watchdog_wait((toks, emit, busy), timeout,
+                                         chunk_idx, wedge)
+        with self._tel.span("serve.harvest"):
+            self._harvest(chunk_idx, t1 - t0, toks, emit, busy, events)
+        self._tel.record("serve.chunk", t0, t1, **gauges)
+        # background integrity scrub: re-verify a bounded round-robin
+        # slice of the stamped (shared) pages each chunk; a bad page is
+        # quarantined and its cached chain dropped, so the prefix
+        # recomputes on next use instead of spreading. Runs after harvest
+        # (admit-time verify is the first line of defense — the scrub
+        # covers pages no admission is currently touching).
+        if (self.kv is not None and self._page_read_fn is not None
+                and self._scrub_pages):
+            with self._tel.span("serve.scrub"):
+                cand = self.kv.scrub_candidates(self._scrub_pages)
+                for page in self._verify_pages(cand):
+                    self.kv.quarantine_page(page)
+        if self._journal is not None:
+            with self._tel.span("serve.journal"):
+                self._commit()
+        if self._faults is not None and self._faults.crashed(chunk_idx):
+            if self._crash_hook is not None:
+                self._crash_hook(chunk_idx)     # e.g. SIGKILL ourselves
+            raise SessionCrashed(chunk_idx)
+        return events
+
+    def _harvest(self, chunk_idx: int, dt: float, toks, emit, busy,
+                 events: list) -> None:
+        """Turn the chunk's tokens into events, after fault detection."""
         toks, emit, busy = (np.asarray(toks), np.asarray(emit),
                             np.asarray(busy))
         now = time.perf_counter()
@@ -1048,6 +1115,7 @@ class ServeSession:
             if new.size:
                 if req.first_token_at is None:
                     req.first_token_at = now
+                    self._tel.request(req)
                     self._ttfts.append(now - req.submitted_at)
                     self._class_stats[req.klass]["ttfts"].append(
                         now - req.submitted_at)
@@ -1095,37 +1163,21 @@ class ServeSession:
                             "ev": "finish", "rid": req.rid,
                             "status": "done", "reason": None})
         self._emitted_total += n_emitted
-        self._per_chunk_emitted.append(n_emitted)
-        # background integrity scrub: re-verify a bounded round-robin
-        # slice of the stamped (shared) pages each chunk; a bad page is
-        # quarantined and its cached chain dropped, so the prefix
-        # recomputes on next use instead of spreading. Runs after harvest
-        # (admit-time verify is the first line of defense — the scrub
-        # covers pages no admission is currently touching).
-        if (self.kv is not None and self._page_read_fn is not None
-                and self._scrub_pages):
-            cand = self.kv.scrub_candidates(self._scrub_pages)
-            for page in self._verify_pages(cand):
-                self.kv.quarantine_page(page)
-        if self._journal is not None:
-            # one fsync per chunk: everything above becomes durable before
-            # the events are handed to the caller
-            self._journal.commit()
-        # periodic bit-exact snapshot, taken at the end of the poll: the
-        # device is already synced by the harvest, so the capture's
-        # device_get costs no pipeline overlap, and every event of this
-        # chunk is committed at the same boundary — snapshot + journal
-        # tail always describe a consistent state
+
+    def _commit(self) -> None:
+        """One fsync per chunk: the poll's journal records become durable
+        before its events are handed to the caller. Then the periodic
+        bit-exact snapshot, at the end of the poll: the device is already
+        synced by the harvest, so the capture's device_get costs no
+        pipeline overlap, and every event of this chunk is committed at
+        the same boundary — snapshot + journal tail always describe a
+        consistent state."""
+        self._journal.commit()
         if (self._snapshot_every and self._durable_dir is not None
                 and self._chunk_index > 0
                 and self._chunk_index % self._snapshot_every == 0
                 and self._chunk_index != self._last_snapshot_chunk):
             self._save_snapshot()
-        if self._faults is not None and self._faults.crashed(chunk_idx):
-            if self._crash_hook is not None:
-                self._crash_hook(chunk_idx)     # e.g. SIGKILL ourselves
-            raise SessionCrashed(chunk_idx)
-        return events
 
     @property
     def busy(self) -> bool:
@@ -1155,16 +1207,12 @@ class ServeSession:
         `occupancy_pct` is live-slot-steps over total slot-steps — the
         slot-pool analogue of the paper's PE-utilization figure; `ttft_ms`
         and `latency_ms` are per-request percentiles (chunk-granular, over
-        the last `HISTORY` requests); `tokens_per_s` counts emitted tokens
-        across all slots over the post-warmup chunk walls (same window);
-        `stall` is the StallClock ledger. Counters (`requests_done`,
-        `emitted_total`, ...) cover the whole session lifetime.
+        the last `HISTORY` requests); `stall` is the StallClock ledger.
+        Counters (`requests_done`, `emitted_total`, ...) cover the whole
+        session lifetime; `compiles` counts the programs the whole process
+        has compiled (or loaded from the compilation cache) so far, so on a
+        warm server it stays flat.
         """
-        rows = list(self.chunk_latencies)
-        lat = np.asarray([dt for dt, _ in rows[1:]], np.float64)
-        emitted = np.asarray(list(self._per_chunk_emitted)[1:], np.int64)
-        tok_s = (float(emitted.sum() / max(lat.sum(), 1e-9))
-                 if lat.size else 0.0)
         pct = lambda xs, q: (float(np.percentile(np.asarray(xs), q))
                              if len(xs) else 0.0)
         ttfts, lats = list(self._ttfts), list(self._latencies)
@@ -1191,7 +1239,6 @@ class ServeSession:
             "requests_shed": sum(cs["shed"]
                                  for cs in self._class_stats.values()),
             "emitted_total": self._emitted_total,
-            "tokens_per_s": tok_s,
             "occupancy_pct": 100.0 * self._busy_steps / max(total, 1),
             "ttft_ms": {"p50": pct(ttfts, 50) * 1e3,
                         "p99": pct(ttfts, 99) * 1e3},
@@ -1208,6 +1255,7 @@ class ServeSession:
             "slots": self.n_slots,
             "chunk": self.chunk,
             "stall": self.clock.report(),
+            "compiles": self._tel.rec.compiles,
         }
         if self.kv is not None:
             out["kv"] = dict(self.kv.stats(),
