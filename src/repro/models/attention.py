@@ -469,7 +469,9 @@ def update_cache(k_cache, v_cache, k_new, v_new, pos, *, rolling: bool = False):
 def paged_update_cache(k_pool, v_pool, k_new, v_new, pos, pages):
     """Scatter (B, 1, KV, hd) new keys/values through per-slot page tables.
 
-    Pools are (n_pages, page_size, KV, hd) — the whole session shares them;
+    Pools are (n_pages, page_size, KV, hd) — the whole session shares them
+    (inside the decode step's layer scan, the flat view of every layer's
+    pool, with `pages` offset into the layer's region: `steps.py`);
     `pages` is the (B, pages_per_slot) int32 table and `pos` the scalar or
     (B,) decode position. Slot b's token lands at
     `pool[pages[b, pos_b // page_size], pos_b % page_size]` — the paged

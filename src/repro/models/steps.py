@@ -682,6 +682,51 @@ def make_prefill_step(cfg, *, policy=None):
     return prefill_step
 
 
+def _paged_layer_scan(cfg, super_layer, x, blocks_params, blocks_cache, ctx):
+    """The decode step's layer scan over a paged cache, with the pool in
+    the scan's carry.
+
+    As scan xs/ys, a stacked pool leaf (n_super, n_pages, ps, KV, hd)
+    would be sliced out whole per layer and written back whole into a new
+    stacked buffer: three pool copies a step for one row a slot. Carried
+    instead as one flat (n_super * n_pages, ps, KV, hd) view (merging the
+    leading axes is a bitcast), layer l's pages are `pages + l * n_pages`,
+    so the row scatter and the page gather address the carried buffer in
+    place. Layer l's trash page is `l * n_pages`, inside its own region.
+    Private leaves (windowed buffers, cross K/V) stay scan xs/ys."""
+    leaves, treedef = jax.tree.flatten(blocks_cache)
+    is_pool = treedef.flatten_up_to(paged_cache_mask(cfg, 1, 1)["blocks"])
+
+    def split(cs):
+        return ([c for c, m in zip(cs, is_pool) if m],
+                [c for c, m in zip(cs, is_pool) if not m])
+
+    def merge(pools, private):
+        pools, private = iter(pools), iter(private)
+        return treedef.unflatten([next(pools) if m else next(private)
+                                  for m in is_pool])
+
+    pools, private = split(leaves)
+    n_super = leaves[0].shape[0]
+    n_pages = pools[0].shape[1] if pools else 0
+
+    def body(carry, scanned):
+        x, pools = carry
+        layer_params, private, layer = scanned
+        layer_ctx = dict(ctx, pages=ctx["pages"] + layer * n_pages)
+        x, new_cache = super_layer(x, layer_params, merge(pools, private),
+                                   layer_ctx)
+        pools, private = split(treedef.flatten_up_to(new_cache))
+        return (x, pools), private
+
+    flat = [c.reshape(n_super * n_pages, *c.shape[2:]) for c in pools]
+    (x, flat), private = jax.lax.scan(
+        body, (x, flat),
+        (blocks_params, private, jnp.arange(n_super, dtype=jnp.int32)))
+    return x, merge([f.reshape(c.shape) for f, c in zip(flat, pools)],
+                    private)
+
+
 def make_decode_step(cfg, max_seq: int = 1 << 30, *, policy=None,
                      logits: bool = False):
     """`max_seq` is the workload's logical context length; caches shorter
@@ -714,17 +759,23 @@ def make_decode_step(cfg, max_seq: int = 1 << 30, *, policy=None,
         ctx = {"positions": positions, "rope": cfg.family != "encdec",
                "max_seq": max_seq, "pages": batch.get("pages")}
 
-        def super_body(x, scanned):
-            layer_params, layer_cache = scanned
+        def super_layer(x, layer_params, layer_cache, layer_ctx):
             new_cache = {}
             for i, kind in enumerate(pattern):
                 x, c = BLOCKS[kind]["decode"](cfg, layer_params[f"sub{i}"], x,
-                                              layer_cache[f"sub{i}"], pos, ctx)
+                                              layer_cache[f"sub{i}"], pos,
+                                              layer_ctx)
                 new_cache[f"sub{i}"] = c
             return x, new_cache
 
-        x, new_blocks = jax.lax.scan(super_body, x,
-                                     (params["blocks"], cache["blocks"]))
+        if ctx["pages"] is None:
+            x, new_blocks = jax.lax.scan(
+                lambda x, lc: super_layer(x, *lc, ctx), x,
+                (params["blocks"], cache["blocks"]))
+        else:
+            x, new_blocks = _paged_layer_scan(cfg, super_layer, x,
+                                              params["blocks"],
+                                              cache["blocks"], ctx)
         new_cache: dict[str, Any] = {"blocks": new_blocks}
         if remainder:
             new_cache["rem"] = {}

@@ -9,6 +9,8 @@ StallClock ledger behaves; every Pallas kernel is compiled under the VMEM
 limit the autotuner budgets against.
 """
 
+import math
+import re
 import time
 
 import jax
@@ -182,6 +184,37 @@ def test_model_decode_parity_and_donation():
     assert (re1["stats"]["emitted_per_slot"]
             == re4["stats"]["emitted_per_slot"])
     assert re1["stats"]["finished_slots"] == re4["stats"]["finished_slots"]
+
+
+_HLO_RESULT = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\(", re.M)
+
+
+def test_paged_chunk_program_copies_no_pool():
+    """The compiled paged session chunk touches the KV pool only through
+    the row scatter and the page gather: no `copy`, `dynamic-slice` or
+    `dynamic-update-slice`, fused or not, yields a whole pool leaf or one
+    layer of it (sizes compared by element count, so a bitcast view of
+    the pool counts too). The pool's page count is prime so that no other
+    buffer of the program matches by accident."""
+    from repro.cluster import ServeSessionProgram
+
+    cluster = Cluster("qwen3-14b-smoke")
+    prog = cluster.compile(ServeSessionProgram(
+        slots=4, max_seq=48, max_prompt=16, chunk=4, paged=True,
+        page_size=4, n_pages=53))
+    state = prog._make_state()
+    pool = state["cache"]["blocks"]["sub0"]["k"]
+    n_super, n_pages = pool.shape[:2]
+    assert n_super >= 2 and n_pages == 53
+    sizes = {pool.size, pool.size // n_super}
+    text = prog._chunk_fn.lower(prog.init_params(), state).compile().as_text()
+    ops = [(dims, op) for dims, op in _HLO_RESULT.findall(text)
+           if math.prod(int(d) for d in dims.split(",") if d) in sizes]
+    assert ops, "the pool's own buffers must be found"
+    bad = [(dims, op) for dims, op in ops
+           if op in ("copy", "dynamic-slice", "dynamic-update-slice")]
+    assert not bad, bad
 
 
 # ----------------------------------------------------------------------------

@@ -20,6 +20,7 @@ tentpole rests on, checked here:
   private layout's KV capacity still serves the full slot complement.
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -297,6 +298,74 @@ def test_paged_bit_identical_on_prefix_free_workload(programs):
     for a, b in zip(toks_p, toks_g):
         np.testing.assert_array_equal(a, b)
     assert st["kv"]["alloc_failures"] == 0
+
+
+@pytest.mark.parametrize("arch,n_layers", [
+    (ARCH, None),
+    # 2 scanned periods of 4 pooled attn layers + 1 cross layer whose
+    # K/V stay private, then 2 unscanned remainder attn layers
+    ("llama-3.2-vision-90b-smoke", 12),
+])
+def test_every_layer_writes_its_own_pool_region(arch, n_layers):
+    """Mid-generation, every pool leaf's layer l holds, at each live
+    slot's pages, exactly the K/V rows the private-cache session holds in
+    its layer l — a write into another layer's region fails here even
+    where the tokens happen to agree; private leaves next to the pool
+    leaves match the private run as they are."""
+    import dataclasses
+
+    from repro.cluster.session import Cluster, ServeSessionProgram
+    from repro.configs import get
+    from repro.models import steps
+
+    cfg = get(arch)
+    if n_layers:
+        cfg = dataclasses.replace(cfg, n_layers=n_layers)
+    _, n_super, remainder = steps.block_plan(cfg)
+    assert n_super >= 2
+    cluster = Cluster(cfg)
+    common = dict(slots=4, max_seq=48, max_prompt=16, chunk=4)
+    private = cluster.compile(ServeSessionProgram(preempt=False, **common))
+    paged = cluster.compile(ServeSessionProgram(paged=True, page_size=4,
+                                                **common))
+    params = private.init_params()
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(1, 50, size=int(rng.integers(2, 10)))
+               .astype(np.int32) for _ in range(4)]
+    sessions = [prog.open(params=params) for prog in (private, paged)]
+    for sess in sessions:
+        for p in prompts:
+            sess.submit(p, 20)
+        for _ in range(3):                       # 12 steps: all still live
+            sess.poll()
+    sp, sg = (s.state for s in sessions)
+    pos = np.asarray(sg["pos"])
+    np.testing.assert_array_equal(pos, np.asarray(sp["pos"]))
+    assert pos.min() > 4                          # rows on several pages
+    rows = np.arange(pos.max())
+    tables = np.asarray(sg["pages"])
+    mask = steps.paged_cache_mask(cfg, 1, 1)
+    groups = [("blocks", True)] + ([("rem", False)] if remainder else [])
+    n_pool = 0
+    for group, stacked in groups:
+        for (path, m), want, got in zip(
+                jax.tree.leaves_with_path(mask[group]),
+                jax.tree.leaves(sp["cache"][group]),
+                jax.tree.leaves(sg["cache"][group])):
+            want, got = np.asarray(want), np.asarray(got)
+            if not m:
+                np.testing.assert_array_equal(got, want, err_msg=str(path))
+                continue
+            n_pool += 1
+            if not stacked:
+                want, got = want[None], got[None]
+            for l in range(want.shape[0]):
+                for b in range(len(pos)):
+                    r = rows[:pos[b]]
+                    np.testing.assert_array_equal(
+                        got[l][tables[b, r // 4], r % 4], want[l][b, r],
+                        err_msg=f"{group}{path} layer {l} slot {b}")
+    assert n_pool >= 2
 
 
 def test_shared_prefix_skips_prefill_bit_identically(programs):
