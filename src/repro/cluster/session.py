@@ -106,6 +106,9 @@ class ServeSessionProgram:
     sessions run with preemption off (slot snapshots do not carry page
     tables) and require an arch with positional attention (windowed /
     recurrent-only archs keep their private layout and reject `paged`).
+    An arch whose layers also keep recurrent state (Falcon-H1's Mamba-2
+    mixer) pages its K/V but reuses no prefix: shared pages cannot restore
+    the state of the tokens they hold.
     """
 
     slots: int = 4                         # slot-pool size (batch rows)
@@ -661,7 +664,8 @@ class CompiledServeSession(Program):
             from repro.runtime.kvpool import PagedKV
             kv = PagedKV(self._n_pages, spec.page_size, spec.slots,
                          self._pages_per_slot,
-                         prefix_cache=spec.prefix_cache)
+                         prefix_cache=spec.prefix_cache,
+                         recurrent=steps.recurrent_state(self.cluster.arch))
         sess = ServeSession(self._chunk_fn, self._refill_fn, params,
                             make_state(),
                             n_slots=spec.slots, chunk=spec.chunk,

@@ -1,4 +1,4 @@
-"""Architecture configs — the ten assigned architectures + the paper's own.
+"""Architecture configs — the assigned architectures + the paper's own.
 
 Each config is exact per the assignment table; `smoke()` returns a reduced
 same-family variant for CPU tests. `input_specs()` returns ShapeDtypeStruct
@@ -30,6 +30,7 @@ class ArchConfig:
     qk_norm: bool = False
     rope_theta: float = 1e4
     norm: str = "rms"            # rms | layer
+    norm_eps: float = 1e-6       # RMSNorm epsilon
     ffn_kind: str = "swiglu"
     # --- MoE ---
     n_experts: int = 0
@@ -48,6 +49,25 @@ class ArchConfig:
     # --- recurrent dims ---
     lru_width: int = 0
     conv_width: int = 4
+    # --- Mamba-2 mixer (the "attn_ssm" block): heads of ssm_head_dim
+    # channels, each a (ssm_head_dim, ssm_state) state; B and C are shared
+    # by the heads of each of ssm_groups groups ---
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    # --- published muP multipliers (Falcon-H1); 1.0 leaves a block as it
+    # is. ssm_mults scale the in-projection's z, x, B, C and dt segments,
+    # mlp_mults the FFN's gate pre-activation and its output ---
+    embed_mult: float = 1.0
+    lm_head_mult: float = 1.0
+    attn_in_mult: float = 1.0
+    attn_out_mult: float = 1.0
+    key_mult: float = 1.0
+    ssm_in_mult: float = 1.0
+    ssm_out_mult: float = 1.0
+    ssm_mults: tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    mlp_mults: tuple[float, float] = (1.0, 1.0)
     # --- numerics / memory policy ---
     param_dtype: str = "bfloat16"
     moment_dtype: str = "float32"
@@ -71,6 +91,10 @@ class ArchConfig:
     @property
     def hd(self) -> int:
         return self.head_dim or (self.d_model // self.n_heads)
+
+    @property
+    def d_ssm(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
 
     def n_params(self) -> int:
         """Total parameter count (for MODEL_FLOPS = 6 N D)."""
@@ -193,6 +217,19 @@ LLAMA32_VISION_90B = _reg(ArchConfig(
     n_heads=64, n_kv_heads=8, d_ff=28672, vocab=128256, rope_theta=5e5,
     cross_every=5, n_img_tokens=1601, pattern=("attn",), grad_accum=16))
 
+FALCON_H1_34B = _reg(ArchConfig(
+    name="falcon-h1-34b", family="hybrid", n_layers=72, d_model=5120,
+    n_heads=20, n_kv_heads=4, head_dim=128, d_ff=21504, vocab=261120,
+    rope_theta=1e11, norm_eps=1e-5, pattern=("attn_ssm",), conv_width=4,
+    ssm_heads=32, ssm_head_dim=128, ssm_state=256, ssm_groups=2,
+    embed_mult=5.656854249492381, lm_head_mult=0.0078125,
+    attn_in_mult=1.0, attn_out_mult=0.0375,
+    key_mult=0.011048543456039804, ssm_in_mult=0.25,
+    ssm_out_mult=0.08838834764831845,
+    ssm_mults=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+               0.3535533905932738),
+    mlp_mults=(0.1767766952966369, 0.011160714285714284), grad_accum=8))
+
 
 # the paper's own evaluation target: a 256-PE kernel cluster; used by the
 # Table-1 benchmarks rather than the LM pipeline.
@@ -300,6 +337,10 @@ def smoke(cfg: ArchConfig) -> ArchConfig:
         n_img_tokens=8 if cfg.n_img_tokens else 0,
         window=min(cfg.window, 16) if cfg.window else None,
         lru_width=64 if cfg.lru_width else 0,
+        ssm_heads=4 if cfg.ssm_heads else 0,
+        ssm_head_dim=16 if cfg.ssm_head_dim else 0,
+        ssm_state=16 if cfg.ssm_state else 0,
+        ssm_groups=min(cfg.ssm_groups, 2),
         attn_chunk=8,
         grad_accum=1,
         moment_dtype="float32",

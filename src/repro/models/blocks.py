@@ -6,6 +6,10 @@ Each kind provides:
   <kind>_cache_specs(cfg, B, cache_len)  -> ParamSpec tree    decode state
   <kind>_decode(cfg, p, x, cache, pos, ctx) -> (x, cache)     one token
 
+A kind whose decode state carries the request's history beyond its K/V
+rows (a recurrence, a conv tail) is registered `recurrent`: such state
+cannot be rebuilt from shared prefix pages.
+
 `ctx` carries positions and cross-attention context (encoder/image embeds).
 Aux is the MoE load-balancing loss contribution (0.0 elsewhere).
 """
@@ -16,6 +20,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.cluster.policy import current_policy
 from repro.core.overlap import shard_batch
@@ -38,7 +43,7 @@ def _norm_specs(cfg, name: str) -> dict:
 
 def _norm(cfg, p, name: str, x):
     if cfg.norm == "rms":
-        return rms_norm(x, p[name])
+        return rms_norm(x, p[name], cfg.norm_eps)
     return layer_norm(x, p[name + "_s"], p[name + "_b"])
 
 
@@ -162,11 +167,29 @@ def _paged(ctx, window) -> bool:
     return ctx.get("pages") is not None and not window
 
 
-def attn_block_decode(cfg, p, x, cache, pos, ctx, *, window=None):
-    window = window if window is not None else cfg.window
+def _attend_decode(cfg, q, k, v, cache, pos, ctx, window):
+    """One token's self-attention over the slot's K/V: the new row is
+    written (through the page table when the session pages, else into the
+    private or rolling buffer), then positions 0..pos are attended.
+    Returns (o, new k cache, new v cache)."""
     paged = _paged(ctx, window)
     rolling = (not paged and bool(window)
                and cache["k"].shape[1] < ctx["max_seq"])
+    if paged:
+        kc, vc = attn_lib.paged_update_cache(cache["k"], cache["v"], k, v,
+                                             pos, ctx["pages"])
+        o = attn_lib.paged_decode_attention(q, kc, vc, pos + 1, ctx["pages"],
+                                            n_kv=cfg.n_kv_heads)
+    else:
+        kc, vc = attn_lib.update_cache(cache["k"], cache["v"], k, v, pos,
+                                       rolling=rolling)
+        o = attn_lib.decode_attention(q, kc, vc, pos + 1, n_kv=cfg.n_kv_heads,
+                                      window=window, rolling=rolling)
+    return o, kc, vc
+
+
+def attn_block_decode(cfg, p, x, cache, pos, ctx, *, window=None):
+    window = window if window is not None else cfg.window
     with jax.named_scope("attention"):
         if _fused_rms(cfg):
             q, k, v = _fused_qkv(cfg, p, x, ctx)
@@ -177,18 +200,7 @@ def attn_block_decode(cfg, p, x, cache, pos, ctx, *, window=None):
                                   qkv_bias=cfg.qkv_bias, qk_norm=cfg.qk_norm,
                                   rope=ctx.get("rope", True),
                                   theta=cfg.rope_theta)
-        if paged:
-            kc, vc = attn_lib.paged_update_cache(cache["k"], cache["v"], k,
-                                                 v, pos, ctx["pages"])
-            o = attn_lib.paged_decode_attention(q, kc, vc, pos + 1,
-                                                ctx["pages"],
-                                                n_kv=cfg.n_kv_heads)
-        else:
-            kc, vc = attn_lib.update_cache(cache["k"], cache["v"], k, v, pos,
-                                           rolling=rolling)
-            o = attn_lib.decode_attention(q, kc, vc, pos + 1,
-                                          n_kv=cfg.n_kv_heads,
-                                          window=window, rolling=rolling)
+        o, kc, vc = _attend_decode(cfg, q, k, v, cache, pos, ctx, window)
         if _fused_rms(cfg):
             x = _fused_out_residual(p, o, x)
         else:
@@ -335,27 +347,13 @@ def moe_block_apply(cfg, p, x, ctx):
 
 
 def moe_block_decode(cfg, p, x, cache, pos, ctx):
-    paged = _paged(ctx, cfg.window)
-    rolling = (not paged and bool(cfg.window)
-               and cache["k"].shape[1] < ctx["max_seq"])
     with jax.named_scope("attention"):
         q, k, v = qkv_project(p["attn"], _norm(cfg, p, "ln_attn", x),
                               ctx["positions"], n_heads=cfg.n_heads,
                               n_kv_heads=cfg.n_kv_heads, head_dim=cfg.hd,
                               qkv_bias=cfg.qkv_bias, qk_norm=cfg.qk_norm,
                               theta=cfg.rope_theta)
-        if paged:
-            kc, vc = attn_lib.paged_update_cache(cache["k"], cache["v"], k,
-                                                 v, pos, ctx["pages"])
-            o = attn_lib.paged_decode_attention(q, kc, vc, pos + 1,
-                                                ctx["pages"],
-                                                n_kv=cfg.n_kv_heads)
-        else:
-            kc, vc = attn_lib.update_cache(cache["k"], cache["v"], k, v, pos,
-                                           rolling=rolling)
-            o = attn_lib.decode_attention(q, kc, vc, pos + 1,
-                                          n_kv=cfg.n_kv_heads,
-                                          window=cfg.window, rolling=rolling)
+        o, kc, vc = _attend_decode(cfg, q, k, v, cache, pos, ctx, cfg.window)
         x = x + out_project(p["attn"], o)
     with jax.named_scope("ffn"):
         y, _ = moe_apply(cfg, p["moe"], _norm(cfg, p, "ln_ffn", x))
@@ -850,6 +848,186 @@ def slstm_block_decode(cfg, p, x, cache, pos, ctx):
 
 
 # ============================================================================
+# Parallel attention + Mamba-2 block ("attn_ssm") — Falcon-H1
+# ============================================================================
+#
+# Every layer runs GQA attention and a Mamba-2 mixer side by side on one
+# normed input, then a SwiGLU FFN (transformers' modeling_falcon_h1), with
+# the published muP multipliers where the model applies them:
+#
+#   h = rmsnorm(x)
+#   x = x + attn(h * attn_in) * attn_out + mamba(h) * ssm_out
+#       (attn's keys scaled by key_mult)
+#   x = x + down(silu(gate(n) * mlp_mults[0]) * up(n)) * mlp_mults[1],
+#       n = rmsnorm(x)
+#
+# mamba: zxbcdt = in_proj(h * ssm_in) * mup, the five ssm_mults laid over
+# the z, x, B, C and dt segments; xBC through a causal depthwise conv with
+# bias, then SiLU; dt = softplus(dt + dt_bias), A = -exp(A_log); for head h
+# of group g = h // (heads / groups), with S_h of (head_dim, state):
+#
+#   S_h <- exp(dt_h A_h) S_h + dt_h x_h (outer) B_g
+#   y_h  = S_h C_g + D_h x_h
+#
+# then y = rmsnorm per group of (y * silu(z)) (the gate before the norm)
+# and out_proj(y). The recurrent state S and the conv tail are float32.
+
+def attn_ssm_block_specs(cfg) -> dict:
+    d, di = cfg.d_model, cfg.d_ssm
+    gn, hs = cfg.ssm_groups * cfg.ssm_state, cfg.ssm_heads
+    s = {}
+    s |= _norm_specs(cfg, "ln_in")
+    s["attn"] = attn_specs(d, cfg.n_heads, cfg.n_kv_heads, cfg.hd)
+    s["ssm"] = {
+        "w_in": ParamSpec((d, 2 * di + 2 * gn + hs), ("embed", "ffn")),
+        "conv_w": ParamSpec((cfg.conv_width, di + 2 * gn), ("conv", "ffn"),
+                            scale=0.5),
+        "conv_b": ParamSpec((di + 2 * gn,), ("ffn",), init="zeros"),
+        "dt_bias": ParamSpec((hs,), (None,), init="zeros"),
+        "A_log": ParamSpec((hs,), (None,), init="zeros"),
+        "D": ParamSpec((hs,), (None,), init="ones"),
+        "norm": ParamSpec((di,), ("norm",), init="zeros"),
+        "w_out": ParamSpec((di, d), ("ffn", "embed")),
+    }
+    s |= _norm_specs(cfg, "ln_ffn")
+    s["ffn"] = ffn_specs(d, cfg.d_ff)
+    return s
+
+
+def attn_ssm_cache_specs(cfg, B: int, cache_len: int) -> dict:
+    conv_dim = cfg.d_ssm + 2 * cfg.ssm_groups * cfg.ssm_state
+    return attn_cache_specs(cfg, B, cache_len) | {
+        "ssm": ParamSpec((B, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
+                         ("batch", None, None, None), dtype=F32,
+                         init="zeros"),
+        # the conv's last conv_width - 1 inputs, oldest first, side by
+        # side in one row a slot: lane-aligned slices, no padded axis
+        "conv": ParamSpec((B, (cfg.conv_width - 1) * conv_dim),
+                          ("batch", None), dtype=F32, init="zeros")}
+
+
+def _conv_step(xbc, w, tail):
+    """The causal conv at one position: xbc (B,1,C), w (W,C), tail
+    (B,(W-1)*C) the last W-1 inputs. Returns y (B,1,C) and the new tail."""
+    c = xbc.shape[-1]
+    ext = jnp.concatenate([tail, xbc[:, 0]], axis=1)
+    y = sum(ext[:, i * c:(i + 1) * c] * w[i] for i in range(w.shape[0]))
+    return y[:, None], ext[:, c:]
+
+
+def _ssm_inputs(cfg, p, h, conv_tail=None):
+    """in_proj, the conv over xBC and the discretisation, in float32:
+    z (B,S,di), x (B,S,H,P), B and C per head (B,S,H,N), dt (B,S,H), and
+    the conv's new tail. With `conv_tail`, h is one position."""
+    di, g, n, hs = cfg.d_ssm, cfg.ssm_groups, cfg.ssm_state, cfg.ssm_heads
+    sizes = (di, di, g * n, g * n, hs)
+    mup = jnp.asarray(np.repeat(np.asarray(cfg.ssm_mults, np.float32),
+                                sizes))
+    zxbcdt = jnp.einsum("bsd,de->bse", h * cfg.ssm_in_mult,
+                        p["w_in"]).astype(F32) * mup
+    z, xbc, dt = jnp.split(zxbcdt, [di, 2 * di + 2 * g * n], axis=-1)
+    w = p["conv_w"].astype(F32)
+    if conv_tail is None:
+        xbc, _ = _causal_conv(xbc, w)
+    else:
+        xbc, conv_tail = _conv_step(xbc, w, conv_tail)
+    xbc = jax.nn.silu(xbc + p["conv_b"].astype(F32))
+    x, bm, cm = jnp.split(xbc, [di, di + g * n], axis=-1)
+    lead = x.shape[:2]
+    rep = hs // g                       # head h reads group h // rep
+
+    def per_head(a):
+        return jnp.repeat(a.reshape(*lead, g, n), rep, axis=2)
+
+    dt = jax.nn.softplus(dt + p["dt_bias"].astype(F32))
+    return (z, x.reshape(*lead, hs, cfg.ssm_head_dim), per_head(bm),
+            per_head(cm), dt, conv_tail)
+
+
+def _ssm_step(rate, state, x, bm, cm, dt):
+    """One position of the recurrence for every head: rate = A (H,), state
+    (B,H,P,N), x (B,H,P), bm/cm (B,H,N), dt (B,H). Returns the new state
+    and S C (B,H,P)."""
+    state = (jnp.exp(dt * rate)[..., None, None] * state
+             + (dt[..., None] * x)[..., None] * bm[:, :, None, :])
+    return state, jnp.sum(state * cm[:, :, None, :], axis=-1)
+
+
+def _ssm_out(cfg, p, z, x, sc, dtype):
+    """D skip, gate, grouped RMSNorm, out_proj and the output multiplier."""
+    y = sc + p["D"].astype(F32)[:, None] * x               # (B,S,H,P)
+    y = y.reshape(*z.shape) * jax.nn.silu(z)
+    g = cfg.ssm_groups
+    y = y.reshape(*z.shape[:-1], g, -1)
+    y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
+                          + cfg.norm_eps)
+    y = y.reshape(z.shape) * (1.0 + p["norm"].astype(F32))
+    return jnp.einsum("bse,ed->bsd", y.astype(dtype),
+                      p["w_out"]) * cfg.ssm_out_mult
+
+
+def _ssm_ffn(cfg, p, x):
+    """x + SwiGLU(rmsnorm(x)) with the gate and output multipliers."""
+    n = _norm(cfg, p, "ln_ffn", x)
+    f = p["ffn"]
+    g = jnp.einsum("bsd,df->bsf", n, f["w_gate"]).astype(F32)
+    u = jnp.einsum("bsd,df->bsf", n, f["w_up"])
+    hid = jax.nn.silu(g * cfg.mlp_mults[0]).astype(x.dtype) * u
+    return x + jnp.einsum("bsf,fd->bsd", hid, f["w_down"]) * cfg.mlp_mults[1]
+
+
+def _ssm_qkv(cfg, p, h, ctx):
+    q, k, v = qkv_project(p["attn"], h * cfg.attn_in_mult, ctx["positions"],
+                          n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+                          head_dim=cfg.hd, rope=ctx.get("rope", True),
+                          theta=cfg.rope_theta)
+    return q, k * cfg.key_mult, v
+
+
+def attn_ssm_block_apply(cfg, p, x, ctx):
+    h = _norm(cfg, p, "ln_in", x)
+    with jax.named_scope("attention"):
+        q, k, v = _ssm_qkv(cfg, p, h, ctx)
+        o = attn_lib.attention(q, k, v, n_kv=cfg.n_kv_heads, causal=True,
+                               chunk=cfg.attn_chunk,
+                               schedule=cfg.attn_schedule)
+        a = out_project(p["attn"], o) * cfg.attn_out_mult
+    with jax.named_scope("ssm"):
+        s = p["ssm"]
+        z, xs, bm, cm, dt, _ = _ssm_inputs(cfg, s, h)
+        rate = -jnp.exp(s["A_log"].astype(F32))
+        state0 = shard_batch(jnp.zeros(
+            (x.shape[0], cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), F32))
+        seq = lambda t: jnp.moveaxis(t, 1, 0)
+        _, sc = jax.lax.scan(
+            lambda st, inp: _ssm_step(rate, st, *inp), state0,
+            (seq(xs), seq(bm), seq(cm), seq(dt)))
+        m = _ssm_out(cfg, s, z, xs, jnp.moveaxis(sc, 0, 1), x.dtype)
+    x = x + a + m
+    with jax.named_scope("ffn"):
+        x = _ssm_ffn(cfg, p, x)
+    return x, 0.0
+
+
+def attn_ssm_block_decode(cfg, p, x, cache, pos, ctx):
+    h = _norm(cfg, p, "ln_in", x)
+    with jax.named_scope("attention"):
+        q, k, v = _ssm_qkv(cfg, p, h, ctx)
+        o, kc, vc = _attend_decode(cfg, q, k, v, cache, pos, ctx, None)
+        a = out_project(p["attn"], o) * cfg.attn_out_mult
+    with jax.named_scope("ssm"):
+        s = p["ssm"]
+        z, xs, bm, cm, dt, conv = _ssm_inputs(cfg, s, h, cache["conv"])
+        state, sc = _ssm_step(-jnp.exp(s["A_log"].astype(F32)), cache["ssm"],
+                              xs[:, 0], bm[:, 0], cm[:, 0], dt[:, 0])
+        m = _ssm_out(cfg, s, z, xs, sc[:, None], x.dtype)
+    x = x + a + m
+    with jax.named_scope("ffn"):
+        x = _ssm_ffn(cfg, p, x)
+    return x, {"k": kc, "v": vc, "ssm": state, "conv": conv}
+
+
+# ============================================================================
 # Kind registry
 # ============================================================================
 
@@ -877,9 +1055,15 @@ BLOCKS: dict[str, dict[str, Any]] = {
     "enc_attn": dict(specs=enc_attn_block_specs, apply=enc_attn_block_apply,
                      cache=None, decode=None),
     "rglru": dict(specs=rglru_block_specs, apply=rglru_block_apply,
-                  cache=rglru_cache_specs, decode=rglru_block_decode),
+                  cache=rglru_cache_specs, decode=rglru_block_decode,
+                  recurrent=True),
     "mlstm": dict(specs=mlstm_block_specs, apply=mlstm_block_apply,
-                  cache=mlstm_cache_specs, decode=mlstm_block_decode),
+                  cache=mlstm_cache_specs, decode=mlstm_block_decode,
+                  recurrent=True),
     "slstm": dict(specs=slstm_block_specs, apply=slstm_block_apply,
-                  cache=slstm_cache_specs, decode=slstm_block_decode),
+                  cache=slstm_cache_specs, decode=slstm_block_decode,
+                  recurrent=True),
+    "attn_ssm": dict(specs=attn_ssm_block_specs, apply=attn_ssm_block_apply,
+                     cache=attn_ssm_cache_specs, decode=attn_ssm_block_decode,
+                     recurrent=True),
 }

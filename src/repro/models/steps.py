@@ -220,16 +220,25 @@ def nan_cache_slots(cache):
 # decode step). Rolling-window buffers and recurrent block states are not
 # pageable — their `pos % window` addressing / dense state is a layout of
 # its own — so they stay private (B, ...) leaves; the two kinds coexist in
-# one cache pytree and every per-slot op below routes each leaf by a
-# structural mask.
+# one cache pytree (in one layer, for "attn_ssm") and every per-slot op
+# below routes each leaf by a structural mask.
 
 def _kind_paged(cfg, kind: str) -> bool:
     """Does this block kind route K/V through the pool? Mirrors the
     `_paged(ctx, window)` gate in blocks.py: positional attention pages,
     windowed attention stays a private rolling buffer."""
-    if kind in ("attn", "attn_moe"):
+    if kind in ("attn", "attn_moe", "attn_ssm"):
         return not cfg.window
     return kind == "attn_cross"        # self_k/self_v (cross_* is static)
+
+
+def recurrent_state(cfg) -> bool:
+    """Does some layer keep state that only the request's whole token
+    stream builds (a recurrence, a conv tail)? A slot's state then cannot
+    start from shared prefix pages, so paged sessions reuse no prefix."""
+    pattern, _, remainder = block_plan(cfg)
+    return any(BLOCKS[k].get("recurrent", False)
+               for k in pattern + remainder)
 
 
 def _pageable_leaf(spec: ParamSpec) -> bool:
@@ -486,8 +495,23 @@ def make_paged_cache_ops(cfg, B: int, cache_len: int):
 
 def _final_norm(cfg, params, x):
     if cfg.norm == "rms":
-        return rms_norm(x, params["ln_f"])
+        return rms_norm(x, params["ln_f"], cfg.norm_eps)
     return layer_norm(x, params["ln_f_s"], params["ln_f_b"])
+
+
+def _embed(cfg, params, tokens):
+    x = jnp.take(params["tok_embed"], tokens, axis=0)
+    if cfg.embed_mult != 1.0:
+        x = x * cfg.embed_mult
+    return x
+
+
+def _logits(cfg, unembed, h, spec: str):
+    """The unembedding in float32, with the published LM-head multiplier."""
+    out = jnp.einsum(spec, h, unembed, preferred_element_type=F32)
+    if cfg.lm_head_mult != 1.0:
+        out = out * cfg.lm_head_mult
+    return out
 
 
 def _encode(cfg, params, enc_embeds):
@@ -518,7 +542,7 @@ def forward(cfg, params, tokens, *, cross_embeds=None, layer_wsc=None):
     """
     pattern, n_super, remainder = block_plan(cfg)
     B, S = tokens.shape
-    x = jnp.take(params["tok_embed"], tokens, axis=0)
+    x = _embed(cfg, params, tokens)
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     if cfg.family == "encdec":
         cross_embeds = _encode(cfg, params, cross_embeds)
@@ -560,8 +584,7 @@ def _chunked_ce(cfg, unembed, hidden, labels):
     @jax.checkpoint
     def body(carry, blk):
         h, y = blk
-        logits = jnp.einsum("bcd,dv->bcv", h, unembed,
-                            preferred_element_type=F32)
+        logits = _logits(cfg, unembed, h, "bcd,dv->bcv")
         lse = jax.nn.logsumexp(logits, axis=-1)
         onehot = jax.nn.one_hot(y, cfg.vocab, dtype=F32)
         ll = jnp.sum(logits * onehot, axis=-1)
@@ -674,57 +697,59 @@ def make_prefill_step(cfg, *, policy=None):
             cross = batch.get("enc_embeds", batch.get("img_embeds"))
             hidden, _ = forward(cfg, params, batch["tokens"],
                                 cross_embeds=cross)
-            last = hidden[:, -1]
-            logits = jnp.einsum("bd,dv->bv", last, params["unembed"],
-                                preferred_element_type=F32)
+            logits = _logits(cfg, params["unembed"], hidden[:, -1],
+                             "bd,dv->bv")
             return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     return prefill_step
 
 
 def _paged_layer_scan(cfg, super_layer, x, blocks_params, blocks_cache, ctx):
-    """The decode step's layer scan over a paged cache, with the pool in
-    the scan's carry.
+    """The decode step's layer scan over a paged cache, with every cache
+    leaf in the scan's carry.
 
-    As scan xs/ys, a stacked pool leaf (n_super, n_pages, ps, KV, hd)
-    would be sliced out whole per layer and written back whole into a new
-    stacked buffer: three pool copies a step for one row a slot. Carried
-    instead as one flat (n_super * n_pages, ps, KV, hd) view (merging the
-    leading axes is a bitcast), layer l's pages are `pages + l * n_pages`,
-    so the row scatter and the page gather address the carried buffer in
-    place. Layer l's trash page is `l * n_pages`, inside its own region.
-    Private leaves (windowed buffers, cross K/V) stay scan xs/ys."""
+    As scan xs/ys, a stacked leaf (n_super, ...) would be sliced out whole
+    per layer and written back whole into a new stacked buffer: copies of
+    the whole pool, and of every slot's recurrent state, each step. In the
+    carry each leaf is addressed in place:
+
+    * a pool leaf rides as one flat (n_super * n_pages, ps, KV, hd) view
+      (merging the leading axes is a bitcast): layer l's pages are
+      `pages + l * n_pages`, so the row scatter and the page gather reach
+      the carried buffer; layer l's trash page `l * n_pages` lies inside
+      its own region;
+    * a private leaf (recurrent state, conv tails, windowed buffers, cross
+      K/V) stays stacked: the block reads layer l's slice and its new
+      slice is written back at l, which the TPU compiler fuses into the
+      update's own reads and an in-place write. (A gather and scatter of
+      the layer's rows would read the same on the CPU, but the TPU
+      compiler lowers that gather by slicing the whole leaf.)"""
     leaves, treedef = jax.tree.flatten(blocks_cache)
     is_pool = treedef.flatten_up_to(paged_cache_mask(cfg, 1, 1)["blocks"])
-
-    def split(cs):
-        return ([c for c, m in zip(cs, is_pool) if m],
-                [c for c, m in zip(cs, is_pool) if not m])
-
-    def merge(pools, private):
-        pools, private = iter(pools), iter(private)
-        return treedef.unflatten([next(pools) if m else next(private)
-                                  for m in is_pool])
-
-    pools, private = split(leaves)
     n_super = leaves[0].shape[0]
-    n_pages = pools[0].shape[1] if pools else 0
+    n_pages = next(c.shape[1] for c, m in zip(leaves, is_pool) if m)
 
     def body(carry, scanned):
-        x, pools = carry
-        layer_params, private, layer = scanned
+        x, cache = carry
+        layer_params, layer = scanned
+        layer_cache = [c if m else
+                       jax.lax.dynamic_index_in_dim(c, layer, keepdims=False)
+                       for c, m in zip(cache, is_pool)]
         layer_ctx = dict(ctx, pages=ctx["pages"] + layer * n_pages)
-        x, new_cache = super_layer(x, layer_params, merge(pools, private),
-                                   layer_ctx)
-        pools, private = split(treedef.flatten_up_to(new_cache))
-        return (x, pools), private
+        x, new_cache = super_layer(x, layer_params,
+                                   treedef.unflatten(layer_cache), layer_ctx)
+        new = treedef.flatten_up_to(new_cache)
+        cache = [n if m else jax.lax.dynamic_update_index_in_dim(c, n, layer, 0)
+                 for c, n, m in zip(cache, new, is_pool)]
+        return (x, cache), None
 
-    flat = [c.reshape(n_super * n_pages, *c.shape[2:]) for c in pools]
-    (x, flat), private = jax.lax.scan(
-        body, (x, flat),
-        (blocks_params, private, jnp.arange(n_super, dtype=jnp.int32)))
-    return x, merge([f.reshape(c.shape) for f, c in zip(flat, pools)],
-                    private)
+    cache = [c.reshape(n_super * c.shape[1], *c.shape[2:]) if m else c
+             for c, m in zip(leaves, is_pool)]
+    (x, cache), _ = jax.lax.scan(
+        body, (x, cache),
+        (blocks_params, jnp.arange(n_super, dtype=jnp.int32)))
+    return x, treedef.unflatten([f.reshape(c.shape)
+                                 for f, c in zip(cache, leaves)])
 
 
 def make_decode_step(cfg, max_seq: int = 1 << 30, *, policy=None,
@@ -743,7 +768,7 @@ def make_decode_step(cfg, max_seq: int = 1 << 30, *, policy=None,
     def _body(params, cache, batch):
         tokens, pos = batch["tokens"], jnp.asarray(batch["pos"])
         B = tokens.shape[0]
-        x = jnp.take(params["tok_embed"], tokens, axis=0)       # (B,1,d)
+        x = _embed(cfg, params, tokens)                         # (B,1,d)
         if cfg.family == "encdec":
             if pos.ndim == 0:
                 dp = jax.lax.dynamic_slice_in_dim(params["dec_pos"], pos, 1,
@@ -786,8 +811,7 @@ def make_decode_step(cfg, max_seq: int = 1 << 30, *, policy=None,
                 new_cache["rem"][f"rem{i}"] = c
         with jax.named_scope("unembed"):
             x = _final_norm(cfg, params, x)
-            out = jnp.einsum("bsd,dv->bsv", x, params["unembed"],
-                             preferred_element_type=F32)[:, 0]
+            out = _logits(cfg, params["unembed"], x, "bsd,dv->bsv")[:, 0]
         if logits:
             return new_cache, out
         return new_cache, jnp.argmax(out, axis=-1).astype(jnp.int32)[:, None]

@@ -376,9 +376,18 @@ class PagedKV:
     """
 
     def __init__(self, n_pages: int, page_size: int, n_slots: int,
-                 pages_per_slot: int, *, prefix_cache: bool = True):
+                 pages_per_slot: int, *, prefix_cache: bool = True,
+                 recurrent: bool = False):
+        """`recurrent`: the cache also holds per-slot recurrent state,
+        which shared pages cannot restore, so no prefix is matched or
+        published whatever `prefix_cache` says (`stats()["prefix_reuse"]`
+        says why)."""
         self.pool = PagePool(n_pages, page_size)
-        self.prefix = PrefixCache(self.pool) if prefix_cache else None
+        reuse = prefix_cache and not recurrent
+        self.prefix = PrefixCache(self.pool) if reuse else None
+        self.prefix_reuse = ("on" if reuse else
+                             "off: recurrent state" if prefix_cache
+                             else "off")
         self.n_slots = int(n_slots)
         self.pages_per_slot = int(pages_per_slot)
         # owned: the references this slot must drop on release (includes a
@@ -703,7 +712,8 @@ class PagedKV:
 
     def stats(self) -> dict:
         out = dict(self.pool.stats())
-        out.update(pages_shared=self.pages_shared_total,
+        out.update(prefix_reuse=self.prefix_reuse,
+                   pages_shared=self.pages_shared_total,
                    prefill_skipped_tokens=self.prefill_skipped_tokens,
                    cow_forks=self.cow_forks,
                    integrity_checks=self.integrity_checks,

@@ -98,7 +98,8 @@ def test_full_config_param_counts():
               "grok-1-314b": (300e9, 330e9), "mixtral-8x7b": (44e9, 50e9),
               "whisper-small": (0.1e9, 0.3e9), "xlstm-125m": (0.1e9, 0.2e9),
               "recurrentgemma-9b": (8e9, 11e9),
-              "llama-3.2-vision-90b": (80e9, 95e9)}
+              "llama-3.2-vision-90b": (80e9, 95e9),
+              "falcon-h1-34b": (33.5e9, 33.7e9)}
     for arch, (lo, hi) in expect.items():
         n = ARCHS[arch].n_params()
         assert lo <= n <= hi, f"{arch}: {n/1e9:.2f}B not in [{lo/1e9},{hi/1e9}]"
@@ -184,3 +185,49 @@ def test_rglru_scan_matches_decode_loop():
     np.testing.assert_allclose(np.asarray(full, np.float32),
                                np.asarray(stepwise, np.float32),
                                rtol=0.15, atol=0.15)
+
+
+def test_attn_ssm_scan_matches_decode_loop():
+    """Falcon-H1's block over a whole sequence (attention, and the Mamba-2
+    recurrence as a scan) equals the block fed one position at a time
+    through its decode state. In float32, with each branch shown to move
+    the output, so that a branch dropped on one path cannot pass."""
+    import dataclasses
+
+    from repro.models import blocks
+    from repro.models.layers import init_tree
+    cfg = get("falcon-h1-34b-smoke")
+    f32 = lambda t: jax.tree.map(lambda a: a.astype(jnp.float32), t)
+    params = f32(init_tree(blocks.attn_ssm_block_specs(cfg), KEY))
+    ks = jax.random.split(KEY, 4)
+    # away from the init's zeros and ones, so that every leaf counts
+    params["ssm"] |= {k: jax.random.normal(kk, params["ssm"][k].shape)
+                      for k, kk in zip(("dt_bias", "A_log", "D", "conv_b"),
+                                       ks)}
+    S = 12
+    x = jax.random.normal(KEY, (2, S, cfg.d_model), jnp.float32)
+    ctx = {"positions": jnp.broadcast_to(jnp.arange(S), (2, S)),
+           "max_seq": S}
+    full, _ = blocks.attn_ssm_block_apply(cfg, params, x, ctx)
+
+    def stepwise(cfg, params):
+        cache = f32(init_tree(blocks.attn_ssm_cache_specs(cfg, 2, S), KEY))
+        outs = []
+        for t in range(S):
+            o, cache = blocks.attn_ssm_block_decode(
+                cfg, params, x[:, t:t + 1], cache, t,
+                dict(ctx, positions=jnp.full((2, 1), t)))
+            outs.append(o)
+        return jnp.concatenate(outs, axis=1)
+
+    np.testing.assert_allclose(np.asarray(full), np.asarray(stepwise(
+        cfg, params)), rtol=1e-4, atol=1e-5)
+    # each branch moves the output well past that tolerance: the mixer's
+    # input to its state (the x segment zeroed), the attention, the FFN
+    frozen = dataclasses.replace(cfg, ssm_mults=(1.0, 0.0, 1.0, 1.0, 1.0))
+    for other in (stepwise(frozen, params),
+                  stepwise(dataclasses.replace(cfg, attn_out_mult=0.0),
+                           params),
+                  stepwise(dataclasses.replace(cfg, mlp_mults=(1.0, 0.0)),
+                           params)):
+        assert float(jnp.max(jnp.abs(other - full))) > 1e-3

@@ -190,16 +190,21 @@ _HLO_RESULT = re.compile(
     r"^\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\(", re.M)
 
 
-def test_paged_chunk_program_copies_no_pool():
+@pytest.mark.parametrize("arch", ["qwen3-14b-smoke",
+                                  # recurrent state carried beside the pool
+                                  "falcon-h1-34b-smoke"])
+def test_paged_chunk_program_copies_no_pool(arch):
     """The compiled paged session chunk touches the KV pool only through
     the row scatter and the page gather: no `copy`, `dynamic-slice` or
     `dynamic-update-slice`, fused or not, yields a whole pool leaf or one
     layer of it (sizes compared by element count, so a bitcast view of
     the pool counts too). The pool's page count is prime so that no other
-    buffer of the program matches by accident."""
+    buffer of the program matches by accident. (The recurrent state's own
+    check is compiled for the TPU, in `test_tpu_compile.py`: the CPU's
+    compiler copies what the TPU's updates in place.)"""
     from repro.cluster import ServeSessionProgram
 
-    cluster = Cluster("qwen3-14b-smoke")
+    cluster = Cluster(arch)
     prog = cluster.compile(ServeSessionProgram(
         slots=4, max_seq=48, max_prompt=16, chunk=4, paged=True,
         page_size=4, n_pages=53))

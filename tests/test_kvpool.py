@@ -305,6 +305,9 @@ def test_paged_bit_identical_on_prefix_free_workload(programs):
     # 2 scanned periods of 4 pooled attn layers + 1 cross layer whose
     # K/V stay private, then 2 unscanned remainder attn layers
     ("llama-3.2-vision-90b-smoke", 12),
+    # pooled K/V and private recurrent state (SSM state, conv tail) in
+    # every one of 4 layers
+    ("falcon-h1-34b-smoke", 4),
 ])
 def test_every_layer_writes_its_own_pool_region(arch, n_layers):
     """Mid-generation, every pool leaf's layer l holds, at each live
@@ -515,3 +518,85 @@ def test_paged_rejects_preempt_and_recurrent_archs(cluster):
         page_size=4, preempt=True))
     sess = prog.open()          # program forces preempt off: must not raise
     assert sess.stats()["kv"]["used_pages"] == 0
+
+
+# ----------------------------------------------------------------------------
+# Recurrent state beside the pool (Falcon-H1: attention and Mamba-2 per layer)
+# ----------------------------------------------------------------------------
+
+HYBRID = "falcon-h1-34b-smoke"
+
+
+@pytest.fixture(scope="module")
+def hybrid_programs():
+    """Smoke widths with a mixer whose state matters: no multiplier
+    scales its inputs or output down, its output is the state's alone
+    (D = 0) and decays slowly (A = -exp(-4)), so a state left over from an
+    earlier request, or missing tokens, changes the served tokens."""
+    import dataclasses
+
+    from repro.cluster.session import Cluster, ServeSessionProgram
+    from repro.configs import get
+    cluster = Cluster(dataclasses.replace(
+        get(HYBRID), ssm_in_mult=1.0, ssm_out_mult=1.0, ssm_mults=(1.0,) * 5))
+    common = dict(slots=2, max_seq=64, max_prompt=48, chunk=4)
+    private = cluster.compile(ServeSessionProgram(preempt=False, **common))
+    paged = cluster.compile(ServeSessionProgram(paged=True, page_size=8,
+                                                **common))
+    params = private.init_params()
+    ssm = params["blocks"]["sub0"]["ssm"]
+    ssm["A_log"] = jax.numpy.full_like(ssm["A_log"], -4.0)
+    ssm["D"] = jax.numpy.zeros_like(ssm["D"])
+    return private, paged, params
+
+
+def test_recurrent_arch_pages_kv_but_skips_no_prefix(hybrid_programs):
+    """Shared pages cannot restore the recurrent state of the tokens they
+    hold: with recurrent state in the cache, admission matches and
+    publishes no prefix, every prompt token is prefilled, and the tokens
+    are the private-cache session's."""
+    private, paged, params = hybrid_programs
+    rng = np.random.default_rng(5)
+    system = rng.integers(0, 256, 16, dtype=np.int32)       # two pages
+    prompts = [system,
+               np.concatenate([system, rng.integers(0, 256, 7,
+                                                    dtype=np.int32)]),
+               system]
+    served = []
+    for prog in (private, paged):
+        sess = prog.open(params=params)
+        toks = []
+        for p in prompts:                   # one at a time: the first
+            h = sess.submit(p, 6)           # finishes (and would publish)
+            while not h.done:               # before the next is admitted
+                sess.poll()
+            toks.append(h.result())
+        served.append(toks)
+        kv = sess.stats().get("kv")
+    assert kv["prefix_reuse"] == "off: recurrent state"
+    assert kv["prefill_skipped_tokens"] == 0 and kv["pages_shared"] == 0
+    assert "prefix_hits" not in kv
+    for a, b in zip(*served):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(served[1][0], served[1][2])
+
+
+def test_reused_slot_serves_as_a_fresh_session(hybrid_programs):
+    """A slot that served a long request is zeroed on refill: the next
+    request in it gets the tokens a fresh session gives it."""
+    _, paged, params = hybrid_programs
+    rng = np.random.default_rng(6)
+    long_p = rng.integers(0, 256, 40, dtype=np.int32)
+    short = rng.integers(0, 256, 5, dtype=np.int32)
+    sess = paged.open(params=params)
+    first = [sess.submit(long_p, 20), sess.submit(long_p[::-1].copy(), 20)]
+    sess.drain()
+    assert all(h.done for h in first)
+    reused = sess.submit(short, 8)
+    sess.drain()
+    fresh = paged.open(params=params)
+    h = fresh.submit(short, 8)
+    fresh.drain()
+    np.testing.assert_array_equal(reused.result(), h.result())
+    assert paged.open(params=params).stats()["kv"]["prefix_reuse"] == \
+        "off: recurrent state"

@@ -5,12 +5,17 @@ compiles for a *described* v5e topology. This catches what interpret mode
 cannot — a blocking that needs more VMEM than the kernel may use, a tile
 the Mosaic compiler refuses — at qwen3-14b widths, in about two seconds a
 kernel (the autotuner's large prefill picks take longer). Each compile
-must contain the Pallas kernel itself (`tpu_custom_call`).
+must contain the Pallas kernel itself (`tpu_custom_call`). One more test
+compiles a whole paged session chunk at Falcon-H1 widths and reads what the
+TPU compiler made of its recurrent state, which the CPU's compiler cannot
+show.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, so describing it while
 collecting would break every other test worker.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -111,3 +116,49 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache):
             for s in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+_HLO_OP = re.compile(r"^\s*(ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\(",
+                     re.M)
+
+
+def test_paged_chunk_program_copies_no_state(one_chip, no_persistent_cache):
+    """Falcon-H1's recurrent state rides in the paged decode step's layer
+    scan carry and is updated in place. In the session chunk compiled for
+    a v5e at published widths (2 layers, the cell's 32 slots and prime
+    page count), no op copies, slices, gathers or scatters a whole SSM
+    state leaf or one layer of it, and no computation yields such a buffer
+    except by a dynamic-update-slice at its root (the in-place write): a
+    layer's slice is read only inside the fusions that use it. (The conv
+    tail, 2 MB a layer, is left out: the compiler may stage a buffer that
+    small through fast memory.)"""
+    import dataclasses
+    import math
+
+    from repro.cluster import Cluster, ServeSessionProgram
+    from repro.configs import get
+
+    cfg = dataclasses.replace(get("falcon-h1-34b"), n_layers=2)
+    prog = Cluster(cfg).compile(ServeSessionProgram(
+        paged=True, preempt=False, slots=32, max_seq=2048, max_prompt=2048,
+        chunk=16, page_size=16))
+    shaped = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    state = shaped(jax.eval_shape(prog._make_state))
+    params = shaped(jax.eval_shape(prog.init_params))
+    text = prog._chunk_fn.lower(params, state).compile().as_text()
+    leaf = state["cache"]["blocks"]["sub0"]["ssm"]
+    sizes = {leaf.size, leaf.size // leaf.shape[0]}
+    moved = ("copy", "copy-start", "copy-done", "slice", "gather", "scatter")
+    seen, bad = 0, []
+    for comp in re.split(r"\n(?=\S)", text):
+        for root, dims, op in _HLO_OP.findall(comp):
+            if math.prod(int(d) for d in dims.split(",") if d) not in sizes:
+                continue
+            seen += 1
+            in_place = op == "dynamic-update-slice"
+            if op in moved or in_place != bool(root):
+                bad.append((dims, op, bool(root)))
+    assert seen, "the state's own buffers must be found"
+    assert not bad, bad
