@@ -53,6 +53,7 @@ from . import dotp as _dotp
 from . import flash_attention as _fa
 from . import fused as _fused
 from . import matmul as _matmul
+from . import paged_attention as _pa
 from . import ref as _ref
 from . import rmsnorm as _rmsnorm
 
@@ -231,6 +232,33 @@ def flash_attention(q, k, v, *, causal: bool = True, bq: int | None = None,
         return _ref_flash_attention(q, k, v, causal=causal)
     return _flash_attention_c(q, k, v, causal=causal, bq=bq, bk=bk,
                               interpret=_interp("flash_attention"))
+
+
+def _ref_paged_decode_attention(q, k_pool, v_pool, lengths, pages, **_):
+    from repro.models import attention as attn
+    return attn.decode_attention(q, attn.paged_gather(k_pool, pages),
+                                 attn.paged_gather(v_pool, pages), lengths,
+                                 n_kv=k_pool.shape[2])
+
+
+@functools.partial(jax.jit, static_argnames=("pages_per_block", "interpret"))
+def _paged_decode_attention_c(q, k_pool, v_pool, lengths, pages, *,
+                              pages_per_block, interpret):
+    return _pa.paged_decode_attention(q, k_pool, v_pool, lengths, pages,
+                                      pages_per_block=pages_per_block,
+                                      interpret=interpret)
+
+
+def paged_decode_attention(q, k_pool, v_pool, lengths, pages, *,
+                           pages_per_block: int | None = None):
+    """One decode token per slot against the shared page pool, reading
+    only the pages below each slot's length; the reference gathers each
+    slot's whole page list and masks it."""
+    if _take_reference("paged_decode_attention"):
+        return _ref_paged_decode_attention(q, k_pool, v_pool, lengths, pages)
+    return _paged_decode_attention_c(
+        q, k_pool, v_pool, lengths, pages, pages_per_block=pages_per_block,
+        interpret=_interp("paged_decode_attention"))
 
 
 # ----------------------------------------------------------------------------
@@ -433,6 +461,13 @@ def _shapes_flash_attention(q, k, v):
     return {"b": b, "h": h, "kv": k.shape[1], "s": s, "hd": hd}
 
 
+def _shapes_paged_decode_attention(q, k_pool, v_pool, lengths, pages):
+    b, _, h, hd = q.shape
+    _, ps, kv, _ = k_pool.shape
+    return {"b": b, "h": h, "kv": kv, "hd": hd, "ps": ps,
+            "npp": pages.shape[1]}
+
+
 def _shapes_rmsnorm_matmul(x, scale, w):
     return {"m": x.shape[0], "k": x.shape[1], "n": w.shape[1]}
 
@@ -484,6 +519,15 @@ def _mk_flash_attention(s, dt):
     b, h, kv, sq, hd = (s[k] for k in ("b", "h", "kv", "s", "hd"))
     return (_rand(11, (b, h, sq, hd), dt), _rand(12, (b, kv, sq, hd), dt),
             _rand(13, (b, kv, sq, hd), dt))
+
+
+def _mk_paged_decode_attention(s, dt):
+    b, h, kv, hd, ps, npp = (s[k] for k in ("b", "h", "kv", "hd", "ps",
+                                              "npp"))
+    pages = 1 + jnp.arange(b * npp, dtype=jnp.int32).reshape(b, npp)
+    pool = (1 + b * npp, ps, kv, hd)
+    return (_rand(27, (b, 1, h, hd), dt), _rand(28, pool, dt),
+            _rand(29, pool, dt), jnp.full((b,), npp * ps, jnp.int32), pages)
 
 
 def _mk_rmsnorm_matmul(s, dt):
@@ -582,6 +626,9 @@ for _desc in (
                  operands=_mk_rmsnorm),
     OpDescriptor("flash_attention", flash_attention, _shapes_flash_attention,
                  _ref_flash_attention, operands=_mk_flash_attention),
+    OpDescriptor("paged_decode_attention", paged_decode_attention,
+                 _shapes_paged_decode_attention, _ref_paged_decode_attention,
+                 operands=_mk_paged_decode_attention),
     OpDescriptor("rmsnorm_matmul", rmsnorm_matmul, _shapes_rmsnorm_matmul,
                  _ref_rmsnorm_matmul, fused=True,
                  operands=_mk_rmsnorm_matmul,

@@ -496,21 +496,24 @@ def paged_update_cache(k_pool, v_pool, k_new, v_new, pos, pages):
 def paged_gather(pool, pages):
     """Gather each slot's pages into a contiguous (B, npp * ps, KV, hd)
     cache view. Positions past a slot's written length read stale pool
-    data (or the trash page) — harmless, because `decode_attention`'s
-    `idx < pos` mask gives them exactly-zero softmax weight."""
+    data (or the trash page) — harmless for finite data, because
+    `decode_attention`'s `idx < pos` mask gives them exactly-zero softmax
+    weight. With `decode_attention`, the reference route of
+    `paged_decode_attention`."""
     b, npp = pages.shape
     _, ps, kv, hd = pool.shape
     with jax.named_scope("kv_gather"):
         return pool[pages].reshape(b, npp * ps, kv, hd)
 
 
-def paged_decode_attention(q, k_pool, v_pool, pos, pages, *, n_kv: int):
-    """`decode_attention` against the shared pool: gather-through-table,
-    then the standard masked path — bit-identical to the private-cache
-    result for any slot whose pages hold the same K/V rows."""
-    kg = paged_gather(k_pool, pages)
-    vg = paged_gather(v_pool, pages)
-    return decode_attention(q, kg, vg, pos, n_kv=n_kv)
+def paged_decode_attention(q, k_pool, v_pool, pos, pages):
+    """`decode_attention` against the shared pool, through the paged
+    decode kernel (`kernels/paged_attention.py`): each slot's pages below
+    `pos` are read through its table, none past it. Under the
+    "reference" policy the gather-then-mask composition runs instead."""
+    from repro.kernels import ops
+    with jax.named_scope("paged_attention"):
+        return ops.paged_decode_attention(q, k_pool, v_pool, pos, pages)
 
 
 def copy_page(pool, src, dst):

@@ -178,8 +178,7 @@ def _attend_decode(cfg, q, k, v, cache, pos, ctx, window):
     if paged:
         kc, vc = attn_lib.paged_update_cache(cache["k"], cache["v"], k, v,
                                              pos, ctx["pages"])
-        o = attn_lib.paged_decode_attention(q, kc, vc, pos + 1, ctx["pages"],
-                                            n_kv=cfg.n_kv_heads)
+        o = attn_lib.paged_decode_attention(q, kc, vc, pos + 1, ctx["pages"])
     else:
         kc, vc = attn_lib.update_cache(cache["k"], cache["v"], k, v, pos,
                                        rolling=rolling)
@@ -487,8 +486,7 @@ def attn_cross_block_decode(cfg, p, x, cache, pos, ctx):
     if _paged(ctx, None):
         kc, vc = attn_lib.paged_update_cache(cache["self_k"], cache["self_v"],
                                              k, v, pos, ctx["pages"])
-        o = attn_lib.paged_decode_attention(q, kc, vc, pos + 1, ctx["pages"],
-                                            n_kv=cfg.n_kv_heads)
+        o = attn_lib.paged_decode_attention(q, kc, vc, pos + 1, ctx["pages"])
     else:
         kc, vc = attn_lib.update_cache(cache["self_k"], cache["self_v"],
                                        k, v, pos)

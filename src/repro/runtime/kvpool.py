@@ -7,9 +7,9 @@ makes every bank one hop away. The serving analogue built here: the model
 KV cache stops being a private per-slot rectangle and becomes ONE global
 pool of fixed-size KV pages ("banks"). Each slot owns only a small page
 table; attention reads/writes are routed through it on device
-(`models/attention.paged_update_cache` / `paged_gather`), and slot refill
-becomes page allocation + table install instead of a full cache-zero
-pass.
+(`models/attention.paged_update_cache` / `paged_decode_attention`), and
+slot refill becomes page allocation + table install instead of a full
+cache-zero pass.
 
 Three host-side pieces live in this module:
 

@@ -300,6 +300,69 @@ def test_paged_bit_identical_on_prefix_free_workload(programs):
     assert st["kv"]["alloc_failures"] == 0
 
 
+def _float32_logits(cfg, params, tokens):
+    """Float32 logits for the token after `tokens`, teacher-forced through
+    the private-cache decode step with every Pallas kernel off."""
+    import jax.numpy as jnp
+
+    from repro.models import steps
+    f32 = lambda t: jax.tree.map(lambda a: a.astype(jnp.float32)
+                                 if a.dtype == jnp.bfloat16 else a, t)
+    step = jax.jit(steps.make_decode_step(cfg, max_seq=48, logits=True,
+                                          policy="reference"))
+    cache, params = f32(steps.init_cache(cfg, 1, 48)), f32(params)
+    for t, tok in enumerate(tokens):
+        cache, logits = step(params, cache,
+                             {"tokens": jnp.full((1, 1), tok, jnp.int32),
+                              "pos": jnp.full((1,), t, jnp.int32)})
+    return np.asarray(logits[0])
+
+
+def test_paged_kernel_and_gather_route_serve_the_same_tokens(cluster,
+                                                             programs):
+    """The default policy serves paged attention through the paged decode
+    kernel (interpreted off a TPU); a "reference" override for it, through
+    the gather-then-mask composition. Requests of mixed lengths, most
+    spanning several 4-row pages, get the same greedy tokens from both,
+    and each policy's counter shows the route it traced.
+
+    The two routes round the softmax weights to bf16 at different points,
+    so where the model's two best tokens lie within bf16 noise of each
+    other (random smoke weights make such near-ties common: a logit gap of
+    0.008 on this mix) either route may take either token. Where the
+    routes part, the float32 model must hold the two tokens within TIE of
+    each other and of its best; that request is compared no further."""
+    from repro.cluster.policy import KernelPolicy
+    from repro.cluster.session import Cluster, ServeSessionProgram
+    from repro.configs import get
+    tie = 0.02
+    _, paged, params = programs
+    gather = Cluster(ARCH, policy=KernelPolicy(
+        overrides={"paged_decode_attention": "reference"}))
+    gather_prog = gather.compile(ServeSessionProgram(
+        paged=True, page_size=4, slots=4, max_seq=48, max_prompt=16,
+        max_new=6, chunk=4))
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, 50, size=n).astype(np.int32)
+               for n in (1, 3, 4, 5, 9, 16, 12, 7)]
+    toks_k, _ = _run(paged, params, prompts)
+    toks_g, _ = _run(gather_prog, params, prompts)
+    agreed = 0
+    for prompt, a, b in zip(prompts, toks_k, toks_g):
+        split = next((j for j in range(len(a)) if a[j] != b[j]), None)
+        if split is None:
+            agreed += len(a)
+            continue
+        agreed += split
+        logits = _float32_logits(get(ARCH), params,
+                                 [*prompt, *a[:split]])
+        pair = logits[[a[split], b[split]]]
+        assert logits.max() - pair.min() < tie, (prompt, split, pair)
+    assert agreed >= 0.8 * sum(len(a) for a in toks_k)
+    assert cluster.kernel_policy.stats.get("pallas_calls", 0) > 0
+    assert gather.kernel_policy.stats.get("ref_calls", 0) > 0
+
+
 @pytest.mark.parametrize("arch,n_layers", [
     (ARCH, None),
     # 2 scanned periods of 4 pooled attn layers + 1 cross layer whose
@@ -317,6 +380,7 @@ def test_every_layer_writes_its_own_pool_region(arch, n_layers):
     leaves match the private run as they are."""
     import dataclasses
 
+    from repro.cluster.policy import KernelPolicy
     from repro.cluster.session import Cluster, ServeSessionProgram
     from repro.configs import get
     from repro.models import steps
@@ -326,7 +390,11 @@ def test_every_layer_writes_its_own_pool_region(arch, n_layers):
         cfg = dataclasses.replace(cfg, n_layers=n_layers)
     _, n_super, remainder = steps.block_plan(cfg)
     assert n_super >= 2
-    cluster = Cluster(cfg)
+    # the write path is the same under either attention route; with the
+    # gather-then-mask route the paged session's arithmetic is the private
+    # one's, so each later layer's rows can be compared bit for bit
+    cluster = Cluster(cfg, policy=KernelPolicy(
+        overrides={"paged_decode_attention": "reference"}))
     common = dict(slots=4, max_seq=48, max_prompt=16, chunk=4)
     private = cluster.compile(ServeSessionProgram(preempt=False, **common))
     paged = cluster.compile(ServeSessionProgram(paged=True, page_size=4,

@@ -26,6 +26,8 @@ SHAPES = {
     "dct8x8": {"n": 1536},
     "rmsnorm": {"m": 384, "d": 256},
     "flash_attention": {"b": 1, "h": 4, "kv": 2, "s": 256, "hd": 64},
+    "paged_decode_attention": {"b": 3, "h": 4, "kv": 2, "hd": 128, "ps": 4,
+                               "npp": 5},
 }
 
 # which traffic dims each block size must divide
@@ -37,6 +39,8 @@ DIVIDES = {
     "dct8x8": {"block_n": "n"},
     "rmsnorm": {"block_rows": "m"},
     "flash_attention": {"bq": "s", "bk": "s"},
+    # a slot's last block may run past its table: nothing to divide
+    "paged_decode_attention": {},
 }
 
 
@@ -61,6 +65,14 @@ def make_operands(name, shapes):
         b, h, kv, s, hd = (shapes[k] for k in ("b", "h", "kv", "s", "hd"))
         return (rand(11, (b, h, s, hd)), rand(12, (b, kv, s, hd)),
                 rand(13, (b, kv, s, hd)))
+    if name == "paged_decode_attention":
+        b, h, kv, hd, ps, npp = (shapes[k] for k in
+                                 ("b", "h", "kv", "hd", "ps", "npp"))
+        pool = (1 + b * npp, ps, kv, hd)
+        pages = np.random.default_rng(0).permutation(np.arange(1, pool[0]))
+        return (rand(14, (b, 1, h, hd)), rand(15, pool), rand(16, pool),
+                jnp.asarray([1, 9, npp * ps], jnp.int32),
+                jnp.asarray(pages.reshape(b, npp), jnp.int32))
     raise KeyError(name)
 
 
@@ -82,6 +94,13 @@ def reference(name, operands):
         g = q.shape[1] // k.shape[1]
         return ref.flash_attention(q, jnp.repeat(k, g, axis=1),
                                    jnp.repeat(v, g, axis=1))
+    if name == "paged_decode_attention":
+        from repro.models import attention
+        q, k_pool, v_pool, lengths, pages = operands
+        return attention.decode_attention(
+            q, attention.paged_gather(k_pool, pages),
+            attention.paged_gather(v_pool, pages), lengths,
+            n_kv=k_pool.shape[2])
     raise KeyError(name)
 
 

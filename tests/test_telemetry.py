@@ -218,8 +218,12 @@ def test_decode_step_carries_named_scopes(paged):
     text = sess._chunk_fn.lower(params, sess.state).as_text(debug_info=True)
     sess.close()
     # a scope prefixes the name of each op traced inside it
-    for scope in ("attention", "ffn", "unembed", "kv_write", "kv_gather"):
+    for scope in ("attention", "ffn", "unembed", "kv_write",
+                  "paged_attention"):
         assert re.search(rf'"([^"]*/)?{scope}/\w', text), scope
+    # the paged decode kernel reads the pool through the page table: no
+    # gather of each slot's whole page list
+    assert not re.search(r'"([^"]*/)?kv_gather/\w', text)
 
 
 def test_recorded_chip_trace_shares_the_device_clock():

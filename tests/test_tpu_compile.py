@@ -118,11 +118,75 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# the served attention widths: query heads, kv heads (hd 128 for all)
+PAGED_WIDTHS = {"qwen3-14b": (40, 8), "yi-34b": (56, 8),
+                "falcon-h1-34b": (20, 4)}
+
+
+@pytest.mark.parametrize("arch", sorted(PAGED_WIDTHS))
+def test_paged_decode_attention_compiles_for_v5e(arch, one_chip,
+                                                 no_persistent_cache):
+    """The paged decode kernel at a served cell's decode shapes: 32 slots,
+    129 pages a slot of 16 rows, the default pool of 4129 pages a layer
+    for 8 layers, the blocking it reads from those shapes."""
+    from repro.kernels.paged_attention import paged_decode_attention
+    h, kv = PAGED_WIDTHS[arch]
+    slots, npp, pool = 32, 129, (8 * 4129, 16, kv, HD)
+    spec = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    compiled = jax.jit(paged_decode_attention).lower(
+        spec((slots, 1, h, HD)), spec(pool), spec(pool),
+        spec((slots,), jnp.int32), spec((slots, npp), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+def _chunk_program(arch, n_layers, one_chip, monkeypatch):
+    """The paged session chunk at published widths, the cells' 32 slots,
+    2048 context and page 16, compiled for a described v5e with the
+    Pallas kernels compiled as on the chip (off a TPU backend the policy
+    would interpret them)."""
+    import dataclasses
+
+    from repro.cluster import Cluster, ServeSessionProgram
+    from repro.cluster.policy import KernelPolicy
+    from repro.configs import get
+
+    monkeypatch.setattr(KernelPolicy, "interpret_for", lambda self, op: False)
+    cfg = dataclasses.replace(get(arch), n_layers=n_layers)
+    prog = Cluster(cfg).compile(ServeSessionProgram(
+        paged=True, preempt=False, slots=32, max_seq=2048, max_prompt=2048,
+        chunk=16, page_size=16))
+    shaped = lambda t: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    state = shaped(jax.eval_shape(prog._make_state))
+    params = shaped(jax.eval_shape(prog.init_params))
+    return prog._chunk_fn.lower(params, state).compile(), state
+
+
+def test_paged_chunk_program_gathers_no_page_list(one_chip,
+                                                  no_persistent_cache,
+                                                  monkeypatch):
+    """At yi-34b-d8 widths (8 layers of 56/8 heads) the chunk's attention
+    is the paged decode kernel: no op holds a slot batch's whole page
+    list, as pages (32, 129, 16, 8, 128) or as rows (32, 2064, 8, 128),
+    and the program's temporaries stay below the 1,328,793,088 B that the
+    gather-then-mask step needed."""
+    compiled, _ = _chunk_program("yi-34b", 8, one_chip, monkeypatch)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    shapes = {dims for _, dims, _ in _HLO_OP.findall(text)}
+    assert not shapes & {"32,129,16,8,128", "32,2064,8,128"}
+    assert compiled.memory_analysis().temp_size_in_bytes < 1_328_793_088
+
+
 _HLO_OP = re.compile(r"^\s*(ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\(",
                      re.M)
 
 
-def test_paged_chunk_program_copies_no_state(one_chip, no_persistent_cache):
+def test_paged_chunk_program_copies_no_state(one_chip, no_persistent_cache,
+                                            monkeypatch):
     """Falcon-H1's recurrent state rides in the paged decode step's layer
     scan carry and is updated in place. In the session chunk compiled for
     a v5e at published widths (2 layers, the cell's 32 slots and prime
@@ -132,22 +196,11 @@ def test_paged_chunk_program_copies_no_state(one_chip, no_persistent_cache):
     layer's slice is read only inside the fusions that use it. (The conv
     tail, 2 MB a layer, is left out: the compiler may stage a buffer that
     small through fast memory.)"""
-    import dataclasses
     import math
 
-    from repro.cluster import Cluster, ServeSessionProgram
-    from repro.configs import get
-
-    cfg = dataclasses.replace(get("falcon-h1-34b"), n_layers=2)
-    prog = Cluster(cfg).compile(ServeSessionProgram(
-        paged=True, preempt=False, slots=32, max_seq=2048, max_prompt=2048,
-        chunk=16, page_size=16))
-    shaped = lambda t: jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        t)
-    state = shaped(jax.eval_shape(prog._make_state))
-    params = shaped(jax.eval_shape(prog.init_params))
-    text = prog._chunk_fn.lower(params, state).compile().as_text()
+    compiled, state = _chunk_program("falcon-h1-34b", 2, one_chip,
+                                     monkeypatch)
+    text = compiled.as_text()
     leaf = state["cache"]["blocks"]["sub0"]["ssm"]
     sizes = {leaf.size, leaf.size // leaf.shape[0]}
     moved = ("copy", "copy-start", "copy-done", "slice", "gather", "scatter")
