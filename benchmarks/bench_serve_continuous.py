@@ -1,27 +1,20 @@
-"""Continuous vs static batching on a mixed-length serving workload.
+"""Continuous batching on a mixed-length serving workload.
 
 The paper's headline is <2% execution stalls because shared-L1 slots are
 always addressable and refilled while compute proceeds; the serving
-analogue is slot occupancy. Static batching (the fixed-batch
-ServeProgram/ServeLoop path) runs each batch to its slowest member, so a
-slot that finishes its request early idles until the batch drains.
-Continuous batching (ServeSession) recycles the slot at the next chunk
-boundary. This bench runs the same request set — mixed prompt (1-8) and
-output lengths drawn from {8..64}, right-skewed like real traffic —
-through both paths on one slot pool and reports tokens/s, slot occupancy,
-and p99 request latency.
+analogue is slot occupancy. Continuous batching (ServeSession) recycles a
+slot at the next chunk boundary once its request finishes. This bench runs
+a request set — mixed prompt (1-8) and output lengths drawn from {8..64},
+right-skewed like real traffic — through one slot pool and reports
+tokens/s, slot occupancy, and p99 request latency.
 
-Both paths share the decode cadence (chunk=K host-sync granularity) and
-the same per-step model cost; the only difference is the admission
-policy, so the ratio isolates the scheduling win.
-
-A third scenario exercises the SLO layer: a mixed-class workload
+A second scenario exercises the SLO layer: a mixed-class workload
 (latency requests with deadlines arriving over a pool already full of
 throughput work, plus more best-effort than the shed watermark admits)
 reports per-class TTFT/latency percentiles, deadline misses, preemption
 and retry counts — the rows `check_gate.py --require classes` enforces.
 
-A fourth scenario exercises the shared paged KV pool (runtime/kvpool.py)
+A third scenario exercises the shared paged KV pool (runtime/kvpool.py)
 on an attention arch: the same shared-preamble workload runs through the
 private-cache session and the paged session (`paged=True`), in waves so
 TTFT is queue-free. Wave 1 runs cold (empty prefix cache); later waves
@@ -31,7 +24,7 @@ concurrent requests the same pool memory holds relative to the private
 per-slot reservation (measured from actual page allocs, so prefix
 sharing counts).
 
-A fifth scenario prices the durability layer (runtime/journal.py +
+A fourth scenario prices the durability layer (runtime/journal.py +
 session snapshots + KV checksum scrub): the continuous workload runs
 with and without `durable_dir` to measure the fsync'd-journal overhead
 on fault-free tokens/s, a scripted crash + restore measures MTTR and
@@ -39,7 +32,7 @@ asserts exactly-once bit-identical completion, and a bit-flip on a
 published prefix page must be detected and repaired before any request
 reuses it — the row `check_gate.py --require recovery` enforces.
 
-A sixth scenario measures cluster-of-clusters scaling: the same
+A fifth scenario measures cluster-of-clusters scaling: the same
 per-group workload runs through `ShardedServeSessionProgram` at 1, 2,
 and 4 groups, in this process (a device belongs to one process at a
 time), each group on its own device: on a CPU host run it under
@@ -54,7 +47,7 @@ throughput is flat) it degenerates to a bound on the two-level
 scheduler's overhead. The row records `cores=` and `devices=` so the
 gate and readers know which regime was measured.
 
-Row format: serve/{continuous|static},us_per_token,tokens_per_s=..;...
+Row format: serve/continuous,us_per_token,tokens_per_s=..;...
             serve/class_{latency|throughput|best_effort},p99_lat_us,...
             serve/slo,us_per_token,preemptions=..;retries=..;shed=..
             serve/paged_kv,us_per_token,tokens_per_s=..;capacity_x=..
@@ -74,8 +67,7 @@ ARCH = "xlstm-125m-smoke"
 # their private per-slot state and reject paged mode)
 PAGED_ARCH = "qwen3-14b-smoke"
 PAGE_SIZE = 4
-# right-skewed output-length mix on {8..64} (multiples of the chunk so the
-# static path needs no tail-scan variants): mostly short, a long tail
+# right-skewed output-length mix on {8..64}: mostly short, a long tail
 OUT_LENS = (8, 8, 12, 16, 16, 24, 32, 64)
 CHUNK = 4
 SLOTS = 8
@@ -99,13 +91,10 @@ def run_continuous(program, params, prompts, outs) -> dict:
     sess.drain()
     wall = time.perf_counter() - t0
     st = sess.stats()
-    useful = sum(h.tokens.size + p.size - 1
-                 for h, p in zip(handles, prompts))   # prompt steps count too
     lats = sorted(h.latency_s for h in handles)
     import numpy as np
     return {
         "wall_s": wall,
-        "useful_slot_steps": useful,
         "emitted": st["emitted_total"],
         "tokens_per_s": st["emitted_total"] / wall,
         "occupancy_pct": st["occupancy_pct"],
@@ -140,58 +129,8 @@ def run_classes(program, params, n_bulk: int, n_lat: int, seed: int) -> dict:
     wall = time.perf_counter() - t0
     st = sess.stats()
     st["wall_s"] = wall
+    st["tokens_per_s"] = st["emitted_total"] / wall
     return st
-
-
-def run_static(decode, engine, cfg, params, prompts, outs) -> dict:
-    """The fixed-batch ServeProgram path (ServeLoop + DecodeEngine), gang-
-    scheduled: groups of SLOTS requests run to the group's slowest member.
-    The jitted decode step and the engine are shared across calls, so no
-    recompiles ride in the timing."""
-    import jax.numpy as jnp
-    import numpy as np
-
-    from repro.models import steps
-    from repro.runtime.serve_loop import ServeLoop
-    groups = [list(range(i, min(i + SLOTS, len(prompts))))
-              for i in range(0, len(prompts), SLOTS)]
-    max_seq = MAX_PROMPT + max(OUT_LENS) + 1
-    wall = 0.0
-    useful = total_slot_steps = 0
-    lats = []
-    for g in groups:
-        g_prompts, g_outs = [prompts[i] for i in g], [outs[i] for i in g]
-        max_p = max(p.size for p in g_prompts)
-        max_n = max(g_outs)
-        pad = np.zeros((SLOTS, max_p), np.int32)     # short prompts padded
-        for r, p in enumerate(g_prompts):
-            pad[r, :p.size] = p
-        t0 = time.perf_counter()
-        cache = steps.init_cache(cfg, SLOTS,
-                                 steps.decode_cache_len(cfg, max_seq))
-        tok = None
-        for t in range(max_p):                       # batch prompt ingest
-            cache, tok = decode(params, cache,
-                                {"tokens": jnp.asarray(pad[:, t:t + 1]),
-                                 "pos": jnp.asarray(t, jnp.int32)})
-        loop = ServeLoop(decode, params, cache, batch_size=SLOTS,
-                         eos_id=None, chunk=CHUNK, engine=engine)
-        loop.generate(np.asarray(tok), max_new=max_n, start_pos=max_p)
-        wall += time.perf_counter() - t0
-        useful += sum(p.size + n for p, n in zip(g_prompts, g_outs))
-        total_slot_steps += SLOTS * (max_p + max_n)
-        lats += [wall] * len(g)            # a request lands when its group does
-    # tokens/s counts USEFUL tokens: over-generated tail tokens past a
-    # request's max_new are waste, not throughput
-    useful_emitted = sum(outs)
-    return {
-        "wall_s": wall,
-        "useful_slot_steps": useful,
-        "emitted": useful_emitted,
-        "tokens_per_s": useful_emitted / wall,
-        "occupancy_pct": 100.0 * useful / total_slot_steps,
-        "p99_ms": float(np.percentile(np.asarray(lats), 99) * 1e3),
-    }
 
 
 def run_paged(smoke: bool) -> list[str]:
@@ -494,32 +433,22 @@ def run_groups(smoke: bool) -> list[str]:
 
 
 def main(smoke: bool = False) -> list[str]:
-    import jax
-
     from repro.cluster import Cluster, ServeSessionProgram
-    from repro.models import steps
 
     n_req = 24 if smoke else 48
     prompts, outs = _workload(n_req, seed=0)
 
     cluster = Cluster(ARCH)
-    cfg = cluster.arch
     max_seq = MAX_PROMPT + max(OUT_LENS) + 1
     program = cluster.compile(ServeSessionProgram(
         slots=SLOTS, max_seq=max_seq, max_prompt=MAX_PROMPT, chunk=CHUNK))
     params = program.init_params()
-    decode = jax.jit(steps.make_decode_step(cfg, max_seq=max_seq))
-    from repro.runtime.engine import DecodeEngine
-    engine = DecodeEngine(decode, CHUNK, eos_id=None)
 
-    # warm both paths (compiles stay out of the timed region)
+    # warm the cell (compiles stay out of the timed region)
     w_prompts, w_outs = _workload(SLOTS, seed=1)
     run_continuous(program, params, w_prompts, w_outs)
-    run_static(decode, engine, cfg, params, w_prompts[:SLOTS],
-               w_outs[:SLOTS])
 
     cont = run_continuous(program, params, prompts, outs)
-    stat = run_static(decode, engine, cfg, params, prompts, outs)
 
     # SLO scenario: same cell, priority admission + preemption + shedding
     n_bulk = 8 if smoke else 16
@@ -529,17 +458,15 @@ def main(smoke: bool = False) -> list[str]:
         shed_watermark=n_bulk + n_bulk // 2, preempt=True))
     slo = run_classes(slo_program, params, n_bulk, n_lat, seed=2)
 
-    lines = []
-    for name, r in (("continuous", cont), ("static", stat)):
-        us = 1e6 / r["tokens_per_s"] if r["tokens_per_s"] > 0 else float("nan")
-        extra = (f";ttft_p50_ms={r['ttft_p50_ms']:.1f}"
-                 if "ttft_p50_ms" in r else "")
-        lines.append(
-            f"serve/{name},{us:.1f},"
-            f"tokens_per_s={r['tokens_per_s']:.1f};"
-            f"occupancy_pct={r['occupancy_pct']:.1f};"
-            f"p99_ms={r['p99_ms']:.1f}{extra};"
-            f"requests={n_req};slots={SLOTS};chunk={CHUNK}")
+    us = (1e6 / cont["tokens_per_s"] if cont["tokens_per_s"] > 0
+          else float("nan"))
+    lines = [
+        f"serve/continuous,{us:.1f},"
+        f"tokens_per_s={cont['tokens_per_s']:.1f};"
+        f"occupancy_pct={cont['occupancy_pct']:.1f};"
+        f"p99_ms={cont['p99_ms']:.1f};"
+        f"ttft_p50_ms={cont['ttft_p50_ms']:.1f};"
+        f"requests={n_req};slots={SLOTS};chunk={CHUNK}"]
     for klass in ("latency", "throughput", "best_effort"):
         c = slo["classes"][klass]
         lines.append(

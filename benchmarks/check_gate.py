@@ -11,8 +11,7 @@ Checks (each prints one `gate ok:`/`gate FAIL:` line; any FAIL exits 1):
           us_per_call <= default_us * (1 + --tol)
   require comma-separated section presence: `tuned` (>=1 tuned row),
           `fused` (>=1 `table1_fused/*` row with both timings),
-          `decode` (K1 + K16 rows, positive tok/s),
-          `serve`  (continuous + static rows, positive tok/s),
+          `serve`  (the continuous row, positive tok/s),
           `classes` (per-class SLO rows: latency/throughput/best_effort +
           the serve/slo roll-up, with the scripted contention actually
           exercised — >=1 preemption, >=1 shed, 0 latency deadline misses)
@@ -33,9 +32,8 @@ Checks (each prints one `gate ok:`/`gate FAIL:` line; any FAIL exits 1):
           with >= 4 cores also monotone tok/s in group count and
           per-group stall within 2x of single-group)
   baseline (optional, vs a committed copy of BENCH_table1.json):
-          decode K16 stall_pct must not rise more than --stall-tol
-          percentage points; serve continuous occupancy_pct must not drop
-          more than --occ-tol percentage points; per-class p99 latency and
+          serve continuous occupancy_pct must not drop more than
+          --occ-tol percentage points; per-class p99 latency and
           TTFT p99 must not rise more than --class-tol (fraction), and
           per-class deadline misses must not exceed the baseline.
 
@@ -43,7 +41,7 @@ Usage (the CI perf-gate job):
 
     python benchmarks/run.py --smoke --json /tmp/bench.json   # refreshes
     python benchmarks/check_gate.py --bench BENCH_table1.json \
-        --baseline /tmp/baseline_table1.json --require tuned,fused,decode,serve
+        --baseline /tmp/baseline_table1.json --require tuned,fused,serve
 """
 
 from __future__ import annotations
@@ -53,8 +51,8 @@ import json
 import sys
 from pathlib import Path
 
-REQUIREMENTS = ("tuned", "fused", "decode", "serve", "classes", "paged",
-                "recovery", "groups")
+REQUIREMENTS = ("tuned", "fused", "serve", "classes", "paged", "recovery",
+                "groups")
 
 CLASS_ROWS = ("serve/class_latency", "serve/class_throughput",
               "serve/class_best_effort")
@@ -114,20 +112,13 @@ def check_require(gate: Gate, record: dict, require: list[str],
             and float(_derived(r).get("unfused_us", 0)) > 0 for r in rows)
         gate.check(ok, "require",
                    f"{len(rows)} table1_fused rows with both timings")
-    if "decode" in require:
-        by = _by_name(record.get("decode", []))
-        ok = {"decode/K1", "decode/K16"} <= set(by) and all(
-            float(_derived(r).get("tokens_per_s", 0)) > 0
-            for r in by.values())
-        gate.check(ok, "require",
-                   f"decode rows {sorted(by)} with positive tok/s")
     if "serve" in require:
         by = _by_name(record.get("serve_continuous", []))
-        need = {"serve/continuous", "serve/static"}
-        ok = need <= set(by) and all(
-            float(_derived(by[n]).get("tokens_per_s", 0)) > 0 for n in need)
+        row = by.get("serve/continuous")
+        ok = row is not None and float(
+            _derived(row).get("tokens_per_s", 0)) > 0
         gate.check(ok, "require",
-                   f"serve rows {sorted(set(by) & need)} with positive tok/s")
+                   "serve/continuous row with positive tok/s")
     if "classes" in require:
         by = _by_name(record.get("serve_continuous", []))
         missing = [n for n in CLASS_ROWS + ("serve/slo",) if n not in by]
@@ -234,16 +225,7 @@ def check_require(gate: Gate, record: dict, require: list[str],
 
 
 def check_baseline(gate: Gate, record: dict, baseline: dict,
-                   stall_tol: float, occ_tol: float,
-                   class_tol: float) -> None:
-    new_dec = _by_name(record.get("decode", []))
-    old_dec = _by_name(baseline.get("decode", []))
-    if "decode/K16" in new_dec and "decode/K16" in old_dec:
-        new_stall = float(_derived(new_dec["decode/K16"])["stall_pct"])
-        old_stall = float(_derived(old_dec["decode/K16"])["stall_pct"])
-        gate.check(new_stall <= old_stall + stall_tol, "baseline",
-                   f"K16 stall {new_stall:.1f}% vs baseline "
-                   f"{old_stall:.1f}% (+{stall_tol:.1f}pt tol)")
+                   occ_tol: float, class_tol: float) -> None:
     new_srv = _by_name(record.get("serve_continuous", []))
     old_srv = _by_name(baseline.get("serve_continuous", []))
     if "serve/continuous" in new_srv and "serve/continuous" in old_srv:
@@ -276,8 +258,6 @@ def main(argv: list[str] | None = None) -> int:
                     help="committed BENCH_table1.json to diff against")
     ap.add_argument("--tol", type=float, default=0.15,
                     help="tuned-vs-default timer-noise tolerance (fraction)")
-    ap.add_argument("--stall-tol", type=float, default=2.0,
-                    help="decode stall_pct regression tolerance (abs points)")
     ap.add_argument("--occ-tol", type=float, default=10.0,
                     help="serve occupancy regression tolerance (abs points)")
     ap.add_argument("--class-tol", type=float, default=1.0,
@@ -306,8 +286,7 @@ def main(argv: list[str] | None = None) -> int:
     check_require(gate, record, require, args.recovery_tol)
     if args.baseline:
         baseline = json.loads(Path(args.baseline).read_text())
-        check_baseline(gate, record, baseline, args.stall_tol, args.occ_tol,
-                       args.class_tol)
+        check_baseline(gate, record, baseline, args.occ_tol, args.class_tol)
 
     if gate.failures:
         print(f"perf gate: {len(gate.failures)} FAILURE(S)", file=sys.stderr)

@@ -28,9 +28,9 @@ Flags:
 Whenever the table1 section runs, its rows are also persisted to
 `BENCH_table1.json` at the repo root — the perf-trajectory record the CI
 smoke job refreshes on every run — and a `fused-vs-unfused:` summary line
-is printed for the fused kernel path. The decode (K=1 vs K=16 engine) and
-serve (continuous vs static batching) rows and their `decode-throughput:`
-/ `serve-continuous:` summary lines ride along in the same record.
+is printed for the fused kernel path. The serve rows (continuous batching,
+SLO classes, paged KV, recovery, serving groups) ride along in the same
+record.
 """
 
 from __future__ import annotations
@@ -48,15 +48,14 @@ from repro.cluster import BenchProgram, Cluster  # noqa: E402
 from repro.cluster.policy import MODES  # noqa: E402
 from repro.runtime.compile_cache import use_persistent_cache  # noqa: E402
 
-from benchmarks import (bench_decode_throughput,  # noqa: E402
-                        bench_fig4_interconnect, bench_fig5_hybrid,
-                        bench_fig13_scaling, bench_fig14_breakdown,
-                        bench_fig15_double_buffer, bench_fig16_energy,
-                        bench_serve_continuous, bench_table1_kernels)
+from benchmarks import (bench_fig4_interconnect,  # noqa: E402
+                        bench_fig5_hybrid, bench_fig13_scaling,
+                        bench_fig14_breakdown, bench_fig15_double_buffer,
+                        bench_fig16_energy, bench_serve_continuous,
+                        bench_table1_kernels)
 
 MODULES = [
     ("table1", bench_table1_kernels),
-    ("decode", bench_decode_throughput),
     ("serve", bench_serve_continuous),
     ("fig4", bench_fig4_interconnect),
     ("fig5", bench_fig5_hybrid),
@@ -83,55 +82,11 @@ def _fused_comparison_line(rows: list[dict]) -> str | None:
     return "# fused-vs-unfused: " + " | ".join(parts)
 
 
-def _decode_rows(results: dict) -> list[dict]:
-    section = results["sections"].get("decode")
-    if not section or section["status"] != "ok":
-        return []
-    return section["rows"]
-
-
-def _decode_comparison_line(rows: list[dict]) -> str | None:
-    """K=1 (per-token loop) vs K=16 (scan-compiled engine) summary."""
-    by_k = {}
-    for r in rows:
-        kv = dict(p.split("=", 1) for p in r["derived"].split(";"))
-        by_k[r["name"].removeprefix("decode/")] = (r["us_per_call"], kv)
-    if "K1" not in by_k or "K16" not in by_k:
-        return None
-    (us1, kv1), (us16, kv16) = by_k["K1"], by_k["K16"]
-    return (f"# decode-throughput: K16 {float(kv16['tokens_per_s']):.1f} tok/s"
-            f" (stall {float(kv16['stall_pct']):.1f}%,"
-            f" {kv16['host_syncs']} syncs) vs"
-            f" K1 {float(kv1['tokens_per_s']):.1f} tok/s"
-            f" (stall {float(kv1['stall_pct']):.1f}%,"
-            f" {kv1['host_syncs']} syncs) —"
-            f" {us1 / max(us16, 1e-9):.2f}x per-token speedup")
-
-
 def _serve_rows(results: dict) -> list[dict]:
     section = results["sections"].get("serve")
     if not section or section["status"] != "ok":
         return []
     return section["rows"]
-
-
-def _serve_comparison_line(rows: list[dict]) -> str | None:
-    """Continuous vs static batching summary from the serve section."""
-    by_name = {}
-    for r in rows:
-        kv = dict(p.split("=", 1) for p in r["derived"].split(";"))
-        by_name[r["name"].removeprefix("serve/")] = kv
-    if "continuous" not in by_name or "static" not in by_name:
-        return None
-    c, s = by_name["continuous"], by_name["static"]
-    tps_c, tps_s = float(c["tokens_per_s"]), float(s["tokens_per_s"])
-    occ_c, occ_s = float(c["occupancy_pct"]), float(s["occupancy_pct"])
-    return (f"# serve-continuous: {tps_c:.1f} tok/s, occ {occ_c:.1f}% vs"
-            f" static {tps_s:.1f} tok/s, occ {occ_s:.1f}% —"
-            f" {tps_c / max(tps_s, 1e-9):.2f}x tok/s,"
-            f" {occ_c / max(occ_s, 1e-9):.2f}x occupancy;"
-            f" p99 {float(c['p99_ms']):.0f}ms vs {float(s['p99_ms']):.0f}ms"
-            f" ({c['requests']} reqs, {c['slots']} slots)")
 
 
 def _persist_table1(results: dict, repeat: int) -> Path | None:
@@ -144,23 +99,10 @@ def _persist_table1(results: dict, repeat: int) -> Path | None:
               "rows": section["rows"]}
     if "tuning" in results:
         record["tuning"] = results["tuning"]
-    decode = _decode_rows(results)
-    if decode:
-        # the K=1 vs K=16 engine trajectory rides with the kernel table
-        record["decode"] = [r for r in decode
-                            if r["name"] in ("decode/K1", "decode/K16")]
-        line = _decode_comparison_line(decode)
-        if line:
-            record["decode_summary"] = line.removeprefix(
-                "# decode-throughput: ")
     serve = _serve_rows(results)
     if serve:
-        # continuous vs static batching rows ride along too
+        # the serving rows ride along too
         record["serve_continuous"] = serve
-        line = _serve_comparison_line(serve)
-        if line:
-            record["serve_summary"] = line.removeprefix(
-                "# serve-continuous: ")
     path.write_text(json.dumps(record, indent=2))
     return path
 
@@ -214,16 +156,6 @@ def main(argv: list[str] | None = None) -> None:
                       f"{' (frozen)' if db.frozen else ''}")
     print(tune_line)
     failed = results.pop("failed")
-    decode_rows = _decode_rows(results)
-    if decode_rows:
-        dec_line = _decode_comparison_line(decode_rows)
-        if dec_line:
-            print(dec_line)
-    serve_rows = _serve_rows(results)
-    if serve_rows:
-        srv_line = _serve_comparison_line(serve_rows)
-        if srv_line:
-            print(srv_line)
     table1 = results["sections"].get("table1")
     if table1 and table1["status"] == "ok":
         cmp_line = _fused_comparison_line(table1["rows"])

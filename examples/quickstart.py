@@ -12,7 +12,7 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
-from repro.cluster import Cluster, ServeProgram, TrainProgram
+from repro.cluster import Cluster, ServeSessionProgram, TrainProgram
 from repro.runtime.compile_cache import use_persistent_cache
 
 use_persistent_cache()
@@ -38,10 +38,15 @@ report = train.run()
 for m in report["metrics"]:
     print(f"step {m['step']}: loss={m['loss']:.4f}")
 
-# 4. greedy decode with a KV cache, reusing the trained params
-serve = cluster.compile(ServeProgram(batch=4, max_seq=32, max_new=8))
+# 4. greedy decode through a serving session (one request per slot),
+#    reusing the trained params
+serve = cluster.compile(ServeSessionProgram(slots=4, max_seq=32, max_new=8))
 out = serve.run(params=report["params"])
+stats = out["stats"]
 print("decoded:", out["tokens"][0].tolist())
+print(f"ttft p50={stats['ttft_ms']['p50']:.1f}ms "
+      f"latency p50={stats['latency_ms']['p50']:.1f}ms "
+      f"host syncs={stats['stall']['host_syncs']}")
 
 # 5. every program self-describes: spec + policy + compile-cache traffic
 print("program report:", {k: train.report()[k]

@@ -1,20 +1,19 @@
-"""Batched serving: decode a batch of requests against a shared KV cache.
+"""Batched serving: decode one batch of requests through a serving session.
 
-A thin wrapper over the Cluster façade: one `ServeProgram` handles the
-token-by-token prompt ingest (continuous-batching style) and the greedy
-generation loop. Generation runs on the device-resident execution engine —
-`--chunk K` decode steps are compiled into one `lax.scan` program with
-donated cache/token buffers, so the host syncs once per K tokens instead
-of per token (`--chunk 1` falls back to the per-token loop; the tokens are
-bit-identical either way). The stats line reports the StallClock ledger:
-host-sync count and `stall_pct`, the host-side dispatch gap as a fraction
-of wall time — the paper's execution-stall figure.
+A thin wrapper over the Cluster façade: `ServeSessionProgram(...).run()`
+submits one request per slot, prefills each prompt inside the compiled
+session cell, and drains the greedy generation. `--chunk K` decode steps
+are compiled into one `lax.scan` program over the donated pool state, so
+the host syncs once per K tokens instead of per token (`--chunk 1` syncs
+every token; the tokens are bit-identical either way). The stats lines
+report the session's per-request TTFT and latency and the StallClock
+ledger: host-sync count and `stall_pct`, the host-side dispatch gap as a
+fraction of wall time — the paper's execution-stall figure.
 
     PYTHONPATH=src python examples/serve_batched.py --batch 8 --new 32
 
-This is the fixed-batch path: every slot runs to the slowest request.
-For request-level serving — submit/stream/cancel against a slot pool with
-continuous batching — see `examples/serve_continuous.py` (ServeSession).
+For a stream of requests — submit/stream/cancel against the slot pool as
+they arrive — see `examples/serve_continuous.py`.
 """
 
 import argparse
@@ -25,8 +24,10 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 import jax
 
-from repro.cluster import Cluster, ServeProgram
+from repro.cluster import Cluster, ServeSessionProgram
 from repro.runtime.compile_cache import use_persistent_cache
+
+PROMPT_LEN = 8
 
 
 def main():
@@ -36,28 +37,29 @@ def main():
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--new", type=int, default=32)
     ap.add_argument("--chunk", type=int, default=16,
-                    help="decode steps per host sync (1 = per-token loop)")
+                    help="decode steps per host sync (1 = every token)")
     ap.add_argument("--eos-id", type=int, default=None,
                     help="stop a slot once it emits this token id")
     args = ap.parse_args()
 
     cluster = Cluster(args.arch + "-smoke")
     cfg = cluster.arch
-    program = cluster.compile(ServeProgram(batch=args.batch, max_seq=64,
-                                           max_new=args.new,
-                                           chunk=args.chunk,
-                                           eos_id=args.eos_id))
+    program = cluster.compile(ServeSessionProgram(
+        slots=args.batch, max_seq=64, max_prompt=PROMPT_LEN,
+        max_new=args.new, chunk=args.chunk, eos_id=args.eos_id))
 
-    prompt = jax.random.randint(jax.random.PRNGKey(0), (args.batch, 8), 0,
-                                cfg.vocab)
+    prompt = jax.random.randint(jax.random.PRNGKey(0),
+                                (args.batch, PROMPT_LEN), 0, cfg.vocab)
     out = program.run(prompt=prompt)
     stats = out["stats"]
     stall = stats["stall"]
     print(f"arch={cfg.name} batch={args.batch} generated {args.new} "
           f"tokens/slot (chunk={stats['chunk']})")
-    print(f"p50={stats['p50_ms']:.1f}ms p99={stats['p99_ms']:.1f}ms "
-          f"{stats['tokens_per_s_per_slot']:.1f} tok/s/slot")
-    print(f"engine: {stall['host_syncs']} host syncs, "
+    print(f"ttft p50={stats['ttft_ms']['p50']:.1f}ms "
+          f"p99={stats['ttft_ms']['p99']:.1f}ms, latency "
+          f"p50={stats['latency_ms']['p50']:.1f}ms "
+          f"p99={stats['latency_ms']['p99']:.1f}ms")
+    print(f"session: {stall['host_syncs']} host syncs, "
           f"stall={stall['stall_pct']:.1f}% "
           f"(dispatch gap {stall['dispatch_gap_s'] * 1e3:.1f}ms over "
           f"{stall['wall_s'] * 1e3:.0f}ms)")

@@ -4,9 +4,9 @@ Requests with mixed prompt/output lengths arrive on a Poisson clock and
 flow through a fixed slot pool: the scheduler admits each one into the
 first recycled slot (per-slot prompt prefill and position reset happen
 inside the compiled chunk), so short requests finish and free their slot
-while long ones keep decoding — no slot waits for a batch to drain. The
-fixed-batch equivalent (`examples/serve_batched.py`, ServeProgram) still
-works unchanged for the one-rectangular-batch case.
+while long ones keep decoding — no slot waits for a batch to drain. For
+one batch submitted at once, see `examples/serve_batched.py` (the same
+session's one-shot `run()`).
 
 Prints per-request TTFT/latency as requests complete, then the session
 stats: slot occupancy (the MemPool PE-utilization analogue), tokens/s,

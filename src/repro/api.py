@@ -10,8 +10,7 @@ and Halide (declarative). This framework mirrors that:
   Halide     : the config-driven launcher (repro.launch.train CLI)
 
 New code should build a `repro.cluster.Cluster` and compile programs on it;
-these wrappers exist so old call sites keep working with identical return
-shapes.
+these wrappers exist so old call sites keep working.
 """
 
 from __future__ import annotations
@@ -61,17 +60,15 @@ def train(arch: str, *, num_steps: int | None = None, steps_=_UNSET,
 def serve(arch: str, params=None, *, batch: int = 4, max_seq: int = 64,
           max_new: int = 16, smoke: bool = True, seed: int = 0,
           chunk: int = 1) -> dict:
-    """One-call batched greedy decoding. Returns tokens + latency stats.
+    """One-call batched greedy decoding. Returns tokens + session stats.
 
     Shim over the request-level serving API: opens a `ServeSession`
     (`Cluster(...).compile(ServeSessionProgram(...))`), submits one batch
-    of requests (one per slot), and drains — the legacy return shape
-    (tokens array + ServeLoop-style stats) is preserved, and the decoded
-    tokens are bit-identical to the old fixed-batch `ServeProgram` path.
-    `chunk` is the decode-steps-per-host-sync knob (1 = one sync per
-    token, the legacy default; K > 1 buries K steps in one device
-    program). New code should open a session directly and use
-    `submit`/`stream`/`drain`.
+    of requests (one per slot), and drains; the result is
+    `CompiledServeSession.run()`'s `{"tokens", "stats"}`. `chunk` is the
+    decode-steps-per-host-sync knob (1 = one sync per token; K > 1 buries
+    K steps in one device program). New code should open a session
+    directly and use `submit`/`stream`/`drain`.
     """
     cluster = Cluster(arch + ("-smoke" if smoke else ""))
     program = cluster.compile(ServeSessionProgram(

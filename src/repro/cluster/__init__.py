@@ -12,10 +12,10 @@ from repro.cluster.policy import (KernelPolicy, as_policy,  # noqa: F401
                                   use_policy)
 from repro.kernels.tunedb import TuneDB  # noqa: F401  (dependency-light)
 
-_SESSION_EXPORTS = ("Cluster", "Program", "TrainProgram", "ServeProgram",
+_SESSION_EXPORTS = ("Cluster", "Program", "TrainProgram",
                     "ServeSessionProgram", "ShardedServeSessionProgram",
                     "DryRunProgram", "BenchProgram",
-                    "CompiledTrain", "CompiledServe", "CompiledServeSession",
+                    "CompiledTrain", "CompiledServeSession",
                     "CompiledShardedServeSession",
                     "CompiledDryRun", "CompiledBench")
 

@@ -86,19 +86,16 @@ def build_cell(cfg, shape, mesh, rules, fsdp_gather: bool = False,
                page_size: int = 16):
     """Returns (fn, args_sds, in_shardings, out_shardings, donate).
 
-    `decode_chunk > 1` (decode shapes only) builds the execution-engine
-    cell instead of the single-step one: K decode steps rolled into one
-    `lax.scan` with donated cache/token/flag buffers — the program the
-    dry-run lowers then mirrors what `ServeProgram(chunk=K)` runs.
-
-    `session=True` (decode shapes) builds the continuous-batching session
-    cell instead: the K-step slot-scheduled chunk over the donated pool
-    state (per-slot positions, prompt buffers, budgets — see
+    A decode shape lowers the single decode step. `session=True` (decode
+    shapes) builds the continuous-batching session cell instead: the
+    `decode_chunk`-step slot-scheduled chunk over the donated pool state
+    (per-slot positions, prompt buffers, budgets — see
     `engine.session_chunk_fn`), mirroring what a compiled
-    `ServeSessionProgram` steps between refills. `paged=True` (session
-    shapes) lowers the shared-paged-KV variant of that cell: pageable
-    K/V leaves become the global page pool and the state carries the
-    per-slot page tables (`ServeSessionProgram(paged=True)`).
+    `ServeSessionProgram` steps between refills; `decode_chunk > 1`
+    without it raises `ValueError`. `paged=True` (session shapes) lowers
+    the shared-paged-KV variant of that cell: pageable K/V leaves become
+    the global page pool and the state carries the per-slot page tables
+    (`ServeSessionProgram(paged=True)`).
     """
     batch_sds = input_specs(cfg, shape)
     batch_log = batch_logical(cfg, shape)
@@ -164,26 +161,9 @@ def build_cell(cfg, shape, mesh, rules, fsdp_gather: bool = False,
                   slot_sh, scalar_sh)
         return fn, (params_sds, state_sds), (params_sh, state_sh), out_sh, (1,)
     if decode_chunk > 1:
-        from repro.runtime import engine
-        step = steps.make_decode_step(cfg, max_seq=shape.seq_len,
-                                      policy=policy)
-        fn = engine.decode_chunk_fn(step, decode_chunk)
-        B = shape.global_batch
-        i32 = jax.ShapeDtypeStruct((), jax.numpy.int32)
-        slot_sds = lambda dt: jax.ShapeDtypeStruct((B,), dt)
-        slot_sh = NamedSharding(
-            mesh, rules.spec_for(("batch",), (B,), mesh))
-        args = (params_sds, cache_sds, batch_sds["tokens"],
-                slot_sds(jax.numpy.bool_), slot_sds(jax.numpy.int32),
-                i32, i32)
-        scalar_sh = NamedSharding(mesh, jax.sharding.PartitionSpec())
-        in_sh = (params_sh, cache_sh, batch_sh["tokens"], slot_sh, slot_sh,
-                 scalar_sh, scalar_sh)
-        toks_sh = NamedSharding(
-            mesh, rules.spec_for(("batch", None), (B, decode_chunk), mesh))
-        out_sh = (cache_sh, batch_sh["tokens"], slot_sh, slot_sh, scalar_sh,
-                  scalar_sh, scalar_sh, toks_sh)
-        return fn, args, in_sh, out_sh, (1, 2, 3, 4)
+        raise ValueError(
+            f"decode_chunk={decode_chunk} without session=True: no program "
+            f"runs a K-step decode cell outside the session")
     fn = steps.make_decode_step(cfg, max_seq=shape.seq_len, policy=policy)
     return (fn, (params_sds, cache_sds, batch_sds),
             (params_sh, cache_sh, batch_sh), (cache_sh, tok_sh), (1,))

@@ -13,12 +13,12 @@ module is the substrate object for the TPU translation:
 
 `Cluster` owns the ArchConfig, the mesh, the hybrid-addressing rules, the
 KERNEL_TUNES view, and a CompileCache; `cluster.compile(spec)` turns a
-program spec (TrainProgram / ServeProgram / DryRunProgram / BenchProgram)
-into a Program with `.run()`, `.plan()`, and `.report()`. Every entrypoint
-(`repro.api`, `launch/train.py`, `launch/dryrun.py`, `benchmarks/run.py`,
-the examples) is a thin wrapper over these objects, so later subsystems
-(continuous batching, multi-cluster, backend selection) plug into one
-place instead of five.
+program spec (TrainProgram / ServeSessionProgram / DryRunProgram /
+BenchProgram) into a Program with `.run()`, `.plan()`, and `.report()`.
+Every entrypoint (`repro.api`, `launch/train.py`, `launch/dryrun.py`,
+`benchmarks/run.py`, the examples) is a thin wrapper over these objects,
+so later subsystems (continuous batching, multi-cluster, backend
+selection) plug into one place instead of five.
 """
 
 from __future__ import annotations
@@ -28,7 +28,6 @@ import time
 from typing import Any, Callable, Iterator
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.cluster import cells
@@ -39,8 +38,7 @@ from repro.configs.registry import (ArchConfig, SHAPES, cell_supported,
 from repro.core import addressing, compat
 from repro.kernels import tunedb
 from repro.models import steps
-from repro.runtime import (CompileCache, ServeLoop, TrainLoop,
-                           TrainLoopConfig, engine)
+from repro.runtime import CompileCache, TrainLoop, TrainLoopConfig, engine
 
 
 # ----------------------------------------------------------------------------
@@ -68,20 +66,6 @@ class TrainProgram:
 
 
 @dataclasses.dataclass(frozen=True)
-class ServeProgram:
-    """Batched greedy decoding against a KV cache."""
-
-    batch: int = 4
-    max_seq: int = 64
-    max_new: int = 16
-    seed: int = 0
-    eos_id: int | None = None
-    chunk: int = 16                        # decode steps per host sync:
-    #   1 = per-token host loop; K > 1 = scan-compiled K-step engine with
-    #   donated cache/token buffers (runtime/engine.py)
-
-
-@dataclasses.dataclass(frozen=True)
 class ServeSessionProgram:
     """Request-level serving: a slot pool with continuous batching.
 
@@ -89,7 +73,7 @@ class ServeSessionProgram:
     `ServeSession` with `submit(prompt, max_new, klass=..., deadline_s=...)
     -> RequestHandle`, `poll()`/`stream()` for incremental tokens,
     `cancel(handle)`, and `drain()`. `run()` is the one-shot path (fill
-    the pool with one batch, drain, legacy `ServeProgram`-shaped result).
+    the pool with one batch, drain, return the tokens and the stats).
 
     The SLO/robustness knobs configure the session's priority admission
     (`shed_watermark`, `aging_rounds`), slot preemption (`preempt`), the
@@ -166,7 +150,7 @@ class ShardedServeSessionProgram(ServeSessionProgram):
     is token-for-token identical to `ServeSessionProgram.open()` (same
     cell, same scheduler, a trivial placement layer) and its durable
     directory stays restorable by either program. `run()` (the one-shot
-    legacy path) is not defined for sharded sessions.
+    path) is not defined for sharded sessions.
     """
 
     groups: int = 2                        # serving groups (session cells)
@@ -179,8 +163,8 @@ class DryRunProgram:
 
     shape: str = "train_4k"
     fsdp_gather: bool = False
-    decode_chunk: int = 1                  # decode shapes: lower the K-step
-    #   scan-compiled engine cell instead of the single-step one
+    decode_chunk: int = 1                  # session cells: decode steps per
+    #   chunk (> 1 without `session` is refused: no program runs that cell)
     session: bool = False                  # decode shapes: lower the slot-
     #   scheduled session cell (donated pool state) instead
     paged: bool = False                    # session shapes: lower the
@@ -301,7 +285,7 @@ class Cluster:
     def compile(self, spec) -> "Program":
         """Program spec -> compiled Program, memoized in the compile cache
         keyed on (spec, arch, mesh, policy knobs)."""
-        builders = {TrainProgram: CompiledTrain, ServeProgram: CompiledServe,
+        builders = {TrainProgram: CompiledTrain,
                     ServeSessionProgram: CompiledServeSession,
                     ShardedServeSessionProgram: CompiledShardedServeSession,
                     DryRunProgram: CompiledDryRun, BenchProgram: CompiledBench}
@@ -485,63 +469,13 @@ class CompiledTrain(Program):
         return report
 
 
-class CompiledServe(Program):
-    kind = "serve"
-
-    def __init__(self, cluster, spec: ServeProgram, policy):
-        super().__init__(cluster, spec, policy)
-        cfg = cluster._require_arch("ServeProgram")
-        self.decode: Callable = jax.jit(
-            steps.make_decode_step(cfg, max_seq=spec.max_seq, policy=policy))
-        # the K-step scan program is built once here so repeated .run()s
-        # hit the jit cache instead of re-tracing the whole chunk
-        self.engine = (engine.DecodeEngine(self.decode, spec.chunk,
-                                           eos_id=spec.eos_id)
-                       if spec.chunk > 1 else None)
-
-    def init_params(self, seed: int | None = None):
-        cfg = self.cluster.arch
-        seed = self.spec.seed if seed is None else seed
-        return steps.init_params(cfg, jax.random.PRNGKey(seed),
-                                 max_seq=self.spec.max_seq)
-
-    def run(self, params=None, prompt=None) -> dict:
-        """Greedy decode `max_new` tokens per slot. `prompt` (B, P) is fed
-        token-by-token first (continuous-batching-style ingest); generation
-        then continues from the last sampled token."""
-        cfg, spec = self.cluster.arch, self.spec
-        if params is None:
-            params = self.init_params()
-        cache = steps.init_cache(cfg, spec.batch,
-                                 steps.decode_cache_len(cfg, spec.max_seq))
-        start = np.zeros((spec.batch, 1), np.int32)
-        pos0 = 0
-        if prompt is not None:
-            prompt = np.asarray(prompt)
-            tok = None
-            for t in range(prompt.shape[1]):
-                cache, tok = self.decode(
-                    params, cache,
-                    {"tokens": jnp.asarray(prompt[:, t:t + 1], jnp.int32),
-                     "pos": jnp.asarray(t, jnp.int32)})
-            start, pos0 = np.asarray(tok), prompt.shape[1]
-        loop = ServeLoop(self.decode, params, cache, batch_size=spec.batch,
-                         eos_id=spec.eos_id, chunk=spec.chunk,
-                         engine=self.engine)
-        out = loop.generate(start, max_new=spec.max_new, start_pos=pos0)
-        result = {"tokens": out, "stats": loop.stats()}
-        self._last_run = {"stats": result["stats"],
-                          "tokens_shape": tuple(out.shape)}
-        return result
-
-
 class CompiledServeSession(Program):
     """Request-level serving: slot pool + scheduler + compiled session cell.
 
     `open()` hands out a live `ServeSession`; `run()` is the one-shot path
     that fills every slot with one batch of requests, drains, and returns
-    the legacy `ServeProgram` result shape — bit-identical tokens for
-    single-batch submission (api.serve routes through this).
+    the tokens as one (B, 1+max_new) array (api.serve routes through
+    this).
     """
 
     kind = "serve_session"
@@ -716,13 +650,15 @@ class CompiledServeSession(Program):
 
     def run(self, params=None, prompt=None, max_new: int | None = None) -> dict:
         """One-shot: submit one batch (one request per slot), drain, return
-        the legacy `{"tokens": (B, 1+max_new), "stats": ...}` shape.
+        `{"tokens": (B, 1+max_new), "stats": ...}`.
 
-        Without `prompt`, slot i's request is the single start token 0 —
-        exactly the `ServeProgram` path, bit for bit (tokens, EOS
-        masking/early-stop, and `emitted_per_slot`). With `prompt` (B, P),
-        the prompt is prefilled per slot and the first sampled token lands
-        in column 0, as `ServeProgram.run(prompt=...)` does.
+        Without `prompt`, slot i's request is the single start token 0,
+        which sits in column 0. With `prompt` (B, P), the prompt is
+        prefilled per slot and the first sampled token lands in column 0.
+        Rows that stop early at `eos_id` are padded with it (with 0 when
+        there is none). `stats` is the drained session's `stats()` plus
+        `emitted_per_slot` (tokens after column 0) and, with an `eos_id`,
+        `finished_slots`.
         """
         spec = self.spec
         max_new = spec.max_new if max_new is None else max_new
@@ -734,11 +670,10 @@ class CompiledServeSession(Program):
         else:
             prompt = np.asarray(prompt)
             rows = [prompt[i] for i in range(B)]
-            # +1: the last prefill step's output (legacy column 0) counts
-            # toward the session budget but not toward legacy emitted
+            # +1: the last prefill step's output is column 0
             per_req = max_new + 1
         handles = [sess.submit(r, per_req) for r in rows]
-        sess_stats = sess.drain()
+        stats = sess.drain()
         toks = [h.result() for h in handles]
         if prompt is None:
             toks = [np.concatenate([[0], t]).astype(np.int32) for t in toks]
@@ -747,30 +682,11 @@ class CompiledServeSession(Program):
         out = np.full((B, w), pad, np.int32)
         for i, t in enumerate(toks):
             out[i, :t.size] = t
-        stats = self._legacy_stats(sess, handles,
-                                   gen_offset=0 if prompt is None else 1)
-        stats["session"] = sess_stats
-        result = {"tokens": out, "stats": stats}
-        self._last_run = {"stats": {k: v for k, v in stats.items()
-                                    if k != "session"},
-                          "session": sess_stats,
-                          "tokens_shape": tuple(out.shape)}
-        return result
-
-    def _legacy_stats(self, sess, handles, gen_offset: int) -> dict:
-        """`ServeLoop.stats()`-shaped dict from a drained one-shot session
-        (per-token percentiles over post-warmup chunks, stall ledger,
-        emitted_per_slot in legacy generation-step counting)."""
-        from repro.runtime.serve_loop import chunked_latency_stats
-
-        st = chunked_latency_stats(sess.chunk_latencies)
-        st["chunk"] = sess.chunk
-        st["stall"] = sess.clock.report()
-        st["emitted_per_slot"] = [int(h.tokens.size - gen_offset)
-                                  for h in handles]
-        if self.spec.eos_id is not None:
-            st["finished_slots"] = sum(h.hit_eos for h in handles)
-        return st
+        stats["emitted_per_slot"] = [t.size - 1 for t in toks]
+        if spec.eos_id is not None:
+            stats["finished_slots"] = sum(h.hit_eos for h in handles)
+        self._last_run = {"stats": stats, "tokens_shape": tuple(out.shape)}
+        return {"tokens": out, "stats": stats}
 
     def report(self) -> dict:
         out = super().report()
@@ -888,8 +804,8 @@ class CompiledShardedServeSession(CompiledServeSession):
 
     def run(self, params=None, prompt=None, max_new=None) -> dict:
         raise NotImplementedError(
-            "the one-shot legacy path is not defined for sharded "
-            "sessions; use open() + submit/drain")
+            "the one-shot path is not defined for sharded sessions; use "
+            "open() + submit/drain")
 
 
 class CompiledDryRun(Program):

@@ -823,18 +823,6 @@ def make_decode_step(cfg, max_seq: int = 1 << 30, *, policy=None,
     return decode_step
 
 
-def make_decode_chunk(cfg, chunk: int, max_seq: int = 1 << 30, *,
-                      eos_id: int | None = None, policy=None,
-                      donate: bool = True):
-    """Scan-compiled K-token decode program (the execution-engine entry):
-    `make_decode_step` rolled into one `lax.scan` of `chunk` steps with
-    on-device EOS masking/early-exit and donated cache/token buffers. See
-    `runtime/engine.make_decode_chunk` for the calling convention."""
-    from repro.runtime import engine
-    step = make_decode_step(cfg, max_seq=max_seq, policy=policy)
-    return engine.make_decode_chunk(step, chunk, eos_id=eos_id, donate=donate)
-
-
 def decode_cache_len(cfg, seq_len: int) -> int:
     """Physical cache length: windowed archs keep a rolling window buffer."""
     if cfg.window and cfg.window < seq_len:

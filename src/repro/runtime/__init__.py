@@ -1,7 +1,7 @@
-from .engine import (DecodeEngine, StallClock, init_session_state,  # noqa: F401
-                     make_decode_chunk, make_nan_scan, make_session_chunk,
-                     make_session_refill, make_slot_corrupt,
-                     make_slot_restore, make_slot_snapshot, make_train_chunk)
+from .engine import (StallClock, init_session_state,  # noqa: F401
+                     make_nan_scan, make_session_chunk, make_session_refill,
+                     make_slot_corrupt, make_slot_restore,
+                     make_slot_snapshot, make_train_chunk)
 from .faults import (Fault, FaultPlan, InjectedFault,  # noqa: F401
                      SessionCrashed, SessionWedged)
 from .groups import (GroupPlan, GroupRuntime, GroupView,  # noqa: F401
@@ -13,5 +13,5 @@ from .scheduler import (QueueFull, Request, RequestFailed,  # noqa: F401
                         RequestHandle, SlotScheduler, deserialize_request,
                         serialize_request)
 from .train_loop import TrainLoop, TrainLoopConfig  # noqa: F401
-from .serve_loop import ServeLoop, ServeSession  # noqa: F401
+from .serve_loop import ServeSession  # noqa: F401
 from .compile_cache import CompileCache  # noqa: F401

@@ -9,13 +9,13 @@ small models it dominates wall time.
 
 This module rolls the loop onto the device:
 
-* `make_decode_chunk` compiles K decode steps into ONE `lax.scan` program.
-  EOS masking, the per-slot emitted counter, and the all-finished early-exit
-  all live inside the scan (`lax.cond` skips the model body once every slot
-  has finished), so the host syncs once per K tokens instead of once per
-  token. The KV cache and the token/flag buffers are donated
-  (`donate_argnums`), so steady-state decode re-uses the same device
-  allocations chunk after chunk.
+* `make_session_chunk` compiles K decode steps over a whole slot pool into
+  ONE `lax.scan` program. Per-slot prompt prefill, positions, EOS and
+  budget masking all live inside the scan (`lax.cond` skips the model body
+  once every slot is done), so the host syncs once per K tokens instead of
+  once per token. The pool state is donated (`donate_argnums`), so
+  steady-state serving re-uses the same device allocations chunk after
+  chunk; the refill and the programs that write the pool donate it too.
 * `make_train_chunk` is the same treatment for training: a scan over a
   stacked batch of `steps_per_sync` micro-iterations with the whole train
   state donated; the straggler detector and logger sample at chunk
@@ -38,7 +38,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 
 # ----------------------------------------------------------------------------
@@ -126,163 +125,6 @@ class StallClock:
                 if c._last_sync_end is not None]
         out._last_sync_end = max(ends) if ends else None
         return out
-
-
-# ----------------------------------------------------------------------------
-# Scan-compiled multi-token decode
-# ----------------------------------------------------------------------------
-
-
-def decode_chunk_fn(decode_step: Callable, chunk: int,
-                    eos_id: int | None = None) -> Callable:
-    """The pure K-step decode program (unjitted — see `make_decode_chunk`).
-
-    Signature::
-
-        chunk_fn(params, cache, tok, finished, emitted, pos, remaining)
-          -> (cache, tok, finished, emitted, pos, n_steps, all_done, tokens)
-
-    `tok` (B, 1) is the last sampled token, `finished`/`emitted` the per-slot
-    EOS flags and emitted-token counters, `pos` the decode position and
-    `remaining` how many tokens the caller still wants (both traced int32
-    scalars, so one compiled program serves every chunk of a generation).
-    `tokens` is (B, K); only the first `n_steps` columns are valid — padding
-    steps (past `remaining`, or after every slot finished) are skipped with
-    `lax.cond`, i.e. the model body does not run for them.
-
-    Step semantics replicate the per-token host loop bit for bit: `emitted`
-    counts a slot's tokens up to and including its EOS; a finished slot's
-    tokens are masked to EOS before being fed back and recorded.
-    """
-
-    def chunk_fn(params, cache, tok, finished, emitted, pos, remaining):
-        def body(carry, k):
-            cache, tok, finished, emitted, pos, n = carry
-            stop = k >= remaining
-            if eos_id is not None:
-                stop = jnp.logical_or(stop, jnp.all(finished))
-            active = jnp.logical_not(stop)
-
-            def run(operand):
-                cache, tok = operand
-                return decode_step(params, cache,
-                                   {"tokens": tok, "pos": pos})
-
-            def skip(operand):
-                return operand
-
-            new_cache, raw_tok = jax.lax.cond(active, run, skip, (cache, tok))
-            if eos_id is not None:
-                # finished slots (and padding steps) hold EOS regardless of
-                # the argmax — exactly the host loop's masking order
-                mask = jnp.logical_or(finished, stop)
-                out_tok = jnp.where(mask[:, None], eos_id, raw_tok)
-                new_finished = jnp.where(active,
-                                         jnp.logical_or(
-                                             finished,
-                                             out_tok[:, 0] == eos_id),
-                                         finished)
-            else:
-                out_tok = raw_tok
-                new_finished = finished
-            new_emitted = emitted + jnp.where(
-                active, jnp.logical_not(finished).astype(emitted.dtype), 0)
-            step = active.astype(jnp.int32)
-            carry = (new_cache, out_tok, new_finished, new_emitted,
-                     pos + step, n + step)
-            return carry, out_tok
-
-        init = (cache, tok, finished, emitted, pos, jnp.zeros((), jnp.int32))
-        (cache, tok, finished, emitted, pos, n), toks = jax.lax.scan(
-            body, init, jnp.arange(chunk, dtype=jnp.int32))
-        all_done = (jnp.all(finished) if eos_id is not None
-                    else jnp.zeros((), bool))
-        tokens = jnp.moveaxis(toks[..., 0], 0, 1)        # (K, B, 1) -> (B, K)
-        return cache, tok, finished, emitted, pos, n, all_done, tokens
-
-    return chunk_fn
-
-
-def make_decode_chunk(decode_step: Callable, chunk: int, *,
-                      eos_id: int | None = None,
-                      donate: bool = True) -> Callable:
-    """Jit `decode_chunk_fn`, donating the cache/token/flag buffers so
-    steady-state decode runs allocation-free. Donated inputs are invalid
-    after the call — callers must thread the returned buffers forward."""
-    fn = decode_chunk_fn(decode_step, chunk, eos_id)
-    return jax.jit(fn, donate_argnums=(1, 2, 3, 4) if donate else ())
-
-
-class DecodeEngine:
-    """Drives a scan-compiled decode program chunk by chunk.
-
-    One `generate` produces up to `max_new` tokens with `ceil(T / K)` host
-    syncs instead of `T`. Per-chunk wall times land in `chunk_latencies`
-    as `(seconds, steps)` pairs and the stall ledger in `clock`.
-    """
-
-    def __init__(self, decode_step: Callable, chunk: int = 16, *,
-                 eos_id: int | None = None, donate: bool = True):
-        if chunk < 1:
-            raise ValueError(f"chunk must be >= 1, got {chunk}")
-        self.chunk = chunk
-        self.eos_id = eos_id
-        self.donate = donate
-        self._decode_step = decode_step
-        # scan programs keyed by scan length: the steady chunk is K; a tail
-        # chunk (max_new % K) compiles a short-scan variant once and reuses
-        # it, instead of running K iterations with every step masked off
-        self._chunk_fns: dict[int, Callable] = {
-            chunk: make_decode_chunk(decode_step, chunk, eos_id=eos_id,
-                                     donate=donate)}
-        self.clock = StallClock()
-        self.chunk_latencies: list[tuple[float, int]] = []
-
-    def _fn_for(self, k: int) -> Callable:
-        fn = self._chunk_fns.get(k)
-        if fn is None:
-            fn = make_decode_chunk(self._decode_step, k, eos_id=self.eos_id,
-                                   donate=self.donate)
-            self._chunk_fns[k] = fn
-        return fn
-
-    def generate(self, params, cache, start_tok: np.ndarray, max_new: int,
-                 start_pos: int = 0):
-        """Returns (out (B, 1 + T) np.int32, cache, finished, emitted).
-
-        `out[:, 0]` is the start token; T <= max_new generation columns
-        follow (shorter when every slot hits EOS early). `cache` is the
-        final donated-through KV cache; the caller's input cache buffer is
-        consumed.
-        """
-        start_tok = np.asarray(start_tok)
-        B = start_tok.shape[0]
-        out = np.empty((B, 1 + max_new), np.int32)       # one host buffer
-        out[:, 0] = start_tok[:, 0]
-        tok = jnp.asarray(start_tok, jnp.int32)
-        finished = jnp.zeros((B,), bool)
-        emitted = jnp.zeros((B,), jnp.int32)
-        pos = jnp.asarray(start_pos, jnp.int32)
-        self.clock = StallClock()
-        self.chunk_latencies = []
-        w = 0
-        while w < max_new:
-            remaining = max_new - w
-            k = min(self.chunk, remaining)      # tail chunk: short scan
-            t0 = self.clock.dispatch()
-            (cache, tok, finished, emitted, pos, n, all_done,
-             toks) = self._fn_for(k)(params, cache, tok, finished, emitted,
-                                     pos, jnp.asarray(remaining, jnp.int32))
-            self.clock.sync(n, all_done, toks)
-            dt = time.perf_counter() - t0
-            n = int(n)
-            self.chunk_latencies.append((dt, n))
-            out[:, 1 + w:1 + w + n] = np.asarray(toks)[:, :n]
-            w += n
-            if n < k or bool(all_done):
-                break
-        return (out[:, :1 + w], cache, np.asarray(finished),
-                np.asarray(emitted, np.int64))
 
 
 # ----------------------------------------------------------------------------

@@ -323,9 +323,11 @@ class SlotScheduler:
         return shed
 
     def pop_shed(self) -> list[Request]:
-        """Requests shed since the last call (driver event/stats hook)."""
+        """Requests shed since the last call, in the order they were
+        submitted (driver event/stats hook). Shedding takes the newest
+        best-effort request first, so the log itself can run backwards."""
         out, self._shed_log = self._shed_log, []
-        return out
+        return sorted(out, key=lambda r: r.rid)
 
     def fail(self, req: Request, reason: str) -> None:
         """Terminal failure (shed / retries exhausted). Queued requests are

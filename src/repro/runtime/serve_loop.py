@@ -1,16 +1,11 @@
-"""Serving drivers: batch programs (`ServeLoop`) and request-level
-continuous batching (`ServeSession`).
+"""The serving driver: request-level continuous batching (`ServeSession`).
 
-`ServeLoop` is the fixed-batch driver: one rectangular batch of prompts
-runs to completion, so a slot that finishes early idles until the slowest
-request drains — the software analogue of MemPool's stalled-PE problem.
-
-`ServeSession` is the request-level driver: a fixed slot pool stepped by
-the scan-compiled session cell (runtime/engine.py), with a host-side
-`SlotScheduler` (runtime/scheduler.py) evicting finished slots and
-admitting queued requests between chunks. Steady-state decode stays
-allocation-free (the whole pool state is donated through every chunk and
-refill) and the host syncs once per K tokens.
+`ServeSession` is a fixed slot pool stepped by the scan-compiled session
+cell (runtime/engine.py), with a host-side `SlotScheduler`
+(runtime/scheduler.py) evicting finished slots and admitting queued
+requests between chunks. Steady-state decode stays allocation-free (the
+whole pool state is donated through every chunk and refill) and the host
+syncs once per K tokens.
 """
 
 from __future__ import annotations
@@ -22,34 +17,9 @@ from pathlib import Path
 from typing import Callable, Iterator
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-HISTORY = 4096          # sliding-window length for session stats records
-
-
-def chunked_latency_stats(samples) -> dict:
-    """Per-token latency stats from `(seconds, steps)` chunk samples.
-
-    The first sample is dropped (it carries compilation); with zero
-    post-warmup samples the figures report 0.0 rather than fake
-    `1/epsilon` numbers. Shared by `ServeLoop.stats` (engine path) and
-    the session's legacy-shaped one-shot stats so the two cannot drift.
-    """
-    samples = list(samples)
-    lat = np.asarray([dt for dt, _ in samples[1:]], np.float64)
-    steps = np.asarray([n for _, n in samples[1:]], np.int64)
-    tokens = int(steps.sum())
-    if lat.size == 0 or tokens == 0:
-        return {"decode_steps": 0, "p50_ms": 0.0, "p99_ms": 0.0,
-                "tokens_per_s_per_slot": 0.0}
-    per_tok = lat / np.maximum(steps, 1)
-    return {"decode_steps": tokens,
-            "p50_ms": float(np.percentile(per_tok, 50) * 1e3),
-            "p99_ms": float(np.percentile(per_tok, 99) * 1e3),
-            "tokens_per_s_per_slot": float(tokens / max(lat.sum(), 1e-9))}
-
-from repro.runtime.engine import (DecodeEngine, StallClock, make_nan_scan,
+from repro.runtime.engine import (StallClock, make_nan_scan,
                                   make_slot_corrupt, make_slot_restore,
                                   make_slot_snapshot)
 from repro.runtime.faults import FaultPlan, SessionCrashed, SessionWedged
@@ -62,135 +32,7 @@ from repro.runtime.scheduler import (CANCELLED, CLASSES, DONE, FAILED, QUEUED,
                                      deserialize_request, serialize_request)
 from repro.runtime.telemetry import recorder
 
-
-class ServeLoop:
-    """Greedy batched decoding with a step-compiled decode function.
-
-    `decode_step(params, cache, batch) -> (cache, token)`; requests are
-    slotted into the fixed batch (production continuous batching keeps a
-    slot -> request map; completed slots are refilled each round).
-
-    `eos_id` (None disables): a slot that emits EOS is *finished* — its
-    subsequent tokens are masked to EOS, it stops counting toward emitted
-    lengths, and the loop stops early once every slot has finished.
-
-    `chunk` picks the execution engine: 1 (default) is the per-token host
-    loop — one dispatch + one host sync per token; K > 1 compiles K decode
-    steps into one `lax.scan` program with donated cache/token buffers
-    (runtime/engine.py), so the host syncs once per K tokens. Both paths
-    produce bit-identical tokens, EOS behaviour, and emitted counts; the
-    engine path additionally leaves the input `cache` buffer consumed
-    (donated) after `generate`.
-    """
-
-    def __init__(self, decode_step: Callable, params, cache, batch_size: int,
-                 eos_id: int | None = None, chunk: int = 1,
-                 donate: bool = True, engine: DecodeEngine | None = None):
-        self.decode_step = decode_step
-        self.params = params
-        self.cache = cache
-        self.batch_size = batch_size
-        self.eos_id = eos_id
-        self.latencies: list[float] = []
-        self.emitted_lengths: np.ndarray | None = None
-        self._finished: np.ndarray | None = None
-        self._chunk_steps: list[int] | None = None
-        self.clock = StallClock()
-        # a prebuilt engine (e.g. cached on a compiled program so its scan
-        # program compiles once, not per generate) wins over `chunk`
-        if engine is None and chunk > 1:
-            engine = DecodeEngine(decode_step, chunk, eos_id=eos_id,
-                                  donate=donate)
-        self._engine = engine
-        self.chunk = engine.chunk if engine is not None else chunk
-
-    def generate(self, prompt_tokens: np.ndarray, max_new: int,
-                 start_pos: int = 0) -> np.ndarray:
-        """prompt_tokens: (B, 1) last prompt token per slot."""
-        if self._engine is not None:
-            return self._generate_chunked(prompt_tokens, max_new, start_pos)
-        prompt_tokens = np.asarray(prompt_tokens)
-        B = prompt_tokens.shape[0]
-        out = np.empty((B, 1 + max_new), np.int32)       # one host buffer
-        out[:, 0] = prompt_tokens[:, 0]
-        tok = jnp.asarray(prompt_tokens, jnp.int32)
-        finished = np.zeros(B, bool)
-        emitted = np.zeros(B, np.int64)
-        pos = start_pos
-        self.latencies = []
-        self.clock = StallClock()
-        w = 0
-        for _ in range(max_new):
-            t0 = self.clock.dispatch()
-            self.cache, tok = self.decode_step(
-                self.params, self.cache,
-                {"tokens": tok, "pos": jnp.asarray(pos, jnp.int32)})
-            self.clock.sync(tok)
-            self.latencies.append(time.perf_counter() - t0)
-            step_tok = np.asarray(tok)
-            emitted += ~finished
-            if self.eos_id is not None:
-                # already-finished slots hold EOS regardless of the argmax
-                step_tok = np.where(finished[:, None], self.eos_id, step_tok)
-                finished |= step_tok[:, 0] == self.eos_id
-                tok = jnp.asarray(step_tok)
-            out[:, 1 + w] = step_tok[:, 0]
-            w += 1
-            pos += 1
-            if self.eos_id is not None and finished.all():
-                break
-        self.emitted_lengths = emitted
-        self._finished = finished
-        self._chunk_steps = None
-        return out[:, :1 + w]
-
-    def _generate_chunked(self, prompt_tokens, max_new: int,
-                          start_pos: int) -> np.ndarray:
-        out, cache, finished, emitted = self._engine.generate(
-            self.params, self.cache, prompt_tokens, max_new, start_pos)
-        self.cache = cache
-        self.clock = self._engine.clock
-        self.latencies = [dt for dt, _ in self._engine.chunk_latencies]
-        self._chunk_steps = [n for _, n in self._engine.chunk_latencies]
-        self.emitted_lengths = emitted
-        self._finished = finished
-        return out
-
-    def stats(self) -> dict:
-        """Latency stats over the post-warmup steps (first step — or first
-        chunk, on the engine path — dropped: it carries compilation). With
-        zero or one recorded sample there are no measured steps, so
-        throughput/percentiles report 0.0 rather than the fake `1/epsilon`
-        numbers an empty array would produce; `decode_steps` counts the
-        decode steps covered by the measured samples. After a `generate`,
-        `emitted_per_slot` reports how many tokens each slot emitted before
-        (and including) its EOS, and `finished_slots` how many slots hit
-        EOS. `stall` carries the StallClock ledger (host-sync count,
-        dispatch-gap and device-wait seconds, stall_pct).
-        """
-        lat = np.asarray(self.latencies[1:], np.float64)
-        if self._chunk_steps is not None:
-            st = chunked_latency_stats(zip(self.latencies, self._chunk_steps))
-        elif lat.size == 0:
-            st = {"decode_steps": 0, "p50_ms": 0.0, "p99_ms": 0.0,
-                  "tokens_per_s_per_slot": 0.0}
-        else:
-            st = {"decode_steps": int(lat.size),
-                  "p50_ms": float(np.percentile(lat, 50) * 1e3),
-                  "p99_ms": float(np.percentile(lat, 99) * 1e3),
-                  "tokens_per_s_per_slot": float(1.0 / max(lat.mean(), 1e-9))}
-        st["chunk"] = self.chunk
-        st["stall"] = self.clock.report()
-        if self.emitted_lengths is not None:
-            st["emitted_per_slot"] = [int(n) for n in self.emitted_lengths]
-            if self.eos_id is not None:
-                st["finished_slots"] = int(self._finished.sum())
-        return st
-
-
-# ----------------------------------------------------------------------------
-# Request-level serving: continuous batching over a slot pool
-# ----------------------------------------------------------------------------
+HISTORY = 4096          # sliding-window length for session stats records
 
 
 def _class_counters() -> dict:
@@ -454,8 +296,8 @@ class ServeSession:
                              f"session's max_prompt={self.max_prompt}")
         # the request's last KV write lands at position P + max_new - 2
         # (the step consuming prompt token P emits token #1), so it fits
-        # iff P + max_new - 1 <= max_seq — exactly the old ServeProgram
-        # bound of P + N <= max_seq once run(prompt)'s +1 budget is counted
+        # iff P + max_new - 1 <= max_seq (a one-shot run(prompt) of N new
+        # tokens asks for N + 1, so it needs P + N <= max_seq)
         if (self.max_seq is not None
                 and prompt.size + max_new - 1 > self.max_seq):
             raise ValueError(f"prompt ({prompt.size}) + max_new ({max_new}) "

@@ -27,8 +27,8 @@ def test_train_and_serve_one_call(tmp_path):
     out = api.serve("xlstm-125m", report["params"], batch=2, max_seq=16,
                     max_new=4)
     assert out["tokens"].shape == (2, 5)
-    # 4 generated tokens -> 3 post-warmup latency samples
-    assert out["stats"]["decode_steps"] == 3
+    assert out["stats"]["emitted_per_slot"] == [4, 4]
+    assert out["stats"]["stall"]["host_syncs"] == 4   # chunk 1: one a token
 
 
 @pytest.mark.slow

@@ -4,7 +4,8 @@ Covers the policy satellites (scoped override nesting, per-op overrides,
 interpret-mode equivalence with the REPRO_INTERPRET env path, policy-
 respected dispatch in tuned_call), the Cluster programs + compile cache,
 the api.* shims (identical report keys and matching loss/tokens vs the
-Cluster path on a smoke config), and ServeLoop's EOS handling.
+Cluster path on a smoke config), and the one-shot session result's EOS
+handling.
 """
 
 import os
@@ -14,11 +15,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.cluster import (Cluster, KernelPolicy, ServeProgram, TrainProgram,
-                           current_policy, default_policy, use_policy)
+from repro.cluster import (Cluster, DryRunProgram, KernelPolicy,
+                           ServeSessionProgram, TrainProgram, current_policy,
+                           default_policy, use_policy)
 from repro.configs import registry
 from repro.kernels import ops, ref
-from repro.runtime.serve_loop import ServeLoop
+from repro.models import steps
 
 
 def rand(seed, shape):
@@ -178,16 +180,17 @@ def test_cluster_policy_scope_sets_cluster_default():
 
 def test_cluster_compile_cache_memoizes_programs():
     cluster = Cluster("xlstm-125m-smoke")
-    spec = ServeProgram(batch=2, max_seq=16, max_new=2)
+    spec = ServeSessionProgram(slots=2, max_seq=16, max_new=2)
     p1 = cluster.compile(spec)
-    p2 = cluster.compile(ServeProgram(batch=2, max_seq=16, max_new=2))
+    p2 = cluster.compile(ServeSessionProgram(slots=2, max_seq=16, max_new=2))
     assert p1 is p2
     assert cluster.compile_cache.hits == 1
     # a different spec, and a different policy scope, compile fresh
-    p3 = cluster.compile(ServeProgram(batch=4, max_seq=16, max_new=2))
+    p3 = cluster.compile(ServeSessionProgram(slots=4, max_seq=16, max_new=2))
     assert p3 is not p1
     with cluster.policy("fused"):
-        p4 = cluster.compile(ServeProgram(batch=2, max_seq=16, max_new=2))
+        p4 = cluster.compile(ServeSessionProgram(slots=2, max_seq=16,
+                                                 max_new=2))
     assert p4 is not p1
     assert p4.policy.fused
 
@@ -195,6 +198,15 @@ def test_cluster_compile_cache_memoizes_programs():
 def test_cluster_rejects_unknown_program():
     with pytest.raises(TypeError):
         Cluster("xlstm-125m-smoke").compile({"not": "a program"})
+
+
+def test_dry_run_refuses_a_chunked_decode_cell_outside_the_session():
+    """Only the session runs a K-step decode chunk, so only its cell lowers
+    one."""
+    prog = Cluster("qwen3-14b-smoke").compile(
+        DryRunProgram(shape="decode_32k", decode_chunk=4))
+    with pytest.raises(ValueError, match="session"):
+        prog.run()
 
 
 # ----------------------------------------------------------------------------
@@ -221,7 +233,8 @@ def test_api_shims_match_cluster_programs(tmp_path):
 
     s_api = api.serve("xlstm-125m", r_api["params"], batch=2, max_seq=16,
                       max_new=4)
-    s_clu = cluster.compile(ServeProgram(batch=2, max_seq=16, max_new=4)) \
+    s_clu = cluster.compile(ServeSessionProgram(slots=2, max_seq=16,
+                                                max_new=4, chunk=1)) \
         .run(params=r_clu["params"])
     assert sorted(s_api.keys()) == sorted(s_clu.keys())
     np.testing.assert_array_equal(s_api["tokens"], s_clu["tokens"])
@@ -243,49 +256,53 @@ def test_train_program_report_and_plan(tmp_path):
 
 
 # ----------------------------------------------------------------------------
-# ServeLoop EOS handling (satellite)
+# The one-shot session result: EOS padding, finished_slots, emitted_per_slot
 # ----------------------------------------------------------------------------
 
 
-def _scripted_decode(script):
-    """decode_step emitting script[pos] (a (B,) row) at each position."""
+def _one_shot(monkeypatch, script, max_new, eos_id=None):
+    """`CompiledServeSession.run()` with the model's decode step swapped
+    for a scripted one: slot i emits script[pos, i] at position pos."""
+    table = jnp.asarray(script, jnp.int32)
+
     def decode_step(params, cache, batch):
-        pos = int(batch["pos"])
-        return cache, jnp.asarray(script[pos])[:, None].astype(jnp.int32)
-    return decode_step
+        pos = jnp.clip(batch["pos"], 0, table.shape[0] - 1)
+        return cache, jnp.diagonal(jnp.take(table, pos, axis=0))[:, None]
+
+    monkeypatch.setattr(steps, "make_decode_step",
+                        lambda cfg, **kw: decode_step)
+    program = Cluster("xlstm-125m-smoke").compile(ServeSessionProgram(
+        slots=table.shape[1], max_seq=16, max_new=max_new, eos_id=eos_id,
+        chunk=2))
+    return program.run()
 
 
-def test_serve_loop_eos_masks_and_stops():
-    # slot 0 hits EOS (=7) at step 1, slot 1 at step 2; B=3 never does
-    script = {0: np.array([7, 1, 2]), 1: np.array([3, 7, 4]),
-              2: np.array([5, 6, 8]), 3: np.array([9, 9, 9])}
-    loop = ServeLoop(_scripted_decode(script), None, None, batch_size=3,
-                     eos_id=7)
-    out = loop.generate(np.zeros((3, 1), np.int32), max_new=4)
-    # slot 0: eos at step 0, masked afterward
-    np.testing.assert_array_equal(out[0], [0, 7, 7, 7, 7])
-    np.testing.assert_array_equal(out[1], [0, 1, 7, 7, 7])
-    np.testing.assert_array_equal(out[2], [0, 2, 4, 8, 9])
-    st = loop.stats()
+def test_serve_loop_eos_masks_and_stops(monkeypatch):
+    # slot 0 hits EOS (=7) at step 0, slot 1 at step 1; slot 2 never does
+    script = np.array([[7, 1, 2], [3, 7, 4], [5, 6, 8], [9, 9, 9]])
+    out = _one_shot(monkeypatch, script, max_new=4, eos_id=7)
+    # a finished row is padded with EOS
+    np.testing.assert_array_equal(out["tokens"], [[0, 7, 7, 7, 7],
+                                                  [0, 1, 7, 7, 7],
+                                                  [0, 2, 4, 8, 9]])
+    st = out["stats"]
     assert st["emitted_per_slot"] == [1, 2, 4]
     assert st["finished_slots"] == 2
 
 
-def test_serve_loop_eos_early_stop():
-    script = {0: np.array([7, 7]), 1: np.array([1, 1]), 2: np.array([1, 1])}
-    loop = ServeLoop(_scripted_decode(script), None, None, batch_size=2,
-                     eos_id=7)
-    out = loop.generate(np.zeros((2, 1), np.int32), max_new=10)
-    assert out.shape == (2, 2)                       # stopped after step 1
-    assert len(loop.latencies) == 1
-    assert loop.stats()["emitted_per_slot"] == [1, 1]
-    assert loop.stats()["finished_slots"] == 2
+def test_serve_loop_eos_early_stop(monkeypatch):
+    script = np.array([[7, 7], [1, 1]])
+    out = _one_shot(monkeypatch, script, max_new=10, eos_id=7)
+    assert out["tokens"].shape == (2, 2)              # stopped after step 0
+    st = out["stats"]
+    assert st["stall"]["host_syncs"] == 1
+    assert st["emitted_per_slot"] == [1, 1]
+    assert st["finished_slots"] == 2
 
 
-def test_serve_loop_no_eos_unchanged():
-    script = {i: np.array([7, 7]) for i in range(4)}
-    loop = ServeLoop(_scripted_decode(script), None, None, batch_size=2)
-    out = loop.generate(np.zeros((2, 1), np.int32), max_new=4)
-    assert out.shape == (2, 5)                       # eos disabled: full run
-    assert loop.stats()["emitted_per_slot"] == [4, 4]
-    assert "finished_slots" not in loop.stats()
+def test_serve_loop_no_eos_unchanged(monkeypatch):
+    script = np.full((4, 2), 7)
+    out = _one_shot(monkeypatch, script, max_new=4)
+    assert out["tokens"].shape == (2, 5)              # eos disabled: full run
+    assert out["stats"]["emitted_per_slot"] == [4, 4]
+    assert "finished_slots" not in out["stats"]

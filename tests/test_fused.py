@@ -4,7 +4,7 @@ Numerics: every fused kernel against its unfused jnp composition from
 kernels/ref.py, at fp32 (<= 1e-5) and bf16 (<= 2e-2), interpret mode.
 Mechanics: check_fusable compatibility, saved-bytes accounting, the
 autotune-on-miss path of tuned_call, the fused roofline, the model-stack
-routing behind the "fused" KernelPolicy, and the ServeLoop.stats guard.
+routing behind the "fused" KernelPolicy.
 """
 
 import dataclasses
@@ -17,7 +17,6 @@ import pytest
 from repro.configs import registry
 from repro.kernels import fused, ops, ref, pipeline as pp
 from repro.launch.roofline import fused_roofline
-from repro.runtime.serve_loop import ServeLoop
 
 TOL = {jnp.float32: dict(rtol=1e-5, atol=1e-5),
        jnp.bfloat16: dict(rtol=2e-2, atol=2e-2)}
@@ -289,30 +288,3 @@ def test_pallas_attention_schedule_adapter():
                               schedule="direct")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-3, atol=2e-3)
-
-
-# ----------------------------------------------------------------------------
-# ServeLoop.stats guard (satellite)
-# ----------------------------------------------------------------------------
-
-
-def _dummy_loop(n_latencies: int) -> ServeLoop:
-    loop = ServeLoop(decode_step=lambda p, c, b: (c, b["tokens"]),
-                     params=None, cache=None, batch_size=1)
-    loop.latencies = [0.01] * n_latencies
-    return loop
-
-
-def test_serve_stats_empty_and_single_step():
-    for n in (0, 1):
-        st = _dummy_loop(n).stats()
-        assert st["decode_steps"] == 0
-        assert st["tokens_per_s_per_slot"] == 0.0
-        assert st["p50_ms"] == 0.0 and st["p99_ms"] == 0.0
-
-
-def test_serve_stats_counts_warmup_dropped_steps():
-    st = _dummy_loop(5).stats()
-    assert st["decode_steps"] == 4            # first step dropped as warmup
-    assert st["tokens_per_s_per_slot"] == pytest.approx(100.0, rel=1e-6)
-    assert st["p50_ms"] == pytest.approx(10.0, rel=1e-6)

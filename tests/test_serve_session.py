@@ -1,12 +1,14 @@
 """Request-level serving: ServeSession + the slot-scheduled session cell.
 
 Acceptance coverage: a ServeSession fed one batch up-front is bit-identical
-to the fixed-batch `ServeProgram(chunk=K)` path (tokens, EOS behaviour,
-`emitted_per_slot`); staggered requests decoding at independent per-slot
-positions match what each request gets in isolation; finished slots are
-recycled in place (allocation-free steady state, on-device `age`/`active`
-masks); streaming delivers incremental tokens; cancel frees the slot;
-submit applies bounded-queue backpressure.
+to a plain per-token greedy loop over the same decode step (tokens, EOS
+early-stop and masking, `emitted_per_slot`) at every chunk length, with
+O(T/K) host syncs; the chunk consumes its donated state; staggered
+requests decoding at independent per-slot positions match what each
+request gets in isolation; finished slots are recycled in place
+(allocation-free steady state, on-device `age`/`active` masks); streaming
+delivers incremental tokens; cancel frees the slot; submit applies
+bounded-queue backpressure.
 """
 
 import gc
@@ -16,10 +18,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.cluster import Cluster, ServeProgram, ServeSessionProgram
+from repro.cluster import Cluster, ServeSessionProgram
+from repro.models import steps
 from repro.runtime import engine
 from repro.runtime.scheduler import QueueFull, RequestFailed
-from repro.runtime.serve_loop import ServeLoop, ServeSession
+from repro.runtime.serve_loop import ServeSession
 
 
 # ----------------------------------------------------------------------------
@@ -59,31 +62,116 @@ def make_session(script=SCRIPT, *, chunk=2, eos_id=7, max_prompt=4,
                         max_queue=max_queue, admission=admission)
 
 
+def reference_loop(step, params, cache, prompts, n, eos_id=None):
+    """The per-token oracle: one decode call a position for the whole batch,
+    feeding each slot its prompt, then its own greedy token. Slot i emits
+    the outputs from its last prompt position on: n of them, or up to and
+    with its EOS."""
+    prompts = np.asarray(prompts, np.int32)
+    P = prompts.shape[1]
+    rows, tok = [[] for _ in prompts], None
+    for pos in range(P + n - 1):
+        feed = prompts[:, pos:pos + 1] if pos < P else tok
+        cache, tok = step(params, cache, {"tokens": jnp.asarray(feed),
+                                          "pos": jnp.asarray(pos, jnp.int32)})
+        tok = np.asarray(tok)
+        if pos < P - 1:
+            continue
+        for row, t in zip(rows, tok[:, 0]):
+            if not row or row[-1] != eos_id:
+                row.append(int(t))
+    return rows
+
+
 # ----------------------------------------------------------------------------
-# Parity with the fixed-batch loop (scripted)
+# Parity with the per-token loop (scripted)
 # ----------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("chunk", [1, 2, 3, 16])
-def test_session_matches_serve_loop_bit_for_bit(chunk):
+@pytest.mark.parametrize("eos_id", [7, None])
+@pytest.mark.parametrize("chunk", [1, 2, 3, 4, 16])
+def test_scan_decode_matches_per_token_loop(chunk, eos_id):
     B = SCRIPT.shape[1]
-    loop = ServeLoop(scripted_step(SCRIPT), None,
-                     {"kv": jnp.zeros((B, 4), jnp.float32)},
-                     batch_size=B, eos_id=7, chunk=1)
-    ref = loop.generate(np.zeros((B, 1), np.int32), max_new=4)
-    ref_st = loop.stats()
-
-    sess = make_session(chunk=chunk)
+    ref = reference_loop(scripted_step(SCRIPT), None, None,
+                         np.zeros((B, 1)), 4, eos_id)
+    sess = make_session(chunk=chunk, eos_id=eos_id)
     handles = [sess.submit([0], 4) for _ in range(B)]
     sess.drain()
-    # per-request tokens are the unpadded rows of the legacy output
-    for i, h in enumerate(handles):
-        n = ref_st["emitted_per_slot"][i]
-        np.testing.assert_array_equal(h.tokens, ref[i, 1:1 + n])
-    assert [h.tokens.size for h in handles] == ref_st["emitted_per_slot"]
-    assert sum(h.hit_eos for h in handles) == ref_st["finished_slots"]
+    assert [h.tokens.tolist() for h in handles] == ref
+    assert [h.hit_eos for h in handles] == [eos_id in r for r in ref]
     # host syncs once per chunk, not per token
     assert sess.clock.report()["host_syncs"] <= -(-4 // chunk) + 1
+
+
+def test_scan_decode_eos_early_stop_and_masking():
+    sess = make_session(chunk=2)
+    hs = [sess.submit([0], 4) for _ in range(3)]
+    sess.drain()
+    # slot 0 finishes at step 0, slot 1 at step 1; slot 2 never does: a
+    # finished slot emits nothing past its EOS
+    assert [h.tokens.tolist() for h in hs] == [[7], [1, 7], [2, 4, 8, 9]]
+    assert [h.hit_eos for h in hs] == [True, True, False]
+
+    all_eos = np.full((4, 2), 7, np.int32)
+    sess = make_session(all_eos, chunk=4)
+    hs = [sess.submit([0], 10) for _ in range(2)]
+    sess.drain()
+    assert [h.tokens.tolist() for h in hs] == [[7], [7]]
+    assert sess.clock.report()["host_syncs"] == 1   # one chunk was enough
+
+
+@pytest.mark.parametrize("chunk", [4, 16])
+def test_scan_decode_no_eos_and_partial_chunk(chunk):
+    """K > max_new: one chunk, its steps past the budget skipped."""
+    B = SCRIPT.shape[1]
+    sess = make_session(chunk=chunk, eos_id=None)
+    hs = [sess.submit([0], 3) for _ in range(B)]
+    sess.drain()
+    assert [h.tokens.tolist() for h in hs] == reference_loop(
+        scripted_step(SCRIPT), None, None, np.zeros((B, 1)), 3)
+    assert [h.tokens.size for h in hs] == [3, 3, 3]
+    assert sess.clock.report()["host_syncs"] == 1
+
+
+def test_decode_chunk_rejects_bad_k():
+    with pytest.raises(ValueError):
+        engine.make_session_chunk(scripted_step(SCRIPT), 0)
+
+
+def test_decode_chunk_donates_buffers():
+    chunk_fn = engine.make_session_chunk(
+        scripted_step(np.zeros((64, 2), np.int32)), 8)
+    state = engine.init_session_state({"kv": jnp.zeros((2, 4), jnp.float32)},
+                                      2, 4)
+    state = dict(state, active=jnp.ones((2,), bool),
+                 budget=jnp.full((2,), 64, jnp.int32))
+    leaf = state["cache"]["kv"]
+
+    def one_chunk(state):
+        out = chunk_fn(None, state)
+        state = out[0]
+        del out
+        jax.block_until_ready(state)
+        gc.collect()
+        return state
+
+    state = one_chunk(state)                # warmup (compile)
+    assert leaf.is_deleted()                # the donated input is consumed
+    state = one_chunk(state)                # first steady-state chunk
+    baseline = len(jax.live_arrays())
+    for _ in range(3):
+        state = one_chunk(state)
+        # steady state: no growth in live device allocations across chunks
+        assert len(jax.live_arrays()) == baseline
+
+
+def test_serve_stats_report_stall_and_chunk():
+    sess = make_session(chunk=4)
+    sess.submit([0], 4)
+    st = sess.drain()
+    assert st["chunk"] == 4
+    for key in ("host_syncs", "dispatch_gap_s", "device_wait_s", "stall_pct"):
+        assert key in st["stall"]
 
 
 def test_session_slot_recycling_and_age():
@@ -191,6 +279,15 @@ def test_longest_prefix_admission_orders_by_prompt():
     assert list(sess.scheduler.admitted_order) == [b.id, a.id]
 
 
+def test_idle_session_stats_are_zero_not_nan():
+    st = make_session().stats()
+    assert st["occupancy_pct"] == 0.0
+    assert st["emitted_total"] == 0 and st["stall"]["host_syncs"] == 0
+    zero = {"p50": 0.0, "p99": 0.0}
+    for s in [st] + list(st["classes"].values()):
+        assert s["ttft_ms"] == zero and s["latency_ms"] == zero
+
+
 def test_session_stats_shape():
     sess = make_session(chunk=2, eos_id=None)
     hs = [sess.submit([0], 3) for _ in range(4)]
@@ -210,36 +307,67 @@ def test_session_stats_shape():
 
 @pytest.mark.slow
 def test_one_shot_session_bit_identical_to_serve_program():
+    """`run()` against the per-token loop over the jitted decode step,
+    with and without a prompt and an EOS: tokens, EOS padding,
+    `emitted_per_slot` and `finished_slots`."""
     cluster = Cluster("xlstm-125m-smoke")
-    ref = cluster.compile(ServeProgram(batch=2, max_seq=16, max_new=8,
-                                       chunk=4))
-    params = ref.init_params()
-    r_ref = ref.run(params=params)
-    r_sess = cluster.compile(ServeSessionProgram(
-        slots=2, max_seq=16, max_new=8, chunk=4)).run(params=params)
-    np.testing.assert_array_equal(r_ref["tokens"], r_sess["tokens"])
-    assert (r_ref["stats"]["emitted_per_slot"]
-            == r_sess["stats"]["emitted_per_slot"])
+    cfg = cluster.arch
+    program = lambda **kw: cluster.compile(ServeSessionProgram(
+        slots=2, max_seq=16, max_new=8, chunk=4, **kw))
+    params = program().init_params()
+    decode = jax.jit(steps.make_decode_step(cfg, max_seq=16))
 
-    # EOS variant: masking, early stop, finished_slots all line up
-    eos = int(r_ref["tokens"][0, 4])
-    re = cluster.compile(ServeProgram(batch=2, max_seq=16, max_new=8,
-                                      chunk=4, eos_id=eos)).run(params=params)
-    rs = cluster.compile(ServeSessionProgram(
-        slots=2, max_seq=16, max_new=8, chunk=4,
-        eos_id=eos)).run(params=params)
-    np.testing.assert_array_equal(re["tokens"], rs["tokens"])
-    assert re["stats"]["emitted_per_slot"] == rs["stats"]["emitted_per_slot"]
-    assert re["stats"]["finished_slots"] == rs["stats"]["finished_slots"]
+    def reference(prompt, eos_id):
+        # column 0 is the start token 0 without a prompt, else the first
+        # sampled token; 8 generated tokens follow it
+        cache = steps.init_cache(cfg, 2, steps.decode_cache_len(cfg, 16))
+        if prompt is None:
+            rows = reference_loop(decode, params, cache, np.zeros((2, 1)),
+                                  8, eos_id)
+            return [[0] + r for r in rows]
+        return reference_loop(decode, params, cache, prompt, 9, eos_id)
 
-    # prompt ingest parity (continuous-batching-style prefill per slot)
     prompt = np.asarray(jax.random.randint(jax.random.PRNGKey(0), (2, 3), 0,
-                                           cluster.arch.vocab))
-    rp = ref.run(params=params, prompt=prompt)
-    rps = cluster.compile(ServeSessionProgram(
-        slots=2, max_seq=16, max_new=8, chunk=4)).run(params=params,
-                                                      prompt=prompt)
-    np.testing.assert_array_equal(rp["tokens"], rps["tokens"])
+                                           cfg.vocab))
+    eos = reference(None, None)[0][4]         # a token the model emits
+    for p, eos_id in ((None, None), (None, eos), (prompt, None), (prompt, eos)):
+        rows = reference(p, eos_id)
+        out = program(eos_id=eos_id).run(params=params, prompt=p)
+        w = max(map(len, rows))
+        pad = 0 if eos_id is None else eos_id
+        np.testing.assert_array_equal(
+            out["tokens"], [r + [pad] * (w - len(r)) for r in rows])
+        st = out["stats"]
+        assert st["emitted_per_slot"] == [len(r) - 1 for r in rows]
+        if eos_id is None:
+            assert "finished_slots" not in st
+        else:
+            emitted = [r if p is not None else r[1:] for r in rows]
+            assert st["finished_slots"] == sum(eos_id in e for e in emitted)
+
+
+@pytest.mark.slow
+def test_model_decode_parity_and_donation():
+    """Real model: chunk 1 vs chunk 4 — tokens and EOS bit-identical, one
+    host sync a chunk."""
+    cluster = Cluster("xlstm-125m-smoke")
+    program = lambda **kw: cluster.compile(ServeSessionProgram(
+        slots=2, max_seq=16, max_new=8, **kw))
+    params = program(chunk=1).init_params()
+    r1 = program(chunk=1).run(params=params)
+    r4 = program(chunk=4).run(params=params)
+    np.testing.assert_array_equal(r1["tokens"], r4["tokens"])
+    assert r1["stats"]["stall"]["host_syncs"] == 8
+    assert r4["stats"]["stall"]["host_syncs"] == 2
+
+    # EOS parity with a token the model really emits
+    eos = int(r1["tokens"][0, 4])
+    re1 = program(chunk=1, eos_id=eos).run(params=params)
+    re4 = program(chunk=4, eos_id=eos).run(params=params)
+    np.testing.assert_array_equal(re1["tokens"], re4["tokens"])
+    assert (re1["stats"]["emitted_per_slot"]
+            == re4["stats"]["emitted_per_slot"])
+    assert re1["stats"]["finished_slots"] == re4["stats"]["finished_slots"]
 
 
 @pytest.mark.slow
@@ -278,6 +406,6 @@ def test_api_serve_routes_through_session():
     out = api.serve("xlstm-125m", batch=2, max_seq=16, max_new=4)
     assert out["tokens"].shape == (2, 5)
     st = out["stats"]
-    assert st["decode_steps"] == 3            # legacy per-token warmup drop
     assert st["emitted_per_slot"] == [4, 4]
-    assert "session" in st and st["session"]["requests_done"] == 2
+    assert st["stall"]["host_syncs"] == 4     # chunk 1: a sync a token
+    assert st["requests_done"] == 2

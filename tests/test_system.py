@@ -6,12 +6,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.cluster import Cluster, ServeSessionProgram
 from repro.configs import get
 from repro.core import compat
 from repro.data import Distributor, Splitter, SyntheticLMStream
 from repro.data.pipeline import BatchSpec
 from repro.models import steps
-from repro.runtime import ServeLoop, TrainLoop, TrainLoopConfig
+from repro.runtime import TrainLoop, TrainLoopConfig
 
 
 @pytest.mark.slow
@@ -42,20 +43,18 @@ def test_train_then_serve_roundtrip(tmp_path):
     assert report["final_step"] == 4
     assert all(np.isfinite(m["loss"]) for m in report["metrics"])
 
-    # restore params and serve a batch of 2 greedily
+    # restore params and serve a batch of 2 greedily through a session
     restored = loop.ckpt.restore(4, state)
-    params = restored["params"]
-    cache = steps.init_cache(cfg, 2, S)
-    dec = jax.jit(steps.make_decode_step(cfg, max_seq=S))
-    serve = ServeLoop(dec, params, cache, batch_size=2)
-    out = serve.generate(np.zeros((2, 1), np.int32), max_new=5)
-    assert out.shape == (2, 6)
-    assert (out >= 0).all() and (out < cfg.vocab).all()
-    stats = serve.stats()
-    # decode_steps counts the warmup-dropped samples the percentiles use
-    # (5 generated tokens, first step dropped as compile warmup)
-    assert stats["decode_steps"] == 4
-    assert stats["tokens_per_s_per_slot"] > 0
+    serve = Cluster(cfg).compile(ServeSessionProgram(slots=2, max_seq=S,
+                                                     max_new=5, chunk=2))
+    out = serve.run(params=restored["params"])
+    toks = out["tokens"]
+    assert toks.shape == (2, 6)
+    assert (toks >= 0).all() and (toks < cfg.vocab).all()
+    stats = out["stats"]
+    assert stats["emitted_per_slot"] == [5, 5]
+    assert stats["requests_done"] == 2
+    assert stats["stall"]["host_syncs"] == 3      # ceil(5 / 2) chunks
 
 
 @pytest.mark.slow

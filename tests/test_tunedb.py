@@ -466,17 +466,9 @@ def _gate_record(tuned_us: float, default_us: float) -> dict:
             {"name": "table1_fused/rmsnorm_matmul", "us_per_call": 100.0,
              "derived": "unfused_us=150.0;bytes_reduction=2.5"},
         ],
-        "decode": [
-            {"name": "decode/K1", "us_per_call": 1000.0,
-             "derived": "tokens_per_s=1500.0;stall_pct=0.2;host_syncs=32"},
-            {"name": "decode/K16", "us_per_call": 500.0,
-             "derived": "tokens_per_s=3800.0;stall_pct=0.5;host_syncs=2"},
-        ],
         "serve_continuous": [
             {"name": "serve/continuous", "us_per_call": 180.0,
              "derived": "tokens_per_s=5400.0;occupancy_pct=79.0;p99_ms=90"},
-            {"name": "serve/static", "us_per_call": 340.0,
-             "derived": "tokens_per_s=2900.0;occupancy_pct=45.0;p99_ms=180"},
         ],
     }
 
@@ -495,7 +487,7 @@ def _run_gate(tmp_path, record, baseline=None, require="tuned", tol=0.15):
 
 def test_gate_passes_when_tuned_not_slower(tmp_path):
     assert _run_gate(tmp_path, _gate_record(90.0, 100.0),
-                     require="tuned,fused,decode,serve") == 0
+                     require="tuned,fused,serve") == 0
 
 
 def test_gate_fails_when_tuned_slower(tmp_path):
@@ -511,22 +503,21 @@ def test_gate_fails_on_missing_sections(tmp_path):
     record = _gate_record(90.0, 100.0)
     del record["serve_continuous"]
     assert _run_gate(tmp_path, record,
-                     require="tuned,fused,decode,serve") == 1
+                     require="tuned,fused,serve") == 1
 
 
 def test_gate_baseline_regressions(tmp_path):
     good = _gate_record(90.0, 100.0)
-    # stall regression beyond tolerance fails
-    worse = json.loads(json.dumps(good))
-    worse["decode"][1]["derived"] = \
-        "tokens_per_s=3800.0;stall_pct=9.5;host_syncs=2"
-    assert _run_gate(tmp_path, worse, baseline=good) == 1
     # occupancy collapse fails
-    worse2 = json.loads(json.dumps(good))
-    worse2["serve_continuous"][0]["derived"] = \
+    worse = json.loads(json.dumps(good))
+    worse["serve_continuous"][0]["derived"] = \
         "tokens_per_s=5400.0;occupancy_pct=40.0;p99_ms=90"
-    assert _run_gate(tmp_path, worse2, baseline=good) == 1
-    # within tolerance passes
+    assert _run_gate(tmp_path, worse, baseline=good) == 1
+    # a drop within the 10-point tolerance passes
+    near = json.loads(json.dumps(good))
+    near["serve_continuous"][0]["derived"] = \
+        "tokens_per_s=5400.0;occupancy_pct=70.0;p99_ms=90"
+    assert _run_gate(tmp_path, near, baseline=good) == 0
     assert _run_gate(tmp_path, good, baseline=good) == 0
 
 
